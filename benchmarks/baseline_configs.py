@@ -31,15 +31,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paimon_tpu.utils import enable_compile_cache
-from paimon_tpu.utils.tpuguard import ensure_live_backend
+from paimon_tpu.utils import enable_compile_cache, require_device
 
 enable_compile_cache()
-
-# wedge-proof device access (tpuguard): explicit-CPU honored, detached probe
-# (never killed), single-flight lock, clean-exit signals, LOUD CPU fallback
-# (PAIMON_TPU_REQUIRE=1 turns the fallback into exit 3)
-PLATFORM = ensure_live_backend()
+PLATFORM, DEVICE_KIND, DEVICE_COUNT = require_device()
 
 BASE = 975_400.0
 
@@ -49,7 +44,7 @@ def emit(metric, value, unit="rows/s", vs=None, **extra):
         json.dumps(
             {"metric": metric, "value": round(value, 1), "unit": unit,
              "vs_baseline": round(value / BASE, 3) if vs is None else vs,
-             "platform": PLATFORM, **extra}
+             "platform": PLATFORM, "device_kind": DEVICE_KIND, "devices": DEVICE_COUNT, **extra}
         ),
         flush=True,
     )

@@ -25,15 +25,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paimon_tpu.utils import enable_compile_cache
-from paimon_tpu.utils.tpuguard import ensure_live_backend
+from paimon_tpu.utils import enable_compile_cache, require_device
 
 enable_compile_cache()
-
-# wedge-proof device access (tpuguard): explicit-CPU honored, detached probe
-# (never killed), single-flight lock, clean-exit signals, LOUD CPU fallback
-# (PAIMON_TPU_REQUIRE=1 turns the fallback into exit 3)
-PLATFORM = ensure_live_backend()
+PLATFORM, DEVICE_KIND, DEVICE_COUNT = require_device()
 
 
 def best_of(fn, iters=3):
@@ -135,7 +130,7 @@ def main():
             + results["gather_ms"]
         )
         meta = {
-            "platform": PLATFORM,
+            "platform": PLATFORM, "device_kind": DEVICE_KIND, "devices": DEVICE_COUNT,
             "rows": args.rows,
             "runs": args.runs,
             "merged_rows": merged.num_rows,
@@ -222,7 +217,7 @@ def main():
             json.dumps(
                 {"metric": "merge-read.host.total", "value": round(h_total * 1000, 2),
                  "unit": "ms", "rows_per_s": round(args.rows / h_total, 1),
-                 "platform": PLATFORM}
+                 "platform": PLATFORM, "device_kind": DEVICE_KIND, "devices": DEVICE_COUNT}
             ),
             flush=True,
         )

@@ -292,4 +292,7 @@ def run(write_results=True):
 
 
 if __name__ == "__main__":
+    from paimon_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     run()

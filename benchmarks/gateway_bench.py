@@ -213,4 +213,7 @@ def main() -> None:
 
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from paimon_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     main()

@@ -273,4 +273,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from paimon_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     main()

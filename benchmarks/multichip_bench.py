@@ -364,6 +364,9 @@ def main():
 
 
 if __name__ == "__main__":
+    from paimon_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     if len(sys.argv) >= 2 and sys.argv[1] == "--child":
         child_main(int(sys.argv[2]), sys.argv[3], int(sys.argv[4]))
     else:

@@ -134,4 +134,7 @@ def _cold_pass(table, cache_mod) -> float:
 
 
 if __name__ == "__main__":
+    from paimon_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     main()

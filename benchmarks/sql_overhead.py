@@ -16,9 +16,8 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import bench as B  # repo-root headline-table builder; its import resolves the
-                   # backend ONCE (ensure_live_backend_retrying) — resolving it
-                   # here too would self-conflict on the single-flight lock
+import bench as B  # repo-root headline-table builder; its import enables the
+                   # compile cache and requires the device
 
 PLATFORM = B._PLATFORM
 N = B.N_ROWS

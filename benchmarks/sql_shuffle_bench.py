@@ -38,7 +38,7 @@ The local row is the satellite no-regression guard: single-process
 `sql.query` on the same high-cardinality aggregate (the pure segment-
 reduce path the shuffle must not disturb) against a stated budget.
 
-Headlines (asserted in main, not in run_headline):
+Headlines (asserted in main):
   * coordinator serial combine stage: shuffle >= 2x faster than combine
     at 4 workers
   * end-to-end: >= 2x when the host has >= WORKERS cores, else shuffle
@@ -266,6 +266,8 @@ def _time_local(cat, want) -> float:
 def run(iters: int = ITERS) -> dict:
     global ITERS
     ITERS = iters
+    import jax
+
     from paimon_tpu.sql import query
 
     base = tempfile.mkdtemp(prefix="paimon_sqlshuffle_bench_")
@@ -298,6 +300,10 @@ def run(iters: int = ITERS) -> dict:
         "groups": GROUPS,
         "rows": ROWS,
         "cpu_cores": len(os.sched_getaffinity(0)),
+        # the local pass ran in this process; every coordinator_* / e2e_*
+        # field was timed against workers _child_env pins to the CPU
+        "local_platform": jax.default_backend(),
+        "cluster_platform": "cpu",
         "local_single_process_s": round(local_s, 3),
         "local_budget_s": LOCAL_BUDGET_S,
         # the headline: coordinator serial combine stage (sql{combine_ms})
@@ -312,11 +318,6 @@ def run(iters: int = ITERS) -> dict:
         "kill_recovery": kill,
     }
     return {"row": row}
-
-
-def run_headline(iters: int = 2) -> list:
-    """bench.py hook: reduced iterations; gates live in main() only."""
-    return [run(iters=iters)["row"]]
 
 
 def run_local_headline(iters: int = 3) -> list:
@@ -378,4 +379,7 @@ def main() -> None:
 
 if __name__ == "__main__":
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from paimon_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     main()

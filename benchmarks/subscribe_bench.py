@@ -352,4 +352,7 @@ def main() -> int:
 
 
 if __name__ == "__main__":
+    from paimon_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     raise SystemExit(main())

@@ -24,11 +24,10 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from paimon_tpu.utils import enable_compile_cache
-from paimon_tpu.utils.tpuguard import ensure_live_backend
+from paimon_tpu.utils import enable_compile_cache, require_device
 
 enable_compile_cache()
-PLATFORM = ensure_live_backend()
+PLATFORM, DEVICE_KIND, DEVICE_COUNT = require_device()
 
 BASE = 975_400.0
 
@@ -38,7 +37,7 @@ def emit(metric, value, unit="rows/s", **extra):
         json.dumps(
             {"metric": metric, "value": round(value, 1), "unit": unit,
              "vs_baseline": round(value / BASE, 3) if unit == "rows/s" else None,
-             "platform": PLATFORM, **extra}
+             "platform": PLATFORM, "device_kind": DEVICE_KIND, "devices": DEVICE_COUNT, **extra}
         ),
         flush=True,
     )

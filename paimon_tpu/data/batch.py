@@ -24,6 +24,15 @@ from typing import Any, Iterable, Mapping, Sequence
 
 import numpy as np
 
+# pyarrow is initialised HERE, by whichever thread imports the package, and
+# not lazily by the first worker that touches an arrow array: its default
+# (mimalloc) memory pool is bound to the thread that first imports pyarrow,
+# and once that thread exits — a paimon-flush / paimon-decode pool worker
+# does — the next `pa.array(..., from_pandas=True)` on any other thread
+# segfaults (pyarrow 25.0.0; ARROW_DEFAULT_MEMORY_POOL=system hides it).
+# The function-local `import pyarrow` lines below are then just name lookups.
+import pyarrow  # noqa: F401
+
 from ..types import DataField, DataType, RowType, TypeRoot
 
 __all__ = ["Column", "ColumnBatch", "concat_batches"]

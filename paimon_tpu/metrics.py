@@ -270,18 +270,13 @@ def soak_metrics() -> MetricGroup:
 
 
 def pallas_metrics() -> MetricGroup:
-    """The pallas{...} group (fused merge kernels, paimon_tpu.ops.
+    """The pallas{...} group (the boundary-sweep kernel, paimon_tpu.ops.
     pallas_kernels, routed by sort-engine=pallas). Canonical members —
     counters: kernels_launched (merge dispatches routed through the pallas
-    engine), tiles (pallas grid steps: 1 per fused sort+segment call, one
-    per _BLOCK rows for the post-lax.sort boundary sweep), fallback_xla
-    (dispatches that exceeded the fused kernel's VMEM admission test — or
-    found no pallas at all — and fell back to lax.sort; the boundary sweep
-    still runs in pallas when available); histogram: kernel_ms (wall millis
-    of synchronously-resolved fused dispatches: merge_plan and the fused
-    partial-update/aggregate kernels; async dedup dispatch latency is
-    benchmarked in benchmarks/pallas_bench.py instead). Resolved per call
-    so registry.reset() in tests swaps the group out."""
+    engine), tiles (pallas grid steps: one per _BLOCK rows of the
+    post-lax.sort boundary sweep); histogram: kernel_ms (wall millis of
+    synchronously-resolved dispatches: merge_plan). Resolved per call so
+    registry.reset() in tests swaps the group out."""
     return registry.group("pallas")
 
 

@@ -3,17 +3,19 @@
 The reference has no native code (its hot loops ride the JVM JIT); here the
 device kernels are the main "native" layer, but row-major container formats
 like Avro cannot be columnarized before parsing — so their inner decode loop
-is C. Compiled once per machine into ``_build/`` with ``cc -O3 -shared
--fPIC``; every caller falls back to the pure-python path when no compiler is
-available.
+is C. Compiled once per source version into ``_build/`` with ``cc -O3
+-shared -fPIC``; when no compiler is available every caller falls back to
+the pure-python path, and a warning says so.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+import warnings
 
 import numpy as np
 
@@ -37,10 +39,15 @@ def _load() -> "ctypes.CDLL | None":
     with _LOCK:
         if _LIB is not None:
             return _LIB or None
-        so_path = os.path.join(_BUILD, "avrodec.so")
         src = os.path.join(_DIR, "avrodec.c")
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:12]
+        # keyed on the source's CONTENT: a copied checkout has fresh mtimes
+        # and no _build/ (it is not in git), and an edited source must never
+        # load a stale library
+        so_path = os.path.join(_BUILD, f"avrodec-{digest}.so")
         try:
-            if not os.path.exists(so_path) or os.path.getmtime(so_path) < os.path.getmtime(src):
+            if not os.path.exists(so_path):
                 os.makedirs(_BUILD, exist_ok=True)
                 # build to a private name, publish atomically: a concurrent
                 # process must never dlopen a half-written library
@@ -52,12 +59,20 @@ def _load() -> "ctypes.CDLL | None":
                 )
                 os.replace(tmp_path, so_path)
             lib = ctypes.CDLL(so_path)
-            lib.decode_block.restype = ctypes.c_int
-            lib.encode_block.restype = ctypes.c_int64
-            _LIB = lib
-        except Exception:
+        except (OSError, subprocess.CalledProcessError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            warnings.warn(
+                "paimon_tpu.native: avrodec.c did not build/load, Avro blocks decode "
+                f"in Python: {e!r} {detail.decode(errors='replace')[-500:]}",
+                RuntimeWarning,
+                stacklevel=2,
+            )
             _LIB = False
-        return _LIB or None
+            return None
+        lib.decode_block.restype = ctypes.c_int
+        lib.encode_block.restype = ctypes.c_int64
+        _LIB = lib
+        return lib
 
 
 def native_available() -> bool:

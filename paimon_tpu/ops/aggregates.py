@@ -280,8 +280,11 @@ def fused_routable(specs: list[AggregateSpec], columns: list[Column]) -> bool:
 
 
 def _host_reduce(plan: MergePlan, values: np.ndarray, eff_valid: np.ndarray, fn: str, sign=None):
-    """Exact segmented sum/max/min on host via np reduceat over the sorted
-    order (the f64-on-TPU fallback; same pattern as _product_host)."""
+    """Exact segmented f64 sum/max/min on host over the sorted order (the
+    f64-on-TPU route: the chip emulates f64 and its sums are not exact).
+    Sums accumulate row by row in (key, sequence) order — bincount adds
+    sequentially, where add.reduceat folds a + (b + c) — the reference's
+    FieldSumAgg order and the order the CPU device path produces."""
     order = plan.perm[plan.valid_sorted]
     v = values.take(order)
     ok = eff_valid.take(order)
@@ -289,7 +292,7 @@ def _host_reduce(plan: MergePlan, values: np.ndarray, eff_valid: np.ndarray, fn:
     if fn == "sum":
         s = sign.take(order) if sign is not None else np.ones_like(v)
         contrib = np.where(ok, v * s, np.zeros((), v.dtype))
-        total = np.add.reduceat(contrib, bounds)
+        total = np.bincount(plan.seg_id[plan.valid_sorted], weights=contrib, minlength=len(bounds))
     elif fn == "max":
         contrib = np.where(ok, v, np.full((), -np.inf, v.dtype))
         total = np.maximum.reduceat(contrib, bounds)
@@ -408,7 +411,7 @@ def fused_aggregate(
     if engine == "pallas":
         from .pallas_kernels import note_dispatch
 
-        note_dispatch(m, 1 + k + s)
+        note_dispatch(m)
     outs, anyv, packed, count = _fused_aggregate_fn(k, s, tuple(col_fns), engine)(
         klp, slp, pad, tuple(values), tuple(valids), tuple(signs)
     )
@@ -541,7 +544,7 @@ def segment_reduce(
     if engine == "pallas":
         from .pallas_kernels import note_dispatch
 
-        note_dispatch(m, 1 + k)
+        note_dispatch(m)
     big = np.iinfo(np.int64).max
     outs, anyv, first_pos, packed, count = _segment_reduce_fn(k, tuple(fns), engine)(
         klp,

@@ -1,68 +1,37 @@
-"""Pallas TPU kernels for the merge hot path.
+"""Pallas TPU kernel for the merge hot path.
 
-Two tiers, selected by table option `sort-engine=pallas`
-(CoreOptions.SortEngine); `interpret=True` runs the same kernels on CPU so
-CI proves bit-identical output without hardware:
+Selected by table option `sort-engine=pallas` (CoreOptions.SortEngine): the
+merge keeps the stable lexicographic `lax.sort` and computes the
+run-boundary / keep-last mask with the **boundary-sweep kernel**
+(`keep_last_mask`): a bandwidth-bound elementwise pass detecting segment
+boundaries across all key lanes at once, each grid step loading a block of
+the stacked lanes plus a one-element lookahead. `interpret=True` runs the
+same kernel on CPU so CI proves bit-identical output without hardware; on a
+TPU it is compiled by Mosaic, and a kernel the compiler refuses raises.
 
-1. **Fused sort+segment kernel** (`fused_sort_segments`): the whole inner
-   merge — stable lexicographic sort, run-boundary detection, and the
-   keep-last winner mask — in ONE `pallas_call` over VMEM-resident lanes.
-   The sort is a bitonic compare-exchange network over the stacked
-   (pad, key lanes, seq lanes, iota) matrix: the iota lane rides as the
-   final comparison lane, which makes the strict total order identical to
-   XLA's stable variadic sort, so the permutation AND the segmentation are
-   bit-for-bit the `lax.sort` path's. Unsigned lanes are bijected into
-   sign-flipped int32 space (order-preserving) because Mosaic's integer
-   compares are signed. Boundary detection then folds XORs across the
-   segment lanes of adjacent sorted rows — all while the data never leaves
-   VMEM.
-
-2. **Boundary-sweep kernel** (`keep_last_mask`): the post-`lax.sort`
-   fallback when the fused kernel does not qualify (`fusable`): a
-   bandwidth-bound elementwise pass detecting segment boundaries across all
-   key lanes at once, each grid step loading a block of the stacked lanes
-   plus a one-element lookahead.
-
-The fallback ladder mirrors every other engine in this repo: numpy oracle
-(sort-engine=numpy) == xla-segmented == pallas, asserted per-seed by
-tests/test_pallas_merge.py; when pallas itself is unavailable (import
-failure, oversized batch) the dispatch silently degrades to the
-`lax.sort` path and counts `pallas{fallback_xla}`.
+The engines agree: numpy oracle (sort-engine=numpy) == xla-segmented ==
+pallas, asserted per-seed by tests/test_pallas_merge.py.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-try:  # the pallas import can fail on exotic jax builds: degrade, don't die
-    from jax.experimental import pallas as pl
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover - import-time environment dependent
-    pl = None
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
 
 __all__ = [
     "keep_last_mask",
-    "fused_sort_segments",
-    "fusable",
     "note_dispatch",
     "pallas_interpret",
 ]
 
 _BLOCK = 2048
-
-# fused-kernel admission: rows beyond this take the lax.sort + sweep path
-# (VMEM is ~16 MB/core; the compare network holds (lanes+1) int32 rows plus
-# double-buffered temps). Both knobs are env-tunable for chip experiments.
-_FUSE_MAX_ROWS = int(os.environ.get("PAIMON_TPU_PALLAS_FUSE_ROWS", str(1 << 18)))
-_FUSE_MAX_LANES = int(os.environ.get("PAIMON_TPU_PALLAS_FUSE_LANES", "8"))
-_FUSE_VMEM_BUDGET = 12 * 1024 * 1024
+# block indices are int32 for Mosaic: under the package's x64 mode a bare
+# Python 0 in an index map traces as int64, which it refuses to legalize
+_I0 = np.int32(0)
 
 
 def pallas_interpret() -> bool:
@@ -71,159 +40,15 @@ def pallas_interpret() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def fusable(m: int, num_lanes: int) -> bool:
-    """Static admission test for the fused sort+segment kernel: m must be a
-    power of two (pad_size guarantees it) small enough that the compare
-    network and its temps stay VMEM-resident, with a bounded lane count
-    (each extra lane widens every compare-exchange)."""
-    if not _PALLAS_OK:
-        return False
-    if m < 2 or m & (m - 1):
-        return False
-    if m > _FUSE_MAX_ROWS or num_lanes + 1 > _FUSE_MAX_LANES:
-        return False
-    return (num_lanes + 1) * m * 4 * 3 <= _FUSE_VMEM_BUDGET
-
-
-def note_dispatch(m: int, num_lanes: int, tiles: int | None = None) -> bool:
-    """Host-side metric hook for a sort-engine=pallas dispatch: records the
-    pallas{kernels_launched, tiles, fallback_xla} counters from the SAME
-    admission predicate the traced kernel uses (the decision is static in
-    (m, lanes), so host bookkeeping and trace-time routing cannot drift).
-    Returns whether the fused kernel serves the dispatch."""
+def note_dispatch(m: int) -> None:
+    """Host-side metric hook for a sort-engine=pallas dispatch of m padded
+    rows: pallas{kernels_launched, tiles} (one grid step per _BLOCK rows)."""
     from ..metrics import pallas_metrics
 
+    padded, block = _sweep_block(m)
     g = pallas_metrics()
-    fused = fusable(m, num_lanes)
     g.counter("kernels_launched").inc()
-    if fused:
-        g.counter("tiles").inc(1 if tiles is None else tiles)
-    else:
-        # lax.sort fallback still runs the pallas boundary sweep (one grid
-        # step per _BLOCK rows) when pallas imports at all
-        if _PALLAS_OK:
-            g.counter("tiles").inc(max(1, m // _BLOCK) if tiles is None else tiles)
-        g.counter("fallback_xla").inc()
-    return fused
-
-
-# ---------------------------------------------------------------------------
-# fused sort + run-boundary + keep-last kernel
-# ---------------------------------------------------------------------------
-
-
-def _lex_gt(a, b):
-    """Strict lexicographic a > b over the lane axis (axis 0). The caller
-    stacks an iota lane last, so tuples are distinct and the order total."""
-    gt = jnp.zeros(a.shape[1:], dtype=jnp.bool_)
-    eq = jnp.ones(a.shape[1:], dtype=jnp.bool_)
-    lanes = a.shape[0]
-    for i in range(lanes):
-        ai, bi = a[i], b[i]
-        gt = gt | (eq & (ai > bi))
-        if i + 1 < lanes:
-            eq = eq & (ai == bi)
-    return gt
-
-
-def _bitonic_sort_lanes(arr):
-    """In-kernel bitonic sort of the columns of arr (L, m) int32 by
-    ascending lexicographic row-tuple order; m is a power of two. Each
-    (k, j) stage pairs element i with i^j via the reshape view
-    (L, m/(2j), 2, j) — the partner of (q, 0, r) is (q, 1, r) — and the
-    merge direction comes from bit log2(k) of i, which inside a pair block
-    is constant: (q*2j) & k."""
-    lanes, m = arr.shape
-    k = 2
-    while k <= m:
-        j = k // 2
-        while j >= 1:
-            g = m // (2 * j)
-            v = arr.reshape(lanes, g, 2, j)
-            a = v[:, :, 0, :]
-            b = v[:, :, 1, :]
-            gt = _lex_gt(a, b)
-            q = jax.lax.broadcasted_iota(jnp.int32, (g, j), 0)
-            desc = ((q * (2 * j)) & k) != 0
-            swap = (gt != desc)[None, :, :]
-            na = jnp.where(swap, b, a)
-            nb = jnp.where(swap, a, b)
-            arr = jnp.concatenate([na[:, :, None, :], nb[:, :, None, :]], axis=2).reshape(
-                lanes, m
-            )
-            j //= 2
-        k *= 2
-    return arr
-
-
-@functools.lru_cache(maxsize=None)
-def _fused_kernel(num_boundary: int):
-    """Kernel body for a given boundary-lane count. Input (L+1, m) int32:
-    rows [0, num_boundary) split segments (pad flag + OVC/extra + key
-    lanes), rows [num_boundary, L) order within segments only (sequence
-    lanes), row L is the iota / permutation carry. Output (3, m) int32:
-    row 0 = perm (sorted -> input), row 1 = keep_last (1 at the last row of
-    each segment, pad segments included — the sorted_segments contract),
-    row 2 = the sorted pad+boundary lane 0 (still sign-flipped; the wrapper
-    flips it back)."""
-
-    def kernel(arr_ref, out_ref):
-        arr = _bitonic_sort_lanes(arr_ref[...])
-        m = arr.shape[1]
-        cur = arr[:num_boundary]  # (B, m) sorted segment lanes
-        nxt = jnp.concatenate([cur[:, 1:], cur[:, -1:]], axis=1)
-        xor = cur ^ nxt
-        diff = xor[0:1, :]
-        for i in range(1, num_boundary):
-            diff = diff | xor[i : i + 1, :]
-        keep = jnp.where(diff != 0, jnp.int32(1), jnp.int32(0))  # (1, m)
-        # the global last row has no successor: it always closes its segment
-        pos = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
-        keep = jnp.where(pos == m - 1, jnp.int32(1), keep)
-        out_ref[0:1, :] = arr[-1:, :]  # perm (the iota lane, sorted)
-        out_ref[1:2, :] = keep
-        out_ref[2:3, :] = arr[0:1, :]  # sorted pad lane (flipped space)
-
-    return kernel
-
-
-def _flip(lane):
-    """Order-preserving bijection uint{8,16,32} -> int32 (Mosaic compares
-    are signed; XOR of the sign bit keeps unsigned order)."""
-    return jax.lax.bitcast_convert_type(
-        lane.astype(jnp.uint32) ^ jnp.uint32(0x80000000), jnp.int32
-    )
-
-
-def fused_sort_segments(boundary_lanes, order_lanes):
-    """The fused inner merge (traced inside a consumer jit): stable sort +
-    run-boundary detection + keep-last in one pallas pass.
-
-    boundary_lanes: [(m,) uint] — pad flag first, then OVC/extra keys, then
-    key lanes; these both order rows and split segments. order_lanes:
-    [(m,) uint] sequence lanes — order within a segment only. Returns the
-    sorted_segments contract (pad_sorted, perm, seg_start, keep_last,
-    seg_id), bit-identical to the `lax.sort` path."""
-    m = boundary_lanes[0].shape[0]
-    rows = [_flip(l) for l in list(boundary_lanes) + list(order_lanes)]
-    rows.append(jnp.arange(m, dtype=jnp.int32))
-    arr = jnp.stack(rows, axis=0)
-    out = pl.pallas_call(
-        _fused_kernel(len(boundary_lanes)),
-        out_shape=jax.ShapeDtypeStruct((3, m), jnp.int32),
-        interpret=pallas_interpret(),
-    )(arr)
-    perm = out[0]
-    keep_last = out[1] != 0
-    pad_sorted = jax.lax.bitcast_convert_type(out[2], jnp.uint32) ^ jnp.uint32(0x80000000)
-    seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), keep_last[:-1]])
-    seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
-    return pad_sorted, perm, seg_start, keep_last, seg_id
-
-
-# ---------------------------------------------------------------------------
-# post-sort boundary sweep (the large-batch fallback)
-# ---------------------------------------------------------------------------
+    g.counter("tiles").inc(padded // block)
 
 
 def _keep_last_kernel_factory(mask_pad: bool):
@@ -285,12 +110,12 @@ def keep_last_mask(stacked: jax.Array, interpret: bool = False, mask_pad: bool =
         _keep_last_kernel_factory(mask_pad),
         grid=(grid,),
         in_specs=[
-            pl.BlockSpec((l, block), lambda i: (0, i)),
+            pl.BlockSpec((l, block), lambda i: (_I0, i)),
             # lookahead: the next block (the final block reads itself; the
             # wrapper forces the true last element below)
-            pl.BlockSpec((l, block), lambda i: (0, jnp.minimum(i + 1, last_block))),
+            pl.BlockSpec((l, block), lambda i: (_I0, jnp.minimum(i + 1, jnp.int32(last_block)))),
         ],
-        out_specs=pl.BlockSpec((1, block), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((1, block), lambda i: (_I0, i)),
         out_shape=jax.ShapeDtypeStruct((1, m2), jnp.uint32),
         interpret=interpret,
     )(stacked, stacked)

@@ -194,7 +194,7 @@ class ChangelogProducer(str, enum.Enum):
 
 class SortEngine(str, enum.Enum):
     XLA_SEGMENTED = "xla-segmented"  # device sort+segment-reduce (default)
-    PALLAS = "pallas"  # lax.sort + pallas fused boundary/keep-last pass
+    PALLAS = "pallas"  # lax.sort + pallas boundary/keep-last sweep kernel
     NUMPY = "numpy"  # host oracle
 
 

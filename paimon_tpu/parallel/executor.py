@@ -27,6 +27,7 @@ from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
+from jax import shard_map
 
 __all__ = [
     "MeshBatchContext",
@@ -142,19 +143,6 @@ class _KernelCache:
 _KERNELS = _KernelCache()
 
 
-def _shard_map():
-    import jax
-
-    try:
-        from jax import shard_map as mod
-
-        return mod.shard_map if hasattr(mod, "shard_map") else mod
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 def _make_batched_dedup(mesh, k: int, s: int):
     """(B, m, K) uint32 key lanes, (B, m, S) seq lanes, (B, m) pad ->
     per-bucket packed selected input indices + counts, buckets sharded over
@@ -172,7 +160,7 @@ def _make_batched_dedup(mesh, k: int, s: int):
     def shard_fn(kl, sl, pf):
         return jax.vmap(per_bucket)(kl, sl, pf)
 
-    fn = _shard_map()(
+    fn = shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P("bucket", None, None), P("bucket", None, None), P("bucket", None)),
@@ -197,7 +185,7 @@ def _make_batched_plan(mesh, k: int, s: int):
     def shard_fn(kl, sl, pf):
         return jax.vmap(per_bucket)(kl, sl, pf)
 
-    fn = _shard_map()(
+    fn = shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P("bucket", None, None), P("bucket", None, None), P("bucket", None)),
@@ -230,7 +218,7 @@ def _make_key_axis_dedup(mesh, k: int, s: int):
         rowids = rs[s][perm]
         return jnp.where(sel, rowids, sentinel)
 
-    fn = _shard_map()(
+    fn = shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=(P("key", None), P("key", None), P("key")),

@@ -22,14 +22,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 promotes shard_map
-    from jax import shard_map as _shard_map_mod
-
-    shard_map = _shard_map_mod.shard_map if hasattr(_shard_map_mod, "shard_map") else _shard_map_mod
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from ..ops.merge import _plan_fn
 

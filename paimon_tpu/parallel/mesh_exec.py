@@ -30,10 +30,9 @@ Three properties distinguish it from the older `MeshBatchContext`
   rewrite_dispatch), so IO + decode of shard i+1 overlap the batched device
   merge of shard i.
 
-  CPU FALLBACK — gated behind `merge.engine = mesh` (default `single`); a
-  1-device or shard_map-less environment silently degrades to the existing
-  single-device path, bit-identically (the SNIPPETS pjit_with_cpu_fallback
-  pattern applied at the executor seam rather than per-kernel).
+  ONE DEVICE — gated behind `merge.engine = mesh` (default `single`); with
+  a single visible device there is nothing to shard over and the existing
+  single-device path runs, bit-identically.
 
 Observability: the mesh{buckets_sharded, shards, pad_rows, exchange_rows,
 device_busy_ms, feeder_wait_ms} metric group, surfaced as a breakdown line
@@ -66,27 +65,20 @@ def _metrics():
 
 
 def mesh_available() -> bool:
-    """True when the process can actually shard: >= 2 visible devices and an
-    importable shard_map. Everything else falls back to the single-device
-    path — callers never see a partially-working mesh."""
-    try:
-        from .merge import shard_map  # noqa: F401  (import proves availability)
-    except Exception:  # pragma: no cover - jax without shard_map
-        return False
-    try:
-        import jax
+    """True when the process can actually shard: >= 2 visible devices. One
+    device runs the single-device path — the same kernels, nothing to shard
+    over. A backend that fails to initialise raises here, like everywhere."""
+    import jax
 
-        return len(jax.devices()) >= 2
-    except Exception:  # pragma: no cover - no backend at all
-        return False
+    return len(jax.devices()) >= 2
 
 
 def resolve_merge_engine(options) -> str:
     """One resolution order everywhere: the PAIMON_TPU_MERGE_ENGINE env var
     (verify stages force both paths) beats the table's `merge.engine` option,
     which beats the default (`single`). Returns "mesh" or "single"; "mesh"
-    still degrades to single at the call sites when mesh_available() is
-    False — that IS the cpu-fallback contract."""
+    runs the single-device path at the call sites when only one device is
+    visible (mesh_available())."""
     env = os.environ.get("PAIMON_TPU_MERGE_ENGINE", "").strip().lower()
     if env in ("mesh", "single"):
         return env

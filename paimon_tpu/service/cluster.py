@@ -2806,6 +2806,9 @@ def worker_main(args) -> int:
 
     from ..parallel import distributed
     from ..table import load_table
+    from ..utils import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.table.startswith(("fail:", "fail-s3", "latency:", "traceable:", "chaos:")):
         # test-harness schemes register on import (the chaos scheme also

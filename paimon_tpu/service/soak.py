@@ -763,7 +763,7 @@ class SoakHarness:
         def spawn() -> subprocess.Popen:
             remaining = max(deadline - time.monotonic(), 1.0)
             env = dict(os.environ)
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_PLATFORMS"] = "cpu"  # the parent may hold the chip
             return subprocess.Popen(
                 [
                     sys.executable,

@@ -131,12 +131,12 @@
 #                              native — every natively-written file must
 #                              read back bit-identically through BOTH the
 #                              native decoder and pyarrow.
-#   scripts/verify.sh pallas   fused-merge-kernel parity stage: the
+#   scripts/verify.sh pallas   pallas sort-engine parity stage: the
 #                              tests/test_pallas_merge.py randomized suite
 #                              plus the merge-kernel + whole-store oracles
 #                              run TWICE — PAIMON_TPU_SORT_ENGINE forced
 #                              pallas (interpret mode on CPU), then
-#                              xla-segmented — so the fused pallas kernels
+#                              xla-segmented — so the pallas sweep kernel
 #                              and the stock XLA path both prove
 #                              bit-identical merge output end to end.
 #   scripts/verify.sh gateway  multi-tenant gateway stage: the gateway
@@ -219,7 +219,7 @@ if [ "${1:-}" = "faults" ]; then
   # mesh engine + code-domain merge + pallas sort engine forced ON: the
   # fault matrix (transient retries, crash points, torn writes) must stay
   # green through the mesh-sharded executor, its feeder workers, the
-  # dictionary-code merge currency, and the fused pallas kernels on every
+  # dictionary-code merge currency, and the pallas sweep kernel on every
   # single-device merge (ISSUE 7 / ISSUE 10 / ISSUE 11)
   exec env JAX_PLATFORMS=cpu PAIMON_TPU_FAULT_SEEDS="0 1 2 3 4" PAIMON_TPU_PARQUET_ENCODER=native \
     PAIMON_TPU_LANE_COMPRESSION=1 PAIMON_TPU_MERGE_ENGINE=mesh PAIMON_TPU_DICT_DOMAIN=1 \
@@ -388,7 +388,7 @@ if [ "${1:-}" = "encode" ]; then
 fi
 
 if [ "${1:-}" = "pallas" ]; then
-  # parity suites with the sort engine forced pallas (fused kernels, CPU
+  # parity suites with the sort engine forced pallas (sweep kernel, CPU
   # via interpret=True), then xla-segmented: both sides of the sort-engine
   # switch must produce bit-identical merge output (tables that explicitly
   # chose an engine keep it — the env only pins the undecided)

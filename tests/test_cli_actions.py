@@ -2,6 +2,7 @@
 reference's flink-action surface (flink/action/, 47 actions + procedures)."""
 
 import json
+import os
 import subprocess
 import sys
 
@@ -10,13 +11,15 @@ import pytest
 from paimon_tpu.catalog import FileSystemCatalog
 from paimon_tpu.types import BIGINT, DOUBLE, RowType
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 SCHEMA = RowType.of(("id", BIGINT()), ("v", DOUBLE()))
 
 
 def run_cli(*argv):
     r = subprocess.run(
         [sys.executable, "-m", "paimon_tpu", *argv],
-        capture_output=True, text=True, timeout=180, cwd="/root/repo",
+        capture_output=True, text=True, timeout=180, cwd=REPO_ROOT,
         env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu", "HOME": "/root",
              "JAX_ENABLE_X64": "true"},
     )

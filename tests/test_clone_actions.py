@@ -8,6 +8,7 @@ MarkPartitionDoneAction}.java."""
 
 import datetime
 import json
+import os
 import subprocess
 import sys
 
@@ -18,13 +19,15 @@ from paimon_tpu.catalog import FileSystemCatalog
 from paimon_tpu.table import clone as C
 from paimon_tpu.types import BIGINT, DOUBLE, STRING, RowType
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 SCHEMA = RowType.of(("id", BIGINT()), ("v", DOUBLE()), ("s", STRING()))
 
 
 def run_cli(*argv):
     r = subprocess.run(
         [sys.executable, "-m", "paimon_tpu", *argv],
-        capture_output=True, text=True, timeout=180, cwd="/root/repo",
+        capture_output=True, text=True, timeout=180, cwd=REPO_ROOT,
         env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu", "HOME": "/root",
              "JAX_ENABLE_X64": "true"},
     )
@@ -222,7 +225,7 @@ def test_query_service_cli(src, tmp_path):
     proc = sp.Popen(
         [_sys.executable, "-m", "paimon_tpu", "query-service",
          "--warehouse", str(tmp_path / "src"), "--table", "db.t"],
-        stdout=sp.PIPE, stderr=sp.PIPE, text=True, cwd="/root/repo",
+        stdout=sp.PIPE, stderr=sp.PIPE, text=True, cwd=REPO_ROOT,
         env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu", "HOME": "/root"},
     )
     try:

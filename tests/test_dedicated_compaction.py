@@ -2,6 +2,7 @@
 CompactorSink.java, AppendOnlyTableCompactionCoordinator.java): write-only
 ingest + a separate compactor, racing safely on one table."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -16,6 +17,8 @@ from paimon_tpu.table.compactor import (
     execute_compaction_task,
 )
 from paimon_tpu.types import BIGINT, DOUBLE, RowType
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SCHEMA = RowType.of(("k", BIGINT()), ("v", DOUBLE()))
 
@@ -151,9 +154,9 @@ def test_ingest_and_compactor_processes_race(tmp_warehouse):
         print("compactor done", done)
     """)
     env = {"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu", "HOME": "/root"}
-    pw = subprocess.Popen([sys.executable, "-c", writer_code], cwd="/root/repo", env=env,
+    pw = subprocess.Popen([sys.executable, "-c", writer_code], cwd=REPO_ROOT, env=env,
                           stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-    pc = subprocess.Popen([sys.executable, "-c", compactor_code], cwd="/root/repo", env=env,
+    pc = subprocess.Popen([sys.executable, "-c", compactor_code], cwd=REPO_ROOT, env=env,
                           stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
     ow, ew = pw.communicate(timeout=240)
     oc, ec = pc.communicate(timeout=240)
@@ -230,9 +233,9 @@ def test_writer_and_compactor_processes_under_fault_injection(tmp_warehouse):
         print("COMPACTOR", done)
     """)
     env = {"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu", "HOME": "/root"}
-    pw = subprocess.Popen([sys.executable, "-c", writer_code], cwd="/root/repo", env=env,
+    pw = subprocess.Popen([sys.executable, "-c", writer_code], cwd=REPO_ROOT, env=env,
                           stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
-    pc = subprocess.Popen([sys.executable, "-c", compactor_code], cwd="/root/repo", env=env,
+    pc = subprocess.Popen([sys.executable, "-c", compactor_code], cwd=REPO_ROOT, env=env,
                           stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
     ow, ew = pw.communicate(timeout=300)
     oc, ec = pc.communicate(timeout=300)

@@ -3,6 +3,7 @@ docker e2e stands in for this — here separate OS processes share only the
 filesystem, proving snapshot isolation and the commit protocol across
 process boundaries)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -11,6 +12,8 @@ import pytest
 
 from paimon_tpu.catalog import FileSystemCatalog
 from paimon_tpu.types import BIGINT, DOUBLE, RowType
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SCHEMA = RowType.of(("k", BIGINT()), ("v", DOUBLE()))
 
@@ -21,7 +24,7 @@ def run_py(code: str) -> str:
         capture_output=True,
         text=True,
         timeout=120,
-        cwd="/root/repo",
+        cwd=REPO_ROOT,
         env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu", "HOME": "/root"},
     )
     assert r.returncode == 0, r.stderr

@@ -30,6 +30,8 @@ import pytest
 from paimon_tpu.catalog import FileSystemCatalog
 from paimon_tpu.types import BIGINT, RowType
 
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 N_PER_PROC = 3_000
 
 WORKER = textwrap.dedent(
@@ -189,7 +191,7 @@ def _spawn(pid: int, port: int, wh: str, hand: str, crash: str | None, wait_s: s
     return subprocess.Popen(
         [sys.executable, "-c", WORKER],
         env=env,
-        cwd="/root/repo",
+        cwd=REPO_ROOT,
         stdout=subprocess.PIPE,
         stderr=subprocess.PIPE,
         text=True,
@@ -264,7 +266,7 @@ def test_two_process_stream_rounds_and_replay_idempotence(tmp_warehouse, dist_ta
             "PT_N": str(N_PER_PROC),
         }
         procs.append(subprocess.Popen(
-            [sys.executable, "-c", WORKER_STREAM], env=env, cwd="/root/repo",
+            [sys.executable, "-c", WORKER_STREAM], env=env, cwd=REPO_ROOT,
             stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         ))
     outs = [p.communicate(timeout=300) for p in procs]

@@ -385,6 +385,51 @@ def test_aggregation_64bit_exactness(rng):
     assert row[2] == d_vals[0] + d_vals[1] + d_vals[2]  # exact f64 association order
 
 
+def test_f64_host_route_accumulates_in_sequence_order(rng, monkeypatch):
+    """The f64-leaves-the-device route (ops/aggregates._host_reduce) is only
+    live where default_backend() == "tpu"; forced here so the CPU suite pins
+    it: DOUBLE sum/max/min per key must equal a row-by-row fold in
+    (key, sequence) order — values chosen so a + (b + c) != (a + b) + c."""
+    from paimon_tpu.core.kv import KVBatch
+    from paimon_tpu.data.batch import ColumnBatch
+    from paimon_tpu.ops import aggregates
+    from paimon_tpu.types import BIGINT, DOUBLE, RowType
+
+    monkeypatch.setattr(aggregates, "_f64_on_device_unsupported", lambda: True)
+    schema = RowType.of(("id", BIGINT()), ("big", BIGINT()), ("s", DOUBLE()), ("hi", DOUBLE()), ("lo", DOUBLE()))
+    n = 600
+    ids = rng.integers(0, 40, n)
+    big = rng.integers(1_000_000_000, 4_000_000_000, n)
+    vals = rng.normal(size=n) * 10.0 ** rng.integers(-8, 8, n)
+    cols = {"id": ids.tolist(), "big": big.tolist()}
+    for name in ("s", "hi", "lo"):
+        cols[name] = [None if i % 7 == 3 else float(v) for i, v in enumerate(vals)]
+    kv = KVBatch.from_rows(ColumnBatch.from_pydict(schema, cols), 0)
+    opts = {
+        "fields.big.aggregate-function": "sum",
+        "fields.s.aggregate-function": "sum",
+        "fields.hi.aggregate-function": "max",
+        "fields.lo.aggregate-function": "min",
+    }
+    out = _mk_exec(schema, ["id"], "aggregation", opts).merge(kv, seq_ascending=True)
+    want = {}
+    for i in range(n):  # the sequential oracle: input order is sequence order
+        acc = want.setdefault(int(ids[i]), [0, None, None, None])
+        acc[0] += int(big[i])
+        if i % 7 != 3:
+            v = float(vals[i])
+            acc[1] = v if acc[1] is None else acc[1] + v
+            acc[2] = v if acc[2] is None else max(acc[2], v)
+            acc[3] = v if acc[3] is None else min(acc[3], v)
+    assert [tuple(r) for r in out.data.to_pylist()] == [(k, *want[k]) for k in sorted(want)]
+    # the oracle really is order-sensitive: a pairwise fold disagrees somewhere
+    assert any(
+        want[k][1] != float(np.sum([float(vals[i]) for i in range(n) if ids[i] == k and i % 7 != 3][::-1]))
+        for k in want
+        if want[k][1] is not None
+    )
+
+
 def test_lane_narrowing_preserves_selection(rng):
     """Range-narrowed (u8/u16) lane upload selects EXACTLY the same rows as
     the wide u32 path — a constant shift + downcast preserves order and

@@ -2,7 +2,8 @@
 parity against the single-device path, global lane planning, key-axis
 range-shuffle, feeder behavior, and the cpu fallback (ISSUE 7).
 
-Everything here runs on the 8-device virtual CPU mesh the conftest forces;
+Everything here runs on the 8-device virtual CPU mesh the conftest forces
+(or, under PAIMON_TEST_PLATFORM=tpu, on the chips of a multi-chip host);
 the contract under test is BIT-IDENTICAL output: a mesh table and a
 single-engine table fed the same rows must read back equal, row for row, in
 order — across merge engines, bucket counts that don't divide the mesh
@@ -20,7 +21,7 @@ from paimon_tpu.catalog import FileSystemCatalog
 from paimon_tpu.metrics import mesh_metrics, registry
 
 pytestmark = pytest.mark.skipif(
-    len(jax.devices()) < 8, reason="needs 8 devices (virtual CPU mesh or a pod slice)"
+    len(jax.devices()) < 2, reason="needs two or more devices (the virtual CPU mesh or a multi-chip host)"
 )
 
 # scripts/verify.sh mesh runs this suite twice, forcing merge.engine both

@@ -3,6 +3,7 @@ flat namespace, and the full table stack + commit protocol over it, including
 cross-process races (reference: paimon-filesystems/paimon-s3 +
 FileStoreCommitImpl.java:948-957 commit-under-lock-with-exists-check)."""
 
+import os
 import subprocess
 import sys
 import textwrap
@@ -15,6 +16,8 @@ from paimon_tpu.catalog import FileSystemCatalog
 from paimon_tpu.fs import get_file_io
 from paimon_tpu.fs.object_store import ObjectStoreFileIO
 from paimon_tpu.types import BIGINT, DOUBLE, RowType
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 SCHEMA = RowType.of(("k", BIGINT()), ("v", DOUBLE()))
 
@@ -149,7 +152,7 @@ def run_py(code: str, check: bool = True) -> subprocess.CompletedProcess:
         capture_output=True,
         text=True,
         timeout=180,
-        cwd="/root/repo",
+        cwd=REPO_ROOT,
         env={"PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu", "HOME": "/root"},
     )
     if check:

@@ -1,8 +1,7 @@
-"""Fused pallas merge kernel parity: pallas(interpret) == xla-segmented ==
-numpy oracle, bit for bit, across seeds x key shapes x null rates x
-lane-compression on/off x dict-domain on/off — both pallas tiers (the fused
-in-VMEM bitonic kernel and the lax.sort + boundary-sweep fallback above the
-VMEM cap). The `scripts/verify.sh pallas` stage runs this file (plus the
+"""Pallas merge engine parity: pallas (interpreted on the CPU, compiled by
+Mosaic under PAIMON_TEST_PLATFORM=tpu) == xla-segmented == numpy oracle, bit
+for bit, across seeds x key shapes x null rates x lane-compression on/off x
+dict-domain on/off. The `scripts/verify.sh pallas` stage runs this file (plus the
 merge-kernel and whole-store oracles) with PAIMON_TPU_SORT_ENGINE forced
 pallas and then xla-segmented."""
 
@@ -73,12 +72,9 @@ def test_dedup_parity_with_seq_lanes(seed):
 
 
 @pytest.mark.parametrize("seed", range(3))
-def test_sweep_tier_parity(monkeypatch, seed):
-    """Above the fused kernel's VMEM cap the pallas engine keeps lax.sort
-    and computes boundaries with the sweep kernel — same contract. The cap
-    is forced down so the tier runs at test sizes (fresh local jits: the
-    admission decision is baked per trace)."""
-    monkeypatch.setattr(pk, "_FUSE_MAX_ROWS", 1)
+def test_sweep_tier_parity(seed):
+    """The pallas engine keeps lax.sort and computes boundaries with the
+    sweep kernel — the full sorted_segments contract, array for array."""
     rng = np.random.default_rng(200 + seed)
     n = int(rng.integers(5, 2000))
     lanes = _rand_lanes(rng, n, "two")
@@ -87,7 +83,6 @@ def test_sweep_tier_parity(monkeypatch, seed):
     kl[:, :n] = lanes.T
     pad = np.zeros(m, dtype=np.uint32)
     pad[n:] = 1
-    assert not pk.fusable(m, 3)
 
     def run(engine):
         @jax.jit
@@ -98,16 +93,6 @@ def test_sweep_tier_parity(monkeypatch, seed):
 
     for a, b in zip(run("xla"), run("pallas")):
         assert (a == b).all()
-
-
-def test_fused_tier_actually_fuses():
-    """Below the cap the pallas engine must route the fused kernel, not the
-    sweep: fusable() is the single admission predicate both the trace and
-    the metric hook use."""
-    assert pk.fusable(4096, 3)
-    assert not pk.fusable(4097, 3)  # not a power of two
-    assert not pk.fusable(1 << 19, 3)  # above the row cap
-    assert not pk.fusable(4096, 20)  # too many lanes
 
 
 # ---------------------------------------------------------------------------
@@ -218,7 +203,7 @@ def test_keep_last_mask_non_multiple_sizes(m):
     keys = np.sort(rng.integers(0, max(2, m // 3), m)).astype(np.uint32)
     pad = np.zeros(m, dtype=np.uint32)
     stacked = np.stack([pad, keys])
-    out = np.asarray(pk.keep_last_mask(stacked, interpret=True))
+    out = np.asarray(pk.keep_last_mask(stacked, interpret=pk.pallas_interpret()))
     if m == 1:
         expect = np.ones(1, np.uint32)
     else:
@@ -232,8 +217,8 @@ def test_keep_last_mask_pad_contract():
     keys = np.array([1, 1, 2, 0, 0], dtype=np.uint32)  # 2 valid keys + pads
     pad = np.array([0, 0, 0, 1, 1], dtype=np.uint32)
     stacked = np.stack([pad, keys])
-    masked = np.asarray(pk.keep_last_mask(stacked, interpret=True, mask_pad=True))
-    raw = np.asarray(pk.keep_last_mask(stacked, interpret=True, mask_pad=False))
+    masked = np.asarray(pk.keep_last_mask(stacked, interpret=pk.pallas_interpret(), mask_pad=True))
+    raw = np.asarray(pk.keep_last_mask(stacked, interpret=pk.pallas_interpret(), mask_pad=False))
     assert masked.tolist() == [0, 1, 1, 0, 0]
     assert raw.tolist() == [0, 1, 1, 0, 1]
 
@@ -243,12 +228,11 @@ def test_note_dispatch_metrics():
 
     with registry._lock:
         registry.groups.pop(("pallas", ()), None)
-    assert pk.note_dispatch(4096, 3) is True
-    assert pk.note_dispatch(1 << 19, 3) is False
+    pk.note_dispatch(1000)
+    pk.note_dispatch(1 << 19)
     snap = registry.snapshot()["pallas"]
     assert snap["kernels_launched"] == 2
-    assert snap["fallback_xla"] == 1
-    assert snap["tiles"] >= 1 + (1 << 19) // 2048
+    assert snap["tiles"] == 1 + (1 << 19) // 2048
 
 
 # ---------------------------------------------------------------------------
