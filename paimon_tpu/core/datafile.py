@@ -370,6 +370,26 @@ class KeyValueFileReaderFactory:
         fields: Sequence[str] | None,
         system_columns: bool | str,
     ) -> KVBatch:
+        from ..metrics import datafile_metrics, span
+
+        which = "all" if fields is None else ("values" if system_columns is False else "keys")
+        with span("decode.file", format=meta.file_name.rsplit(".", 1)[-1], **{"pass": which}) as sp:
+            kv = self._decode_file(meta, predicate, fields, system_columns)
+            nbytes = kv.byte_size()
+            sp.add(columns=len(kv.data.schema.fields), rows=kv.num_rows, bytes=nbytes)
+        g = datafile_metrics()
+        g.counter("files_decoded").inc()
+        g.counter("rows_decoded").inc(kv.num_rows)
+        g.counter("bytes_decoded").inc(nbytes)
+        return kv
+
+    def _decode_file(
+        self,
+        meta: DataFileMeta,
+        predicate: Predicate | None,
+        fields: Sequence[str] | None,
+        system_columns: bool | str,
+    ) -> KVBatch:
         data_schema = self.schemas_by_id[meta.schema_id]
         disk_schema = kv_disk_schema(data_schema) if self.keyed else data_schema
         read_fields = (

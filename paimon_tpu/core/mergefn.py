@@ -17,6 +17,7 @@ import numpy as np
 
 from ..data.batch import Column, ColumnBatch
 from ..data.keys import build_string_pool, encode_key_lanes, split_int64_lanes
+from ..metrics import span
 from ..options import CoreOptions, MergeEngine
 from ..ops import (
     AggregateSpec,
@@ -121,12 +122,23 @@ class MergeExecutor:
     def _key_lanes(self, kv: KVBatch) -> np.ndarray:
         from ..data.keys import encode_key_lanes_with_pools
 
-        return encode_key_lanes_with_pools(kv.data, self.key_names)
+        with span("lanes.encode", rows=kv.num_rows) as sp:
+            lanes = encode_key_lanes_with_pools(kv.data, self.key_names)
+            sp.add(lanes=lanes.shape[1])
+        return lanes
 
     def _lanes(self, kv: KVBatch, seq_ascending: bool) -> tuple[np.ndarray, np.ndarray | None]:
         return self._key_lanes(kv), self._seq_lanes(kv, seq_ascending)
 
     def _seq_lanes(self, kv: KVBatch, seq_ascending: bool) -> np.ndarray | None:
+        if seq_ascending and not self._user_seq:
+            return None
+        with span("lanes.encode", rows=kv.num_rows) as sp:
+            lanes = self._encode_seq_lanes(kv, seq_ascending)
+            sp.add(lanes=lanes.shape[1])
+        return lanes
+
+    def _encode_seq_lanes(self, kv: KVBatch, seq_ascending: bool) -> np.ndarray:
         seq_parts = []
         if self._user_seq:
             # user-defined sequence fields order before the system seqno
@@ -144,7 +156,7 @@ class MergeExecutor:
             # encode them (stability of the device sort covers the rest)
             hi, lo = split_int64_lanes(kv.seq)
             seq_parts.append(np.stack([hi, lo], axis=1))
-        return np.concatenate(seq_parts, axis=1) if seq_parts else None
+        return np.concatenate(seq_parts, axis=1)
 
     @staticmethod
     def _strictly_increasing(lanes: np.ndarray) -> bool:
@@ -231,14 +243,10 @@ class MergeExecutor:
             backend = "pallas" if engine == SortEngine.PALLAS else "xla"
             from ..ops.merge import deduplicate_resolve, deduplicate_select_async
 
-            return (
-                "sync",
-                kv.take(
-                    deduplicate_resolve(
-                        deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress)
-                    )
-                ),
+            take = deduplicate_resolve(
+                deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress)
             )
+            return ("sync", self.gather(kv, take))
         lanes, seq_lanes = self._lanes(kv, seq_ascending)
         engine = self.effective_sort_engine()
         if ctx is not None and engine != SortEngine.NUMPY:
@@ -281,8 +289,13 @@ class MergeExecutor:
             return handle[1]
         _, ctx, job_id, kv = handle
         if tag == "dedup":
-            return kv.take(ctx.result(job_id))
+            return self.gather(kv, ctx.result(job_id))
         return self._merge_with_plan(kv, ctx.result(job_id))
+
+    @staticmethod
+    def gather(kv: KVBatch, take: np.ndarray) -> KVBatch:
+        with span("gather", rows_in=kv.num_rows, rows_out=len(take), columns=len(kv.data.schema.fields)):
+            return kv.take(take)
 
     def supports_keys_only_pipeline(self) -> bool:
         """True when merge needs only (key cols, seq, kind) to pick winners —

@@ -13,8 +13,9 @@ from typing import Sequence
 
 import numpy as np
 
-from ..data.batch import ColumnBatch, concat_batches
+from ..data.batch import Column, ColumnBatch, concat_batches
 from ..data.predicate import Predicate, PredicateBuilder, and_
+from ..metrics import span
 from .datafile import DataFileMeta, KeyValueFileReaderFactory
 from .kv import KVBatch
 from .levels import IntervalPartition
@@ -124,8 +125,6 @@ class MergeFileSplitRead:
         batch window execute as ONE shard_map over the mesh's bucket axis —
         the TPU equivalent of the reference shipping one split per task
         (MergeTreeSplitGenerator.java:38)."""
-        from ..parallel.executor import current_mesh_context
-
         key_parts = []
         if predicate is not None:
             parts = PredicateBuilder.split_and(predicate)
@@ -133,48 +132,55 @@ class MergeFileSplitRead:
         key_filter = and_(*key_parts) if key_parts else None
 
         dvs = deletion_vectors or {}
-        sections = IntervalPartition(files).partition()
-        section_conts = []
-        for section in sections:
-            if len(section) == 1:
-                # single sorted run: keys are unique — no merge needed; full
-                # predicate pushdown is safe (reference RawFileSplitRead)
-                kv_parts = _parallel_map(
-                    lambda f: self._read_file(f, predicate, dvs),
-                    section[0].files,
-                    parallelism=self.parallelism,
-                )
-                kv = KVBatch.concat(kv_parts)
-                section_conts.append(lambda kv=kv: kv)
-            else:
-                runs, seq_ascending = order_runs_for_merge(section)
-                ordered_files = [f for run in runs for f in run.files]
-                has_dv = any(f.file_name in dvs for f in ordered_files)
-                if (
-                    current_mesh_context() is None
-                    and self.merge.supports_keys_only_pipeline()
-                    and not has_dv
-                ):
-                    # single-device: overlap host decode with the device sort
-                    kv = self._pipelined_dedup(ordered_files, key_filter, seq_ascending)
-                    section_conts.append(lambda kv=kv: kv)
-                else:
-                    # mesh/DV/engine path: the per-file reads fan out over the
-                    # shared pool (order preserved, so the concatenated runs
-                    # and the merge output are bit-identical to serial)
-                    batches = _parallel_map(
-                        lambda f: self._read_file(f, key_filter, dvs),
-                        ordered_files,
-                        parallelism=self.parallelism,
-                    )
-                    kv = KVBatch.concat(batches)
-                    handle = self.merge.merge_async(kv, seq_ascending=seq_ascending)
-                    section_conts.append(lambda h=handle: self.merge.merge_resolve(h))
+        with span("split", files=len(files)) as sp:
+            sections = IntervalPartition(files).partition()
+            sp.add(sections=len(sections))
+            section_conts = [self._dispatch_section(section, predicate, key_filter, dvs) for section in sections]
 
         def complete() -> ColumnBatch:
-            out: list[ColumnBatch] = []
-            for cont in section_conts:
-                kv = cont()
+            with span("split", files=len(files), sections=len(sections)):
+                return self._complete(section_conts, predicate, projection, drop_delete)
+
+        return complete
+
+    def _dispatch_section(self, section, predicate, key_filter, dvs: dict):
+        """Read one section's inputs and dispatch its merge; returns the
+        zero-arg continuation that gives the section's merged KVBatch."""
+        from ..parallel.executor import current_mesh_context
+
+        if len(section) == 1:
+            # single sorted run: keys are unique — no merge needed; full
+            # predicate pushdown is safe (reference RawFileSplitRead)
+            kv = self._read_files(section[0].files, predicate, dvs)
+            return lambda: kv
+        runs, seq_ascending = order_runs_for_merge(section)
+        ordered_files = [f for run in runs for f in run.files]
+        has_dv = any(f.file_name in dvs for f in ordered_files)
+        if current_mesh_context() is None and self.merge.supports_keys_only_pipeline() and not has_dv:
+            # single-device: overlap host decode with the device sort
+            kv = self._pipelined_dedup(ordered_files, key_filter, seq_ascending)
+            return lambda: kv
+        # mesh/DV/engine path
+        kv = self._read_files(ordered_files, key_filter, dvs)
+        handle = self.merge.merge_async(kv, seq_ascending=seq_ascending)
+        return lambda: self.merge.merge_resolve(handle)
+
+    def _read_files(self, files, predicate, dvs: dict) -> KVBatch:
+        """Whole files, concatenated in the order given: the per-file reads
+        fan out over the shared pool (order preserved, so the concatenated
+        runs and the merge output are bit-identical to serial)."""
+        with span("decode.all", files=len(files)):
+            parts = _parallel_map(lambda f: self._read_file(f, predicate, dvs), files, parallelism=self.parallelism)
+        with span("concat", rows=sum(p.num_rows for p in parts), columns=len(self.reader_factory.read_schema.fields)):
+            return KVBatch.concat(parts)
+
+    def _complete(self, section_conts, predicate, projection, drop_delete: bool) -> ColumnBatch:
+        """Phase 2: resolve every section's merge, then drop deletes, apply
+        the predicate and the projection, and concatenate the sections."""
+        out: list[ColumnBatch] = []
+        for cont in section_conts:
+            kv = cont()
+            with span("finish", rows=kv.num_rows):
                 if drop_delete:
                     kv = kv.drop_deletes()
                 data = kv.data
@@ -184,15 +190,14 @@ class MergeFileSplitRead:
                         data = data.filter(mask)
                 if projection is not None:
                     data = data.select(projection)
-                out.append(data)
-            if not out:
-                schema = self.reader_factory.read_schema
-                if projection is not None:
-                    schema = schema.project(projection)
-                return ColumnBatch.empty(schema)
+            out.append(data)
+        if not out:
+            schema = self.reader_factory.read_schema
+            if projection is not None:
+                schema = schema.project(projection)
+            return ColumnBatch.empty(schema)
+        with span("concat", rows=sum(b.num_rows for b in out), columns=len(out[0].schema.fields)):
             return concat_batches(out)
-
-        return complete
 
     def _read_file(self, f: DataFileMeta, predicate, dvs: dict) -> KVBatch:
         """Read one file, applying its deletion vector if present. DV
@@ -216,12 +221,14 @@ class MergeFileSplitRead:
         # disjoint+ordered: skip decoding _SEQUENCE_NUMBER (random int64 is
         # the costliest system column) and read only _VALUE_KIND
         sys_cols = "kind" if seq_ascending else True
-        heads = _parallel_map(
-            lambda f: self.reader_factory.read(f, predicate=key_filter, fields=key_names, system_columns=sys_cols),
-            ordered_files,
-            parallelism=self.parallelism,
-        )
-        kv_keys = KVBatch.concat(heads)
+        with span("decode.keys", files=len(ordered_files)):
+            heads = _parallel_map(
+                lambda f: self.reader_factory.read(f, predicate=key_filter, fields=key_names, system_columns=sys_cols),
+                ordered_files,
+                parallelism=self.parallelism,
+            )
+        with span("concat", rows=sum(h.num_rows for h in heads), columns=len(key_names)):
+            kv_keys = KVBatch.concat(heads)
         if kv_keys.num_rows == 0:
             return KVBatch(
                 ColumnBatch.empty(self.reader_factory.read_schema),
@@ -235,28 +242,28 @@ class MergeFileSplitRead:
             run_offsets.append(run_offsets[-1] + h.num_rows)
         handle = self.merge.dedup_select_async(kv_keys, seq_ascending, run_offsets=run_offsets)
         if rest_names:
-            tails = _parallel_map(
-                lambda f: self.reader_factory.read(
-                    f, predicate=key_filter, fields=rest_names, system_columns=False
-                ),
-                ordered_files,
-                parallelism=self.parallelism,
-            )
+            with span("decode.values", files=len(ordered_files)):
+                tails = _parallel_map(
+                    lambda f: self.reader_factory.read(
+                        f, predicate=key_filter, fields=rest_names, system_columns=False
+                    ),
+                    ordered_files,
+                    parallelism=self.parallelism,
+                )
             full_schema = self.reader_factory.read_schema
-            cols = {}
-            for name in full_schema.field_names:
-                if name in self.key_names:
-                    cols[name] = kv_keys.data.column(name)
-                else:
-                    from ..data.batch import Column
-
-                    cols[name] = Column.concat([t.data.column(name) for t in tails])
+            with span("concat", rows=kv_keys.num_rows, columns=len(rest_names)):
+                cols = {
+                    name: kv_keys.data.column(name)
+                    if name in self.key_names
+                    else Column.concat([t.data.column(name) for t in tails])
+                    for name in full_schema.field_names
+                }
             data = ColumnBatch(full_schema, cols)
         else:
             data = kv_keys.data
         kv = KVBatch(data, kv_keys.seq, kv_keys.kind)
         take = self.merge.dedup_resolve(handle)
-        return kv.take(take)
+        return self.merge.gather(kv, take)
 
     def read_kv(
         self, files: list[DataFileMeta], drop_delete: bool = False, deletion_vectors: dict | None = None

@@ -107,11 +107,12 @@ class FileStoreScan:
 
     # ---- plan ----------------------------------------------------------
     def plan(self) -> ScanPlan:
-        from ..metrics import registry, timed
+        from ..metrics import registry, span
 
         g = registry.group("scan")
-        with timed(g.histogram("duration_ms")):
+        with span("plan", histogram=g.histogram("duration_ms")) as sp:
             plan = self._plan()
+            sp.add(files=len(plan.entries))
         g.counter("plans").inc()
         g.counter("resulted_table_files").inc(len(plan.entries))
         return plan

@@ -9,10 +9,13 @@ reference bridges to Flink/Spark.
 
 from __future__ import annotations
 
+import contextvars
+import itertools
 import threading
 import time
-from collections import defaultdict
 from typing import Callable
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter",
@@ -21,8 +24,11 @@ __all__ = [
     "MetricGroup",
     "MetricRegistry",
     "registry",
+    "span",
+    "carried",
     "timed",
     "compaction_metrics",
+    "datafile_metrics",
     "decode_metrics",
     "dict_metrics",
     "encode_metrics",
@@ -31,9 +37,11 @@ __all__ = [
     "io_metrics",
     "join_metrics",
     "lanes_metrics",
+    "merge_metrics",
     "mesh_metrics",
     "pallas_metrics",
     "pipeline_metrics",
+    "read_metrics",
     "soak_metrics",
     "sql_metrics",
     "sub_metrics",
@@ -68,15 +76,21 @@ class Gauge:
 
 
 class Histogram:
-    """Sliding-window histogram (reference uses a 100-sample window)."""
+    """Sliding-window histogram (reference uses a 100-sample window), with a
+    lifetime `total` (samples ever) and `sum` beside the window: the window
+    forgets, so only those two can be differenced over a stretch of time."""
 
     def __init__(self, window: int = 100):
         self.window = window
         self._values: list[float] = []
+        self.total = 0
+        self.sum = 0.0
         self._lock = threading.Lock()
 
     def update(self, v: float) -> None:
         with self._lock:
+            self.total += 1
+            self.sum += v
             self._values.append(v)
             if len(self._values) > self.window:
                 self._values.pop(0)
@@ -109,14 +123,18 @@ class MetricGroup:
         self.tags = tags or {}
         self.metrics: dict[str, object] = {}
 
+    # get before setdefault: the read path asks for its counters at every
+    # file and every merge, and setdefault alone builds (and drops) a new
+    # metric with its lock on each call
+
     def counter(self, name: str) -> Counter:
-        return self.metrics.setdefault(name, Counter())  # type: ignore[return-value]
+        return self.metrics.get(name) or self.metrics.setdefault(name, Counter())  # type: ignore[return-value]
 
     def gauge(self, name: str, fn: Callable[[], float] | None = None) -> Gauge:
-        return self.metrics.setdefault(name, Gauge(fn))  # type: ignore[return-value]
+        return self.metrics.get(name) or self.metrics.setdefault(name, Gauge(fn))  # type: ignore[return-value]
 
     def histogram(self, name: str, window: int = 100) -> Histogram:
-        return self.metrics.setdefault(name, Histogram(window))  # type: ignore[return-value]
+        return self.metrics.get(name) or self.metrics.setdefault(name, Histogram(window))  # type: ignore[return-value]
 
 
 class MetricRegistry:
@@ -132,16 +150,19 @@ class MetricRegistry:
             return self.groups[key]
 
     def snapshot(self) -> dict:
+        with self._lock:  # group() inserts under it from other threads
+            groups = list(self.groups.items())
         out: dict = {}
-        for (name, tags), group in self.groups.items():
+        for (name, tags), group in groups:
             entry = {}
-            for mname, m in group.metrics.items():
+            for mname, m in list(group.metrics.items()):
                 if isinstance(m, Counter):
                     entry[mname] = m.count
                 elif isinstance(m, Gauge):
                     entry[mname] = m.value
                 elif isinstance(m, Histogram):
-                    entry[mname] = {"count": m.count, "mean": m.mean, "max": m.max}
+                    entry[mname] = {"count": m.count, "mean": m.mean, "max": m.max,
+                                    "total": m.total, "sum": m.sum}
             out[name if not tags else f"{name}{dict(tags)}"] = entry
         return out
 
@@ -231,6 +252,42 @@ def join_metrics() -> MetricGroup:
     pair expansion). Resolved per call so registry.reset() in tests swaps
     the group out."""
     return registry.group("join")
+
+
+def read_metrics() -> MetricGroup:
+    """The read{...} group (TableRead.read_all, paimon_tpu.table.read).
+    Canonical members, counters: ops (read_all calls), rows_in (records in
+    the data files of the splits read, from the file metadata), rows_out
+    (rows returned). rows_out / rows_in is the share of records that won
+    their key. Resolved per call so registry.reset() in tests swaps the
+    group out."""
+    return registry.group("read")
+
+
+def datafile_metrics() -> MetricGroup:
+    """The datafile{...} group (KeyValueFileReaderFactory._decode,
+    paimon_tpu.core.datafile: one data file read through its format and
+    mapped onto the read schema, whichever decoder backs the format; a
+    data-file cache hit decodes nothing and counts nothing). Canonical
+    members, counters: files_decoded, rows_decoded, bytes_decoded (the
+    decoded batch's KVBatch.byte_size()). The decode{...} group stays the
+    native parquet decoder's own. Resolved per call so registry.reset() in
+    tests swaps the group out."""
+    return registry.group("datafile")
+
+
+def merge_metrics() -> MetricGroup:
+    """The merge{...} group (device merge dispatch, paimon_tpu.ops.merge),
+    counted from shapes at every kernel call and every download, on any
+    backend. Canonical members, counters: merges (merges handed to the
+    device: a key-range tiled merge is one), rows_in (valid rows in them),
+    tiles (sort instances: the tiles of a tiled merge, else one), pad_rows
+    (rows allocated on the device beyond the valid ones: rows sorted for
+    nothing), h2d_bytes (bytes of the host arrays passed to the jitted
+    call), d2h_bytes (bytes of the arrays fetched back), winners (rows the
+    resolved selections name). Resolved per call so registry.reset() in
+    tests swaps the group out."""
+    return registry.group("merge")
 
 
 def mesh_metrics() -> MetricGroup:
@@ -445,3 +502,86 @@ class timed:
     def __exit__(self, *exc):
         self.histogram.update((time.perf_counter() - self._t0) * 1000)
         return False
+
+
+# ---- spans ---------------------------------------------------------------
+# The program's spans ARE profiler annotations: a span exists in a trace
+# exactly when a profiler session is open (jax.profiler.start_trace or
+# start_server), on the clock of the device's own events, and is nothing but
+# a few hundred nanoseconds when none is. docs/tracing.md has the names.
+
+SPAN_PREFIX = "pt:"
+_OP_IDS = itertools.count(1)
+# (operation id, name of the open span, the open span or None on a thread
+# that was handed the first two): what a span opened now is caused by
+_CURRENT: contextvars.ContextVar[tuple] = contextvars.ContextVar("paimon_tpu_span", default=(0, "", None))
+
+
+class span:
+    """`with span("decode.file", format="orc") as sp: ...; sp.add(rows=n)`
+
+    Opens the TraceAnnotation `pt:<name>` with the stats `op` (the id of the
+    operation it works for, 0 outside one), `parent` (the name of the span
+    that caused it, absent at the top) and the keyword numbers or strings
+    given. `add` sums numbers known only later (rows decoded, tiles, bytes)
+    onto the span; code deeper in the call reaches the innermost open span
+    of its thread through `span.current()`. `new_op=True` allots the next
+    operation id (TableRead.read_all does). `histogram=` also records the
+    span's wall milliseconds there, traced or not: the one clock read for a
+    timing on the read path."""
+
+    __slots__ = ("name", "op", "_note", "_token", "_sums", "_histogram", "_t0")
+
+    def __init__(self, name: str, histogram: Histogram | None = None, new_op: bool = False, **stats):
+        op, parent, _ = _CURRENT.get()
+        self.name = name
+        self.op = next(_OP_IDS) if new_op else op
+        self._sums: dict | None = None
+        self._histogram = histogram
+        self._note = TraceAnnotation(SPAN_PREFIX + name, op=self.op, parent=parent, **stats)
+
+    def __enter__(self) -> "span":
+        self._token = _CURRENT.set((self.op, self.name, self))
+        self._note.__enter__()
+        if self._histogram is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        if self._histogram is not None:
+            self._histogram.update((time.perf_counter() - self._t0) * 1000)
+        if self._sums:
+            self._note.set_metadata(**self._sums)
+        self._note.__exit__(*exc)
+        _CURRENT.reset(self._token)
+        return False
+
+    def add(self, **numbers) -> None:
+        sums = self._sums
+        if sums is None:
+            sums = self._sums = {}
+        for key, n in numbers.items():
+            sums[key] = sums.get(key, 0) + n
+
+    @staticmethod
+    def current() -> "span | None":
+        """The innermost span open on this thread (None outside any, and on
+        a pool thread that has opened none of its own)."""
+        return _CURRENT.get()[2]
+
+
+def carried(fn: Callable) -> Callable:
+    """`fn` for a pool thread: it runs with this thread's operation id and
+    open span's name, so a span opened over there (a file decoded on a
+    worker) names the operation and the span that asked for it. A ContextVar
+    does not cross into pool threads by itself."""
+    op, name, _ = _CURRENT.get()
+
+    def run(*args):
+        token = _CURRENT.set((op, name, None))
+        try:
+            return fn(*args)
+        finally:
+            _CURRENT.reset(token)
+
+    return run

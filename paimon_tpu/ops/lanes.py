@@ -291,9 +291,13 @@ def compress_key_lanes(
     layer is off. Records the lanes{...} metric group per planned merge."""
     if not resolve_compress(compress):
         return key_lanes, None
-    key_lanes = np.ascontiguousarray(key_lanes)
-    plan = plan_lanes(key_lanes, enable_ovc=enable_ovc)
-    packed = apply_plan(plan, key_lanes)
+    from ..metrics import span
+
+    with span("lanes.compress", rows=key_lanes.shape[0], lanes_in=key_lanes.shape[1]) as sp:
+        key_lanes = np.ascontiguousarray(key_lanes)
+        plan = plan_lanes(key_lanes, enable_ovc=enable_ovc)
+        packed = apply_plan(plan, key_lanes)
+        sp.add(lanes_out=packed.shape[1])
     _record(plan, key_lanes.shape[0])
     return packed, plan
 

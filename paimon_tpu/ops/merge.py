@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..metrics import merge_metrics, span
 from ..types import RowKind
 
 __all__ = [
@@ -54,6 +55,49 @@ def pad_to(arr: np.ndarray, m: int, fill=0) -> np.ndarray:
     out = np.full((m,) + arr.shape[1:], fill, dtype=arr.dtype)
     out[: len(arr)] = arr
     return out
+
+
+def _jit(name: str):
+    """jax.jit under a stable program name: the trace's XLA Modules line and
+    every device event read `jit_<name>`, so a program in a profile has an
+    owner (each kernel here used to be `jit_f`)."""
+
+    def named(f):
+        f.__name__ = f.__qualname__ = name
+        return jax.jit(f)
+
+    return named
+
+
+def _nbytes(operands) -> int:
+    return sum(_nbytes(a) if isinstance(a, (list, tuple)) else a.nbytes for a in operands)
+
+
+def _count_kernel(operands, rows: int, alloc_rows: int, tiles: int = 1) -> None:
+    """merge{...} at one kernel call, from the shapes in hand: `operands`
+    are the host arrays the jitted call is about to upload, `rows` the valid
+    rows among the `alloc_rows` it sorts, in `tiles` sorts. The same numbers
+    go onto the open span (merge.dispatch), which sums them over its calls."""
+    h2d, pad = _nbytes(operands), alloc_rows - rows
+    g = merge_metrics()
+    g.counter("rows_in").inc(rows)
+    g.counter("tiles").inc(tiles)
+    g.counter("pad_rows").inc(pad)
+    g.counter("h2d_bytes").inc(h2d)
+    sp = span.current()
+    if sp is not None:
+        sp.add(tiles=tiles, pad_rows=pad, h2d_bytes=h2d)
+
+
+def _count_download(nbytes: int, winners: int) -> None:
+    """merge{...} at one resolve: bytes fetched from the device and rows the
+    selection names; also onto the open span (merge.resolve)."""
+    g = merge_metrics()
+    g.counter("d2h_bytes").inc(nbytes)
+    g.counter("winners").inc(winners)
+    sp = span.current()
+    if sp is not None:
+        sp.add(d2h_bytes=nbytes, winners=winners)
 
 
 def sorted_segments(
@@ -87,28 +131,31 @@ def sorted_segments(
     order = [seq_lanes[i] for i in range(num_seq_lanes)]
     iota = jnp.arange(m, dtype=jnp.int32)
     operands = boundary + order + [iota]
-    out = jax.lax.sort(operands, num_keys=len(operands) - 1, is_stable=True)
+    with jax.named_scope("merge.sort"):
+        out = jax.lax.sort(operands, num_keys=len(operands) - 1, is_stable=True)
     perm = out[-1]
     if engine == "pallas":
         # lax.sort + the pallas boundary sweep (narrowed lanes may be
         # u8/u16 — widening on device costs nothing)
         from .pallas_kernels import keep_last_mask, pallas_interpret
 
-        stacked = jnp.stack(
-            [lane.astype(jnp.uint32) for lane in out[: len(boundary)]], axis=0
-        )
-        keep_last = keep_last_mask(stacked, interpret=pallas_interpret(), mask_pad=False).astype(
-            jnp.bool_
-        )
-        seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), keep_last[:-1]])
-        seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
+        with jax.named_scope("merge.segment"):
+            stacked = jnp.stack(
+                [lane.astype(jnp.uint32) for lane in out[: len(boundary)]], axis=0
+            )
+            keep_last = keep_last_mask(stacked, interpret=pallas_interpret(), mask_pad=False).astype(
+                jnp.bool_
+            )
+            seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), keep_last[:-1]])
+            seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
         return out[0], perm, seg_start, keep_last, seg_id
-    neq = jnp.zeros(m - 1, dtype=jnp.bool_)
-    for lane in out[: len(boundary)]:
-        neq = neq | (lane[1:] != lane[:-1])
-    seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), neq])
-    keep_last = jnp.concatenate([neq, jnp.ones((1,), jnp.bool_)])
-    seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
+    with jax.named_scope("merge.segment"):
+        neq = jnp.zeros(m - 1, dtype=jnp.bool_)
+        for lane in out[: len(boundary)]:
+            neq = neq | (lane[1:] != lane[:-1])
+        seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), neq])
+        keep_last = jnp.concatenate([neq, jnp.ones((1,), jnp.bool_)])
+        seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
     return out[0], perm, seg_start, keep_last, seg_id
 
 
@@ -127,9 +174,10 @@ def segment_last_where(seg_id, masks, pos=None):
 def pack_selected(sel, perm):
     """In-kernel: pack the selected perms to the front (key order) and count
     them — the minimal device->host transfer for selection kernels."""
-    not_sel = (~sel).astype(jnp.uint32)
-    _, packed = jax.lax.sort([not_sel, perm], num_keys=1, is_stable=True)
-    return packed, sel.sum()
+    with jax.named_scope("merge.pack"):
+        not_sel = (~sel).astype(jnp.uint32)
+        _, packed = jax.lax.sort([not_sel, perm], num_keys=1, is_stable=True)
+        return packed, sel.sum()
 
 
 def _runid_bits(num_runs: int) -> int:
@@ -183,18 +231,19 @@ def pack_selection_compact(sel, perm, starts):
     keep-mask fixes each run's winner set and the run-id sequence fixes the
     interleave."""
     m = perm.shape[0]
-    sel_input = jnp.zeros((m,), jnp.bool_).at[perm].set(sel)
-    mask_bytes = jnp.packbits(sel_input)
-    run_in = jnp.clip(
-        jnp.searchsorted(starts, perm, side="right").astype(jnp.int32) - 1,
-        0,
-        starts.shape[0] - 1,
-    )
-    _, runs_key_order = jax.lax.sort(
-        [(~sel).astype(jnp.uint32), run_in.astype(jnp.uint32)], num_keys=1, is_stable=True
-    )
-    byte = _bitpack_rows(runs_key_order, _runid_bits(starts.shape[0]))
-    return mask_bytes, byte, sel.sum()
+    with jax.named_scope("merge.pack"):
+        sel_input = jnp.zeros((m,), jnp.bool_).at[perm].set(sel)
+        mask_bytes = jnp.packbits(sel_input)
+        run_in = jnp.clip(
+            jnp.searchsorted(starts, perm, side="right").astype(jnp.int32) - 1,
+            0,
+            starts.shape[0] - 1,
+        )
+        _, runs_key_order = jax.lax.sort(
+            [(~sel).astype(jnp.uint32), run_in.astype(jnp.uint32)], num_keys=1, is_stable=True
+        )
+        byte = _bitpack_rows(runs_key_order, _runid_bits(starts.shape[0]))
+        return mask_bytes, byte, sel.sum()
 
 
 def unpack_selection_compact(mask_bytes, runs_packed, count, n: int, num_runs: int, rbits: int) -> np.ndarray:
@@ -211,6 +260,16 @@ def unpack_selection_compact(mask_bytes, runs_packed, count, n: int, num_runs: i
     if num_runs <= 1:
         return winners
     return _interleave_winners(winners, _unpack_runids(runs_packed, c, rbits))
+
+
+def _compact_selection_nbytes(c: int, n: int, num_runs: int, rbits: int) -> int:
+    """Bytes unpack_selection_compact fetches for c winners among n rows:
+    the mask's first ceil(n/8) bytes and, with more than one run, the
+    bit-packed run-ids of the winners; nothing where nothing was selected."""
+    if c == 0:
+        return 0
+    per = 8 // rbits
+    return (n + 7) // 8 + ((c + per - 1) // per if num_runs > 1 else 0)
 
 
 def narrow_lane(col: np.ndarray) -> np.ndarray:
@@ -303,7 +362,7 @@ def _plan_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0, engine:
     if ovc_vbits:
         from .lanes import ovc_codes_jax
 
-        @jax.jit
+        @_jit("merge_plan_ovc")
         def f_ovc(key_lanes, seq_lanes, pad_flag, base):
             code = ovc_codes_jax(
                 [key_lanes[i] for i in range(num_key_lanes)], base, ovc_vbits
@@ -316,7 +375,7 @@ def _plan_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0, engine:
 
         return f_ovc
 
-    @jax.jit
+    @_jit("merge_plan")
     def f(key_lanes, seq_lanes, pad_flag):
         # key/seq lanes: (K, m)/(S, m) arrays OR lists of (m,) mixed-dtype
         # uint arrays (narrowed upload); pad_flag: (m,) uint
@@ -442,25 +501,23 @@ def _merge_plan_padded(
         # wall time around dispatch+download is the kernel latency
         timer = timed(pallas_metrics().histogram("kernel_ms"))
         timer.__enter__()
-    if use_ovc:
-        # this path uploads unshifted u32 lanes, so the packed-space base
-        # passes through unshifted too
-        perm, seg_start, keep_last, seg_id = _plan_fn(k, s, plan.ovc_vbits, engine)(
-            kl, sl, pad, np.asarray(plan.base, dtype=np.uint32)
-        )
-    else:
-        perm, seg_start, keep_last, seg_id = _plan_fn(k, s, 0, engine)(kl, sl, pad)
+    merge_metrics().counter("merges").inc()
+    with span("merge.dispatch", rows=n):
+        if use_ovc:
+            # this path uploads unshifted u32 lanes, so the packed-space base
+            # passes through unshifted too
+            operands = (kl, sl, pad, np.asarray(plan.base, dtype=np.uint32))
+            fn = _plan_fn(k, s, plan.ovc_vbits, engine)
+        else:
+            operands, fn = (kl, sl, pad), _plan_fn(k, s, 0, engine)
+        _count_kernel(operands, n, m)
+        outs = fn(*operands)
+    with span("merge.resolve"):
+        perm, seg_start, keep_last, seg_id = (np.asarray(o) for o in outs)
+        _count_download(_nbytes((perm, seg_start, keep_last, seg_id)), 0)
     if timer is not None:
-        np.asarray(perm)  # force the async dispatch before stopping the clock
         timer.__exit__(None, None, None)
-    return MergePlan(
-        perm=np.asarray(perm),
-        seg_start=np.asarray(seg_start),
-        keep_last=np.asarray(keep_last),
-        seg_id=np.asarray(seg_id),
-        n=n,
-        m=m,
-    )
+    return MergePlan(perm=perm, seg_start=seg_start, keep_last=keep_last, seg_id=seg_id, n=n, m=m)
 
 
 def deduplicate_take(plan: MergePlan) -> np.ndarray:
@@ -483,7 +540,7 @@ def _dedup_select_fn(num_key_lanes: int, num_seq_lanes: int, backend: str = "xla
     if ovc_vbits:
         from .lanes import ovc_codes_jax
 
-        @jax.jit
+        @_jit("dedup_select_ovc")
         def f_ovc(key_lanes, seq_lanes, pad_flag, base):
             code = ovc_codes_jax(
                 [key_lanes[i] for i in range(num_key_lanes)], base, ovc_vbits
@@ -496,7 +553,7 @@ def _dedup_select_fn(num_key_lanes: int, num_seq_lanes: int, backend: str = "xla
 
         return f_ovc
 
-    @jax.jit
+    @_jit("dedup_select")
     def f(key_lanes, seq_lanes, pad_flag):
         pad_sorted, perm, _, keep_last, _ = sorted_segments(
             num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag, engine=backend
@@ -519,6 +576,14 @@ def deduplicate_select_async(
     The key matrix goes through the lane-compression seam first; an
     all-constant key short-circuits to the scalar winner without any device
     dispatch."""
+    with span("merge.dispatch", rows=len(key_lanes)):
+        return _select_async(key_lanes, seq_lanes, backend, compress, merges=1)
+
+
+def _select_async(key_lanes, seq_lanes, backend: str, compress: bool | None, merges: int = 0):
+    """deduplicate_select_async without its span; `merges` is 1 where the
+    call is a whole merge and 0 where it is one tile of a merge that its
+    caller counts."""
     klp, slp, pad, n, k, s, m, plan = prepare_lanes_planned(key_lanes, seq_lanes, compress=compress)
     if k == 0:
         # all keys equal: one winner — the last row in (seq, input) order;
@@ -532,10 +597,13 @@ def deduplicate_select_async(
 
         note_dispatch(m)
     if use_ovc:
-        return _dedup_select_fn(k, s, backend, plan.ovc_vbits)(
-            klp, slp, pad, np.asarray(plan.base, dtype=np.uint32)
-        )
-    return _dedup_select_fn(k, s, backend)(klp, slp, pad)
+        operands = (klp, slp, pad, np.asarray(plan.base, dtype=np.uint32))
+        fn = _dedup_select_fn(k, s, backend, plan.ovc_vbits)
+    else:
+        operands, fn = (klp, slp, pad), _dedup_select_fn(k, s, backend)
+    merge_metrics().counter("merges").inc(merges)
+    _count_kernel(operands, n, m)
+    return fn(*operands)
 
 
 def _link_encodings_pay_off() -> bool:
@@ -586,7 +654,7 @@ def _dedup_select_compact_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: 
     if ovc_vbits:
         from .lanes import ovc_codes_jax
 
-        @jax.jit
+        @_jit("dedup_select_compact_ovc")
         def f_ovc(key_lanes, seq_lanes, pad_flag, starts, base):
             code = ovc_codes_jax(
                 [key_lanes[i] for i in range(num_key_lanes)], base, ovc_vbits
@@ -599,7 +667,7 @@ def _dedup_select_compact_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: 
 
         return f_ovc
 
-    @jax.jit
+    @_jit("dedup_select_compact")
     def f(key_lanes, seq_lanes, pad_flag, starts):
         pad_sorted, perm, _, keep_last, _ = sorted_segments(
             num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag, engine=engine
@@ -634,12 +702,12 @@ def deduplicate_select_compact_async(
 
         note_dispatch(m)
     if use_ovc:
-        outs = _dedup_select_compact_fn(k, s, plan.ovc_vbits, backend)(
-            klp, slp, pad, starts_p, np.asarray(plan.base, dtype=np.uint32)
-        )
+        operands = (klp, slp, pad, starts_p, np.asarray(plan.base, dtype=np.uint32))
+        fn = _dedup_select_compact_fn(k, s, plan.ovc_vbits, backend)
     else:
-        outs = _dedup_select_compact_fn(k, s, 0, backend)(klp, slp, pad, starts_p)
-    return ("compact", outs, n, len(starts_real), _runid_bits(len(starts_p)))
+        operands, fn = (klp, slp, pad, starts_p), _dedup_select_compact_fn(k, s, 0, backend)
+    _count_kernel(operands, n, m)
+    return ("compact", fn(*operands), n, len(starts_real), _runid_bits(len(starts_p)))
 
 
 def pack_delta_runs(col: np.ndarray, run_offsets: Sequence[int]):
@@ -679,15 +747,16 @@ def _delta_reconstruct_lane(deltas, starts, bases, pad_flag):
     """In-kernel: rebuild the u32 key lane from the delta-packed upload
     (one cumsum + per-run rebase) — shared by both delta epilogues."""
     m = pad_flag.shape[0]
-    iota = jnp.arange(m, dtype=jnp.int32)
-    c = jnp.cumsum(deltas.astype(jnp.uint32), dtype=jnp.uint32)
-    run = jnp.clip(
-        jnp.searchsorted(starts, iota, side="right").astype(jnp.int32) - 1,
-        0,
-        starts.shape[0] - 1,
-    )
-    lane = bases[run] + (c - c[starts[run]])
-    return jnp.where(pad_flag == 0, lane, jnp.uint32(0xFFFFFFFF))
+    with jax.named_scope("merge.reconstruct"):
+        iota = jnp.arange(m, dtype=jnp.int32)
+        c = jnp.cumsum(deltas.astype(jnp.uint32), dtype=jnp.uint32)
+        run = jnp.clip(
+            jnp.searchsorted(starts, iota, side="right").astype(jnp.int32) - 1,
+            0,
+            starts.shape[0] - 1,
+        )
+        lane = bases[run] + (c - c[starts[run]])
+        return jnp.where(pad_flag == 0, lane, jnp.uint32(0xFFFFFFFF))
 
 
 @functools.lru_cache(maxsize=None)
@@ -696,7 +765,7 @@ def _dedup_select_delta_fn(backend: str = "xla"):
     u32 lane on device (cumsum + per-run rebase), then the standard
     sort + keep-last epilogue with the compact-encoded download."""
 
-    @jax.jit
+    @_jit("dedup_select_delta")
     def f(deltas, starts, bases, pad_flag):
         lane = _delta_reconstruct_lane(deltas, starts, bases, pad_flag)
         pad_sorted, perm, _, keep_last, _ = sorted_segments(1, 0, [lane], [], pad_flag, engine=backend)
@@ -712,7 +781,7 @@ def _dedup_select_delta_wide_fn(backend: str = "xla"):
     keeps the halved uplink bytes when the compact download encoding is
     unavailable — run counts past its u8 run-id limit (>256)."""
 
-    @jax.jit
+    @_jit("dedup_select_delta_wide")
     def f(deltas, starts, bases, pad_flag):
         lane = _delta_reconstruct_lane(deltas, starts, bases, pad_flag)
         pad_sorted, perm, _, keep_last, _ = sorted_segments(1, 0, [lane], [], pad_flag, engine=backend)
@@ -739,6 +808,7 @@ def deduplicate_select_delta_async(key_lanes: np.ndarray, run_offsets: Sequence[
         from .pallas_kernels import note_dispatch
 
         note_dispatch(m)
+    _count_kernel((deltas, starts, bases, pad), n, m)
     if num_runs > 256:
         return _dedup_select_delta_wide_fn(backend)(deltas, starts, bases, pad)
     outs = _dedup_select_delta_fn(backend)(deltas, starts, bases, pad)
@@ -756,24 +826,33 @@ def _dedup_dispatch(key_lanes: np.ndarray, run_offsets: Sequence[int], backend: 
     with every encoding: the link format is independent of which kernel
     computes the sort + boundary."""
     if not _link_encodings_pay_off():
-        return deduplicate_select_async(key_lanes, None, backend=backend, compress=False)
+        return _select_async(key_lanes, None, backend, False)
     handle = deduplicate_select_delta_async(key_lanes, run_offsets, backend=backend)
     if handle is not None:
         return handle
     handle = deduplicate_select_compact_async(key_lanes, run_offsets, compress=False, backend=backend)
     if handle is None:  # >256 runs: index-download fallback
-        handle = deduplicate_select_async(key_lanes, None, backend=backend, compress=False)
+        handle = _select_async(key_lanes, None, backend, False)
     return handle
 
 
 def deduplicate_resolve(handle) -> np.ndarray:
+    """Block on the device, then fetch the selection a dispatch produced."""
+    with span("merge.resolve"):
+        return _resolve(handle)
+
+
+def _resolve(handle) -> np.ndarray:
     if isinstance(handle, tuple) and handle[0] == "scalar":
         return handle[1]  # zero-width fast path: host-computed winner(s)
     if isinstance(handle, tuple) and handle[0] == "compact":
         _, (mask_bytes, runs_packed, count), n, num_runs, rbits = handle
-        return unpack_selection_compact(mask_bytes, runs_packed, count, n, num_runs, rbits)
+        c = int(count)
+        _count_download(count.nbytes + _compact_selection_nbytes(c, n, num_runs, rbits), c)
+        return unpack_selection_compact(mask_bytes, runs_packed, c, n, num_runs, rbits)
     packed, count = handle
     c = int(count)
+    _count_download(count.nbytes + c * packed.dtype.itemsize, c)
     return np.asarray(packed[:c])
 
 
@@ -815,7 +894,7 @@ def _dedup_select_batched_fn(num_key_lanes: int):
     signature. This replaced the per-tile dispatch whose varying pad buckets
     and narrowing dtypes caused a fresh compile per tile."""
 
-    @jax.jit
+    @_jit("dedup_select_batched")
     def f(key_lanes, pad_flag):
         def per_tile(kl, pf):  # kl: tuple of (m,) uint lanes; pf: (m,) u8
             pad_sorted, perm, _, keep_last, _ = sorted_segments(
@@ -894,6 +973,11 @@ def deduplicate_tiled_dispatch(
     Chunks are dispatched back-to-back without blocking; sections larger
     than the device budget stream through as equal-shaped chunks (the
     reference spills to disk instead: MergeSorter.java:110-116)."""
+    with span("merge.dispatch", rows=len(key_lanes)):
+        return _tiled_dispatch(key_lanes, run_offsets, tile_rows, backend, compress)
+
+
+def _tiled_dispatch(key_lanes, run_offsets, tile_rows: int, backend: str, compress: bool | None):
     key_lanes = np.ascontiguousarray(key_lanes)
     n = key_lanes.shape[0]
     offsets = list(run_offsets)
@@ -912,6 +996,7 @@ def deduplicate_tiled_dispatch(
         # all keys equal: one winner (no seq lanes on this path — run order
         # + stability carries the tie-break, so the winner is the last row)
         return [(("scalar", scalar_dedup_winner(None, n)), np.arange(n, dtype=np.int32))]
+    merge_metrics().counter("merges").inc()
     if n <= tile_rows or len(offsets) < 3:
         return [(_dedup_dispatch(key_lanes, offsets, backend), np.arange(n, dtype=np.int32))]
     lane0_runs = [key_lanes[offsets[r] : offsets[r + 1], 0] for r in range(len(offsets) - 1)]
@@ -952,16 +1037,24 @@ def deduplicate_tiled_dispatch(
             for j in range(k):
                 lanes_b[j][i, :nt] = (tl[:, j] - mins[c0 + i, j]).astype(dtypes[j])
             pad_b[i, :nt] = 0
+        _count_kernel((lanes_b, pad_b), sum(len(rows) for _, rows in chunk), t_chunk * m, tiles=len(chunk))
         outs = fn(lanes_b, pad_b)  # async: next chunk assembles while this sorts
         chunks.append((outs, [rows for _, rows in chunk]))
     return ("batched", chunks)
 
 
 def deduplicate_resolve_tiled(handles) -> np.ndarray:
+    with span("merge.resolve"):
+        return _resolve_tiled(handles)
+
+
+def _resolve_tiled(handles) -> np.ndarray:
     if isinstance(handles, tuple) and handles[0] == "batched":
         out = []
         for (packed, counts), rows_list in handles[1]:
             counts_np = np.asarray(counts)
+            winners = int(counts_np[: len(rows_list)].sum())
+            _count_download(counts_np.nbytes + winners * packed.dtype.itemsize, winners)
             for t, rows in enumerate(rows_list):
                 c = int(counts_np[t])
                 if c:
@@ -969,7 +1062,7 @@ def deduplicate_resolve_tiled(handles) -> np.ndarray:
         return np.concatenate(out) if out else np.empty(0, dtype=np.int32)
     out = []
     for handle, rows in handles:
-        local = deduplicate_resolve(handle)
+        local = _resolve(handle)
         out.append(rows[local])
     return np.concatenate(out) if out else np.empty(0, dtype=np.int32)
 
@@ -981,7 +1074,7 @@ def first_row_take(plan: MergePlan) -> np.ndarray:
 
 @functools.lru_cache(maxsize=None)
 def _partial_update_fn():
-    @jax.jit
+    @_jit("partial_update")
     def f(perm, seg_id, field_valid, is_add, is_delete):
         # perm/seg_id: (m,) sorted coords; field_valid (F, m), is_add (m,),
         # is_delete (m,) in INPUT coords, padded with False
@@ -1058,7 +1151,7 @@ def _fused_partial_update_compact_fn(num_key: int, num_seq: int, num_fields: int
     as bits + block-ids too. ~10x fewer bytes; exact reconstruction in
     unpack_field_selection_compact."""
 
-    @jax.jit
+    @_jit("fused_partial_update_compact")
     def f(key_lanes, seq_lanes, pad_flag, field_valid, is_add, is_delete, starts):
         m = pad_flag.shape[0]
         pad_sorted, perm, _, keep_last, seg_id = sorted_segments(
@@ -1120,7 +1213,7 @@ def _fused_partial_update_fn(num_key: int, num_seq: int, num_fields: int, engine
     of 4 full plan arrays + per-field round trips. This is the fusion the
     dedup engine got in round 1 (_dedup_select_fn), applied to partial-update."""
 
-    @jax.jit
+    @_jit("fused_partial_update")
     def f(key_lanes, seq_lanes, pad_flag, field_valid, is_add, is_delete):
         pad_sorted, perm, _, keep_last, seg_id = sorted_segments(
             num_key, num_seq, key_lanes, seq_lanes, pad_flag, engine=engine
@@ -1165,39 +1258,46 @@ def fused_partial_update(
 
         note_dispatch(m)
     starts_real = _ascending_block_starts(key_lanes) if F and _link_encodings_pay_off() else None
+    merge_metrics().counter("merges").inc()
+    operands = (klp, slp, pad, fv, pad_to(is_add, m, False), pad_to(is_delete, m, False))
     if starts_real is not None:
         starts_p = _pad_starts(starts_real, m)
         rbits = _runid_bits(len(starts_p))
-        win_bits, present_bits, blk_bits, exists_bits, mask_last, runs_last, count = (
-            _fused_partial_update_compact_fn(k, s, fv.shape[0], engine)(
-                klp, slp, pad, fv, pad_to(is_add, m, False), pad_to(is_delete, m, False), starts_p
+        with span("merge.dispatch", rows=n):
+            _count_kernel(operands + (starts_p,), n, m)
+            win_bits, present_bits, blk_bits, exists_bits, mask_last, runs_last, count = (
+                _fused_partial_update_compact_fn(k, s, fv.shape[0], engine)(*operands, starts_p)
             )
-        )
-        kk = int(count)
-        last_take = unpack_selection_compact(
-            mask_last, runs_last, count, n, len(starts_real), rbits
-        )
-        exists = np.unpackbits(np.asarray(exists_bits[: (kk + 7) // 8]), count=kk).astype(bool)
-        # one download per tensor (not per field): 3 link round-trips total
-        per = 8 // rbits
-        winb = np.asarray(win_bits[:, : (n + 7) // 8])
-        prb = np.asarray(present_bits[:, : (kk + 7) // 8])
-        blb = np.asarray(blk_bits[:, : max(1, (kk + per - 1) // per)])
+        with span("merge.resolve"):
+            kk = int(count)
+            last_take = unpack_selection_compact(
+                mask_last, runs_last, count, n, len(starts_real), rbits
+            )
+            exists = np.unpackbits(np.asarray(exists_bits[: (kk + 7) // 8]), count=kk).astype(bool)
+            # one download per tensor (not per field): 3 link round-trips total
+            per = 8 // rbits
+            winb = np.asarray(win_bits[:, : (n + 7) // 8])
+            prb = np.asarray(present_bits[:, : (kk + 7) // 8])
+            blb = np.asarray(blk_bits[:, : max(1, (kk + per - 1) // per)])
+            _count_download(
+                count.nbytes + _compact_selection_nbytes(kk, n, len(starts_real), rbits) + (kk + 7) // 8
+                + _nbytes((winb, prb, blb)),
+                kk,
+            )
         src_out = np.full((F, kk), -1, dtype=np.int32)
         for f in range(F):
             present, vals = unpack_field_selection_compact(winb[f], prb[f], blb[f], kk, n, rbits)
             src_out[f, present] = vals
         return src_out, exists, last_take
-    src, exists, packed, count = _fused_partial_update_fn(k, s, fv.shape[0], engine)(
-        klp, slp, pad, fv, pad_to(is_add, m, False), pad_to(is_delete, m, False)
-    )
-    kk = int(count)
-    # device-side slicing: only (F, k) + 2k elements cross the link
-    return (
-        np.asarray(src[:F, :kk]),
-        np.asarray(exists[:kk]),
-        np.asarray(packed[:kk]),
-    )
+    with span("merge.dispatch", rows=n):
+        _count_kernel(operands, n, m)
+        src, exists, packed, count = _fused_partial_update_fn(k, s, fv.shape[0], engine)(*operands)
+    with span("merge.resolve"):
+        kk = int(count)
+        # device-side slicing: only (F, k) + 2k elements cross the link
+        out = (np.asarray(src[:F, :kk]), np.asarray(exists[:kk]), np.asarray(packed[:kk]))
+        _count_download(count.nbytes + _nbytes(out), kk)
+    return out
 
 
 def partial_update_takes(

@@ -255,7 +255,7 @@ class MeshExecutor:
             self._run_chunk(kind, [(jobs[i2], packed[i2]) for i2 in chunk], axis, k_star, s_star)
 
     def _run_chunk(self, kind: str, items, axis: int, k: int, s: int) -> None:
-        from ..metrics import timed
+        from ..metrics import span
         from ..ops.merge import MergePlan, pad_size
 
         from .merge import bucket_parallel_dedup_fn, bucket_parallel_plan_fn
@@ -286,7 +286,7 @@ class MeshExecutor:
         g.counter("shards").inc()
         g.counter("pad_rows").inc(b * m - total_valid)
         self.executed_batches += 1
-        with timed(g.histogram("device_busy_ms")):
+        with span("mesh.batch", histogram=g.histogram("device_busy_ms"), shards=b, pad_rows=b * m - total_valid):
             if kind == "dedup":
                 packed_out, counts = bucket_parallel_dedup_fn(self.bucket_mesh, k, s)(kl, sl, pad)
                 packed_out = np.asarray(packed_out)

@@ -57,7 +57,6 @@ the leaf-level helper that does use the shared pool, with a sliding window so
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Callable, Iterable, Iterator, Sequence, TypeVar
 
@@ -129,7 +128,7 @@ class SplitPipeline:
             return
         from concurrent.futures import ThreadPoolExecutor
 
-        from ..metrics import pipeline_metrics
+        from ..metrics import carried, pipeline_metrics, span
 
         _warm_decode_state()
         g = pipeline_metrics()
@@ -138,12 +137,10 @@ class SplitPipeline:
         wait = g.histogram(f"{self.stage}_wait_ms")
         high_water = g.gauge("queue_depth_high_water")
 
+        @carried  # the worker's spans name the operation and span that submitted the item
         def timed_fn(x: T) -> R:
-            t0 = time.perf_counter()
-            try:
+            with span(f"pipeline.{self.stage}", histogram=busy):
                 return fn(x)
-            finally:
-                busy.update((time.perf_counter() - t0) * 1000)
 
         window = self.depth + 1
         ex = ThreadPoolExecutor(
@@ -162,9 +159,8 @@ class SplitPipeline:
                 if len(inflight) >= window:
                     break
             while inflight:
-                t0 = time.perf_counter()
-                result = inflight.popleft().result()  # re-raises worker errors
-                wait.update((time.perf_counter() - t0) * 1000)
+                with span(f"pipeline.{self.stage}.wait", histogram=wait):
+                    result = inflight.popleft().result()  # re-raises worker errors
                 for x in it:  # top the window back up before yielding
                     inflight.append(ex.submit(timed_fn, x))
                     prefetched.inc()
@@ -195,8 +191,10 @@ def bounded_map(
     if len(items) <= 1 or (parallelism is not None and parallelism <= 1):
         return [fn(x) for x in items]
     _warm_decode_state()
+    from ..metrics import carried
     from ..utils import shared_executor
 
+    fn = carried(fn)  # a file decoded on a pool thread names the operation and span that asked
     ex = shared_executor()
     if parallelism is None or parallelism >= len(items):
         return list(ex.map(fn, items))

@@ -538,9 +538,7 @@ class TableRead:
         far in family-batched shard_map calls over the mesh's bucket axis;
         emission stays in strict split order, so output is bit-identical to
         the single-device path."""
-        import time
-
-        from ..metrics import mesh_metrics
+        from ..metrics import mesh_metrics, span
         from ..parallel.pipeline import SplitPipeline
 
         from ..parallel.executor import _ACTIVE
@@ -566,20 +564,31 @@ class TableRead:
         it = pipe.map_ordered(splits, dispatch)
         try:
             for s in splits:
-                t0 = time.perf_counter()
-                cont = next(it)
-                wait.update((time.perf_counter() - t0) * 1000)
+                with span("mesh.feed", histogram=wait, shards=lanes):
+                    cont = next(it)
                 yield self.read(s) if cont is None else cont()
         finally:
             it.close()
 
     def read_all(self, splits: Sequence[DataSplit]):
-        from ..data.batch import concat_batches
+        from ..data.batch import ColumnBatch, concat_batches
+        from ..metrics import read_metrics, span
 
-        schema = self.table.row_type if self.projection is None else self.table.row_type.project(self.projection)
-        batches = list(self.batches(splits))
-        if not batches:
-            from ..data.batch import ColumnBatch
-
-            return ColumnBatch.empty(schema)
-        return concat_batches(batches)
+        splits = list(splits)
+        rows_in = sum(s.row_count for s in splits)
+        # one operation: every span below, on this thread or a pool's,
+        # carries the id allotted here
+        with span("read_all", new_op=True, splits=len(splits), rows_in=rows_in) as sp:
+            batches = list(self.batches(splits))
+            if not batches:
+                schema = self.table.row_type if self.projection is None else self.table.row_type.project(self.projection)
+                out = ColumnBatch.empty(schema)
+            else:
+                with span("concat", rows=sum(b.num_rows for b in batches), columns=len(batches[0].schema.fields)):
+                    out = concat_batches(batches)
+            sp.add(rows_out=out.num_rows)
+        g = read_metrics()
+        g.counter("ops").inc()
+        g.counter("rows_in").inc(rows_in)
+        g.counter("rows_out").inc(out.num_rows)
+        return out
