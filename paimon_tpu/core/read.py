@@ -13,9 +13,9 @@ from typing import Sequence
 
 import numpy as np
 
-from ..data.batch import Column, ColumnBatch, concat_batches
+from ..data.batch import Column, ColumnBatch, PartsTake, concat_batches
 from ..data.predicate import Predicate, PredicateBuilder, and_
-from ..metrics import span
+from ..metrics import read_metrics, span
 from .datafile import DataFileMeta, KeyValueFileReaderFactory
 from .kv import KVBatch
 from .levels import IntervalPartition
@@ -213,8 +213,9 @@ class MergeFileSplitRead:
     def _pipelined_dedup(self, ordered_files, key_filter, seq_ascending: bool) -> KVBatch:
         """Overlap host decode with the device merge: decode just the key
         columns, dispatch the dedup kernel (async), decode the value columns
-        while the device sorts, then gather. The two decode passes share the
-        predicate, so their row sets are identical (datafile.read contract)."""
+        while the device sorts, then gather the winners from the per-file
+        value columns. The two decode passes share the predicate, so their
+        row sets are identical (datafile.read contract)."""
         key_names = [n for n in self.reader_factory.read_schema.field_names if n in self.key_names]
         rest_names = [n for n in self.reader_factory.read_schema.field_names if n not in self.key_names]
         # run stability replaces sequence comparison when seq ranges are
@@ -241,6 +242,7 @@ class MergeFileSplitRead:
         for h in heads:
             run_offsets.append(run_offsets[-1] + h.num_rows)
         handle = self.merge.dedup_select_async(kv_keys, seq_ascending, run_offsets=run_offsets)
+        tails = []
         if rest_names:
             with span("decode.values", files=len(ordered_files)):
                 tails = _parallel_map(
@@ -250,20 +252,40 @@ class MergeFileSplitRead:
                     ordered_files,
                     parallelism=self.parallelism,
                 )
-            full_schema = self.reader_factory.read_schema
-            with span("concat", rows=kv_keys.num_rows, columns=len(rest_names)):
-                cols = {
-                    name: kv_keys.data.column(name)
-                    if name in self.key_names
-                    else Column.concat([t.data.column(name) for t in tails])
-                    for name in full_schema.field_names
-                }
-            data = ColumnBatch(full_schema, cols)
-        else:
-            data = kv_keys.data
-        kv = KVBatch(data, kv_keys.seq, kv_keys.kind)
         take = self.merge.dedup_resolve(handle)
-        return self.merge.gather(kv, take)
+        return self._gather_winners(kv_keys, tails, run_offsets, take)
+
+    def _gather_winners(self, kv_keys: KVBatch, tails: list[KVBatch], run_offsets: list[int], take: np.ndarray) -> KVBatch:
+        """The winners of the keys-only pipeline, a column a task on the
+        shared pool. A value column is taken straight from its per-file parts
+        (Column.take_from_parts: the value pass is never concatenated); the
+        key columns, seq and kind, which the key pass joined for the lanes,
+        are taken whole. Same batch as the concatenation's take would give."""
+        schema = self.reader_factory.read_schema
+        rows_out = len(take)
+        with span("gather", rows_in=kv_keys.num_rows, rows_out=rows_out, columns=len(schema.fields), parts=len(run_offsets) - 1):
+            plan = None
+            if tails:
+                with span("gather.plan", rows=rows_out, parts=len(tails)):
+                    plan = PartsTake(run_offsets, take, lambda fn, items: _parallel_map(fn, items, self.parallelism))
+            # a task a column: a key column, seq or kind whole, or a value column's per-file parts
+            tasks = [
+                (n, kv_keys.data.column(n) if n in self.key_names else [t.data.column(n) for t in tails])
+                for n in schema.field_names
+            ]
+            tasks += [("_seq", kv_keys.seq), ("_kind", kv_keys.kind)]
+
+            def gather_column(task):
+                name, col = task
+                whole = not isinstance(col, list)
+                with span("gather.column", column=name, rows_out=rows_out, parts=1 if whole else len(col)):
+                    return (col.take(take), False) if whole else Column.take_from_parts(col, plan)
+
+            gathered = _parallel_map(gather_column, tasks, self.parallelism)
+            *cols, seq, kind = (col for col, _ in gathered)
+            read_metrics().counter("rows_gathered").inc(rows_out * len(tasks))
+            read_metrics().counter("rows_gathered_from_parts").inc(rows_out * sum(from_parts for _, from_parts in gathered))
+        return KVBatch(ColumnBatch(schema, cols), seq, kind)
 
     def read_kv(
         self, files: list[DataFileMeta], drop_delete: bool = False, deletion_vectors: dict | None = None

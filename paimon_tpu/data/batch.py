@@ -35,7 +35,48 @@ import pyarrow  # noqa: F401
 
 from ..types import DataField, DataType, RowType, TypeRoot
 
-__all__ = ["Column", "ColumnBatch", "concat_batches"]
+__all__ = ["Column", "ColumnBatch", "PartsTake", "concat_batches"]
+
+
+class PartsTake:
+    """Where the rows that `take` names lie in the parts: `take` indexes the
+    concatenation of parts whose row offsets are `offsets` (len(parts) + 1
+    of them, ascending from 0), in any order. Computed once a gather and
+    shared by every column (Column.take_from_parts).
+
+    `picks` is a list of (part, positions in the output, rows of the part),
+    both intp and the positions ascending. The output is cut into stretches
+    of CHUNK rows and each stretch into one pick a part found there, so that
+    a pick's scatter stays inside a cache-sized piece of the output and its
+    temporaries inside blocks the allocator hands out again. `map_fn(fn,
+    items)` runs the stretches (the read path gives its pool's map)."""
+
+    CHUNK = 1 << 18
+
+    __slots__ = ("take", "offsets", "picks")
+
+    def __init__(self, offsets: Sequence[int], take: np.ndarray, map_fn=map):
+        self.take = take
+        self.offsets = np.asarray(offsets, dtype=np.intp)
+        self.picks = [p for ps in map_fn(self._picks_of, range(0, len(take), self.CHUNK)) for p in ps]
+
+    def _picks_of(self, start: int) -> list[tuple[int, np.ndarray, np.ndarray]]:
+        idx = self.take[start : start + self.CHUNK]
+        if idx.min() < 0 or idx.max() >= self.offsets[-1]:
+            raise IndexError(f"take index out of bounds for {self.offsets[-1]} rows")
+        part = np.searchsorted(self.offsets[1:], idx, side="right")
+        # the stretch's positions grouped by part: stable, so they ascend
+        # within a part (a radix sort where the parts number under 65536)
+        order = np.argsort(part.astype(np.min_scalar_type(len(self.offsets)), copy=False), kind="stable")
+        counts = np.bincount(part, minlength=len(self.offsets) - 1)
+        picks, at = [], 0
+        for f in np.flatnonzero(counts):
+            sel = order[at : at + counts[f]]
+            at += counts[f]
+            local = idx.take(sel).astype(np.intp, copy=False)
+            local -= self.offsets[f]
+            picks.append((int(f), sel + start, local))
+        return picks
 
 
 class Column:
@@ -264,21 +305,77 @@ class Column:
             out = unify_columns(cols, validity)
             if out is not None:
                 return out
-        if cols and all(c._values is None and c.arrow is not None for c in cols):
+        chunks = _arrow_chunks(cols)
+        if chunks is not None:
             import pyarrow as pa
 
-            chunks = []
-            for c in cols:
-                a = c.arrow
-                chunks.extend(a.chunks if isinstance(a, pa.ChunkedArray) else [a])
-            types = {c.type for c in chunks if not pa.types.is_null(c.type)}
-            if len(types) == 1:
-                t = types.pop()
-                chunks = [c.cast(t) if pa.types.is_null(c.type) else c for c in chunks]
-                return Column(validity=validity, arrow=pa.concat_arrays(chunks))
-            # all-null or mixed types: fall through to the numpy path
+            return Column(validity=validity, arrow=pa.concat_arrays(chunks))
         values = np.concatenate([c.values for c in cols])
         return Column(values, validity)
+
+    @staticmethod
+    def take_from_parts(parts: Sequence["Column"], plan: PartsTake) -> tuple["Column", bool]:
+        """Column.concat(parts).take(plan.take), cell for cell, and whether
+        no one concatenated the parts to make it. True: numpy-valued parts of
+        one dtype (one fresh output and, a pick of the plan at a time,
+        `out[positions] = part.take(rows)`) and code-backed parts (the pools
+        unified, the winners' codes re-mapped only). False: arrow-backed
+        parts, which go to pyarrow's take over the chunks (it joins
+        variable-width chunks inside the kernel), and any shape that takes
+        the concatenation after all (parts backed in different ways or of
+        different types, a unified pool past the limit: Column.concat then
+        tries the pools again). `plan.take` is not negative: IndexError,
+        where Column.take would count from the end."""
+        n = len(plan.take)
+        validity = None
+        if not all(c.validity is None for c in parts):
+            validity = _scatter_parts([c.validity for c in parts], plan, np.ones(n, dtype=np.bool_))
+        if all(c._values is not None for c in parts) and len({c._values.dtype for c in parts}) == 1:
+            values = _scatter_parts([c._values for c in parts], plan, np.empty(n, dtype=parts[0]._values.dtype))
+            return Column(values, validity), True
+        if all(c.is_code_backed for c in parts):
+            from ..ops.dicts import remap_codes, unify_column_pools
+
+            got = unify_column_pools(parts)
+            if got is not None:
+                pool, remaps = got
+                codes = np.empty(n, dtype=np.uint32)
+                for f, pos, local in plan.picks:
+                    codes[pos] = remap_codes(remaps[f], parts[f].dict_cache[1].take(local))
+                return Column.from_codes(pool, codes, validity), True
+        chunks = _arrow_chunks(parts)
+        if chunks is not None:
+            import pyarrow as pa
+            import pyarrow.compute as pc
+
+            taken = pc.take(pa.chunked_array(chunks), plan.take)
+            return Column(validity=validity, arrow=taken.chunk(0) if taken.num_chunks == 1 else taken.combine_chunks()), False
+        return Column.concat(parts).take(plan.take), False
+
+
+def _arrow_chunks(cols: Sequence[Column]) -> list | None:
+    """The chunks of arrow-backed columns, in order and under their one type
+    (null-typed chunks cast to it); None where a column is not arrow-backed,
+    all chunks are null-typed or their types differ."""
+    if not cols or not all(c._values is None and c.arrow is not None for c in cols):
+        return None
+    import pyarrow as pa
+
+    chunks = []
+    for c in cols:
+        chunks.extend(c.arrow.chunks if isinstance(c.arrow, pa.ChunkedArray) else [c.arrow])
+    types = {c.type for c in chunks if not pa.types.is_null(c.type)}
+    if len(types) != 1:
+        return None
+    t = types.pop()
+    return [c.cast(t) if pa.types.is_null(c.type) else c for c in chunks]
+
+
+def _scatter_parts(arrays: Sequence[np.ndarray | None], plan: PartsTake, out: np.ndarray) -> np.ndarray:
+    for f, pos, local in plan.picks:
+        if arrays[f] is not None:
+            out[pos] = arrays[f].take(local)
+    return out
 
 
 class ColumnBatch:

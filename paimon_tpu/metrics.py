@@ -259,8 +259,14 @@ def read_metrics() -> MetricGroup:
     Canonical members, counters: ops (read_all calls), rows_in (records in
     the data files of the splits read, from the file metadata), rows_out
     (rows returned). rows_out / rows_in is the share of records that won
-    their key. Resolved per call so registry.reset() in tests swaps the
-    group out."""
+    their key. rows_gathered (winners, counted once a column gathered, seq
+    and kind among them) and rows_gathered_from_parts (those of the columns
+    that nobody concatenated: numpy-valued and code-backed value columns,
+    taken from the per-file parts by Column.take_from_parts; not arrow-backed
+    ones, whose chunks pyarrow's take joins inside, nor a column that fell
+    back to the concatenation, nor the key columns, seq and kind). Both are
+    counted by the gather of the keys-only pipeline (core/read.py).
+    Resolved per call so registry.reset() in tests swaps the group out."""
     return registry.group("read")
 
 

@@ -51,6 +51,7 @@ __all__ = [
     "remap_codes",
     "remap_codes_np",
     "remap_codes_jax",
+    "unify_column_pools",
     "unify_columns",
     "prune_pool",
     "cache_usable",
@@ -231,6 +232,20 @@ def cache_usable(col) -> bool:
     return cache is not None and len(cache[1]) == len(col)
 
 
+def unify_column_pools(cols: Sequence, limit: int | None = None):
+    """(unified sorted pool, per-column remaps) of code-backed columns, or
+    None when the unified domain exceeds the pool limit."""
+    pools = [c.dict_cache[0] for c in cols]
+    if sum(len(p) for p in pools) > resolve_pool_limit(limit) and len(set(map(id, pools))) > 1:
+        # cheap upper bound first; the exact unified size needs the unify
+        # itself, which we refuse to pay past the limit
+        return None
+    unified, remaps = unify_pools(pools)
+    if len(unified) > resolve_pool_limit(limit):
+        return None
+    return unified, remaps
+
+
 def unify_columns(cols: Sequence, validity: np.ndarray | None, limit: int | None = None):
     """Concatenate code-backed columns without leaving the code domain:
     unify their pools, re-map and concatenate their codes. Returns the
@@ -238,18 +253,11 @@ def unify_columns(cols: Sequence, validity: np.ndarray | None, limit: int | None
     exceeds the pool limit (the caller falls back to expanded concat)."""
     from ..data.batch import Column
 
-    pools = [c.dict_cache[0] for c in cols]
-    if sum(len(p) for p in pools) > resolve_pool_limit(limit) and len(set(map(id, pools))) > 1:
-        # cheap upper bound first; the exact unified size needs the unify
-        # itself, which we refuse to pay past the limit
-        g = _metrics()
-        g.counter("fallback_expanded").inc(sum(len(c) for c in cols))
+    got = unify_column_pools(cols, limit)
+    if got is None:
+        _metrics().counter("fallback_expanded").inc(sum(len(c) for c in cols))
         return None
-    unified, remaps = unify_pools(pools)
-    if len(unified) > resolve_pool_limit(limit):
-        g = _metrics()
-        g.counter("fallback_expanded").inc(sum(len(c) for c in cols))
-        return None
+    unified, remaps = got
     codes = np.concatenate(
         [remap_codes(r, c.dict_cache[1]) for r, c in zip(remaps, cols)]
     )

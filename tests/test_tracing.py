@@ -103,7 +103,8 @@ def _inside(inner, outer):
 
 
 READ_PATH_SPANS = ("plan", "read_all", "split", "decode.keys", "decode.values", "decode.file", "concat",
-                   "lanes.encode", "lanes.compress", "merge.dispatch", "merge.resolve", "gather", "finish")
+                   "lanes.encode", "lanes.compress", "merge.dispatch", "merge.resolve", "gather", "gather.plan",
+                   "gather.column", "finish")
 
 
 @pytest.mark.parametrize("name", READ_PATH_SPANS)
@@ -125,7 +126,7 @@ def test_spans_of_one_read_share_one_operation_id(reads):
 @pytest.mark.parametrize("name,parent", [
     ("split", "read_all"), ("decode.keys", "split"), ("decode.values", "split"), ("lanes.encode", "split"),
     ("merge.dispatch", "split"), ("lanes.compress", "merge.dispatch"), ("merge.resolve", "split"),
-    ("gather", "split"), ("finish", "split")])
+    ("gather", "split"), ("gather.plan", "gather"), ("finish", "split")])
 def test_spans_nest_as_documented(reads, name, parent):
     (read_all,) = _named(reads["cold"], "read_all")
     for e in _named(reads["cold"], name):
@@ -157,6 +158,22 @@ def test_a_file_decoded_on_a_pool_thread_names_its_operation_and_who_asked(reads
         assert f[4]["columns"] == (1 if f[4]["pass"] == "keys" else 2)
         waits = _named(reads["cold"], f[4]["parent"])
         assert any(w[1] <= f[1] and f[2] <= w[2] for w in waits)  # inside the reader's wait, on another line
+
+
+def test_a_column_gathered_on_a_pool_thread_names_its_operation_and_the_gather(reads):
+    (read_all,) = _named(reads["cold"], "read_all")
+    (gather,) = _named(reads["cold"], "gather")
+    columns = _named(reads["cold"], "gather.column")
+    assert gather[4]["parts"] == RUNS and gather[4]["columns"] == 3
+    # the value columns from their per-file parts, the key column, seq and kind whole
+    assert {c[4]["column"]: c[4]["parts"] for c in columns} == {"id": 1, "v": RUNS, "s": RUNS, "_seq": 1, "_kind": 1}
+    assert {c[3] for c in columns} != {read_all[3]}, "no column left the reading thread"
+    for c in columns:
+        assert c[4]["op"] == read_all[4]["op"] and c[4]["parent"] == "gather"
+        assert c[4]["rows_out"] == gather[4]["rows_out"] == read_all[4]["rows_out"]
+        assert gather[1] <= c[1] and c[2] <= gather[2]  # inside the reader's gather, on whichever line
+    # the value pass (2 columns) is not concatenated: what is left joins the key pass, the sections and the splits
+    assert sorted(c[4]["columns"] for c in _named(reads["cold"], "concat")) == [1, 3, 3]
 
 
 def test_a_cache_hit_opens_no_decode_file(reads):
@@ -316,7 +333,10 @@ def test_a_read_counts_rows_and_decodes(tmp_path):
     table = _table(tmp_path / "w")
     out = _read(table)
     snap = registry.snapshot()
-    assert snap["read"] == {"ops": 1, "rows_in": RUNS * ROWS_A_RUN, "rows_out": out.num_rows}
+    # 3 columns, seq and kind gathered; from the per-file parts only v, the numeric value column:
+    # pyarrow joins the chunks of the arrow-backed s inside its take, so s is no column "from parts"
+    assert snap["read"] == {"ops": 1, "rows_in": RUNS * ROWS_A_RUN, "rows_out": out.num_rows,
+                            "rows_gathered": 5 * out.num_rows, "rows_gathered_from_parts": out.num_rows}
     assert snap["datafile"]["files_decoded"] == 2 * RUNS and snap["datafile"]["rows_decoded"] == 2 * RUNS * ROWS_A_RUN
     assert snap["datafile"]["bytes_decoded"] > 0
     assert snap["merge"]["merges"] == 1 and snap["merge"]["winners"] == out.num_rows
