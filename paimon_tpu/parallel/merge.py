@@ -25,7 +25,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.merge import _plan_fn
+from ..ops.merge import _jit, _plan_fn
 
 __all__ = [
     "bucket_parallel_dedup",
@@ -94,7 +94,9 @@ def bucket_parallel_dedup_fn(mesh: Mesh, k: int, s: int):
         in_specs=(P("bucket", None, None), P("bucket", None, None), P("bucket", None)),
         out_specs=(P("bucket", None), P("bucket")),
     )
-    return jax.jit(fn)
+    # a program name of its own (jit_dedup_select_mesh on the trace's XLA
+    # Modules line), beginning as the single-device program's does
+    return _jit("dedup_select_mesh")(fn)
 
 
 @functools.lru_cache(maxsize=None)
@@ -120,7 +122,7 @@ def bucket_parallel_plan_fn(mesh: Mesh, k: int, s: int):
             P("bucket", None),
         ),
     )
-    return jax.jit(fn)
+    return _jit("merge_plan_mesh")(fn)
 
 
 # ---------------------------------------------------------------------------

@@ -3,10 +3,13 @@ across the device mesh (ISSUE 7 tentpole).
 
 `MeshExecutor` is the bridge the shard_map primitives in parallel/merge.py
 were missing: table operations (merge read, compaction rewrite, writer flush)
-dispatch their per-bucket merge jobs into it, and it executes everything
-pending in ONE shard_map call per merge-function family over the mesh's
+dispatch their per-bucket merge jobs into it, and it executes a batch of
+them in ONE shard_map call per merge-function family over the mesh's
 "bucket" axis — the TPU-native mapping of the reference running one
 Flink/Spark task per bucket (SURVEY §2.9, MergeTreeSplitGenerator.java:38).
+A batch is a ROUND of the reader's plan (the next `feeder_lanes` splits in
+split order, `MeshExecutor.round`), or, for submitters that have no rounds
+to give (compaction, the writers), everything they have submitted so far.
 Oversized buckets leave the bucket axis and range-shuffle over the "key"
 axis instead (distributed_dedup_select: all_gather splitter sample +
 all_to_all — the RangeShuffle.java analog), and sort-compact / dynamic-bucket
@@ -27,16 +30,22 @@ Three properties distinguish it from the older `MeshBatchContext`
 
   HOST-SIDE FEEDER — the PR 4 SplitPipeline feeds the executor with one
   prefetch lane per device (table/read._mesh_batches, compact
-  rewrite_dispatch), so IO + decode of shard i+1 overlap the batched device
-  merge of shard i.
+  rewrite_dispatch), so IO + decode of round i+1 overlap the batched device
+  merge of round i. Which jobs share a shard_map call is decided by the
+  plan, never by how far the feeder threads happen to have come: resolving
+  a job runs its round and no job of a later one.
 
   ONE DEVICE — gated behind `merge.engine = mesh` (default `single`); with
   a single visible device there is nothing to shard over and the existing
   single-device path runs, bit-identically.
 
-Observability: the mesh{buckets_sharded, shards, pad_rows, exchange_rows,
-device_busy_ms, feeder_wait_ms} metric group, surfaced as a breakdown line
-in bench.py.
+Observability (docs/tracing.md): the mesh{buckets_sharded, shards, pad_rows,
+exchange_rows, device_busy_ms, feeder_wait_ms} metric group; a bucket-axis
+batch also counts merge{merges, rows_in, tiles, pad_rows, h2d_bytes,
+d2h_bytes, winners} as ops/merge.py does (a job is a merge of one tile); the
+spans mesh.plan, mesh.stack and, inside mesh.batch, mesh.h2d, mesh.run and
+mesh.d2h. perfbench's per-layer readers read them on the cell
+pk-8bucket-mesh.merge-read.
 """
 
 from __future__ import annotations
@@ -130,6 +139,31 @@ class _Job:
     lanes: np.ndarray  # (n, K) uint32 — RAW key lanes (planning is global)
     seq_lanes: np.ndarray | None  # (n, S) uint32
     compress: bool  # merge.lane-compression resolved by the submitter
+    round: int | None = None  # the reader's round it was submitted in; None outside one
+
+
+class _Round:
+    """The executor as the dispatch of one split sees it (the mesh-context
+    protocol of core.mergefn): a job submitted through it belongs to round
+    `n` and runs with that round's jobs only."""
+
+    plans_globally = True
+
+    def __init__(self, mex: "MeshExecutor", n: int):
+        self.mex, self.n = mex, n
+
+    @property
+    def feeder_lanes(self) -> int:
+        return self.mex.feeder_lanes
+
+    def submit_dedup(self, lanes, seq_lanes, compress: bool = True) -> int:
+        return self.mex._submit(_Job("dedup", lanes, seq_lanes, compress, self.n))
+
+    def submit_plan(self, lanes, seq_lanes, compress: bool = True) -> int:
+        return self.mex._submit(_Job("plan", lanes, seq_lanes, compress, self.n))
+
+    def result(self, job_id: int):
+        return self.mex.result(job_id)
 
 
 class MeshExecutor:
@@ -174,16 +208,31 @@ class MeshExecutor:
             self._jobs[jid] = job
             return jid
 
+    def round(self, n: int) -> _Round:
+        """The context to install while round `n`'s splits dispatch. The
+        rounds are the caller's plan (table/read._mesh_batches: the next
+        `feeder_lanes` data splits in split order), fixed before any split
+        dispatches; the caller resolves a round's jobs only once all of its
+        dispatches are in."""
+        return _Round(self, n)
+
     def result(self, job_id: int):
         if job_id not in self._results:
-            self.execute()
+            with self._lock:
+                job = self._jobs.get(job_id)
+            self.execute(None if job is None else job.round)
         return self._results.pop(job_id)
 
     # ---- execution --------------------------------------------------------
-    def execute(self) -> None:
+    def execute(self, round: int | None = None) -> None:
+        """Run the pending jobs of one round, or (None) all those submitted
+        outside any round, which is what compaction and the writers mean.
+        Jobs of other rounds, which the feeder may have submitted already,
+        stay pending."""
         with self._lock:
-            pending = self._jobs
-            self._jobs = {}
+            pending = {jid: job for jid, job in self._jobs.items() if job.round == round}
+            for jid in pending:
+                del self._jobs[jid]
         if not pending:
             return
         g = _metrics()
@@ -218,11 +267,15 @@ class MeshExecutor:
         the off-switch bit-exact)."""
         if not compress:
             return [j.lanes for _, j in jobs], None
+        from ..metrics import span
         from ..ops.lanes import _record, apply_plan, plan_lanes_global
 
-        plan = plan_lanes_global([j.lanes for _, j in jobs])
-        packed = [apply_plan(plan, j.lanes) for _, j in jobs]
-        _record(plan, sum(j.lanes.shape[0] for _, j in jobs))
+        rows = sum(j.lanes.shape[0] for _, j in jobs)
+        with span("mesh.plan", jobs=len(jobs), rows=rows) as sp:
+            plan = plan_lanes_global([j.lanes for _, j in jobs])
+            packed = [apply_plan(plan, j.lanes) for _, j in jobs]
+            sp.add(lanes_in=plan.lanes_in, lanes_out=plan.lanes_out)
+        _record(plan, rows)
         return packed, plan
 
     def _run_family(self, kind: str, jobs: list[tuple[int, _Job]], compress: bool) -> None:
@@ -255,8 +308,11 @@ class MeshExecutor:
             self._run_chunk(kind, [(jobs[i2], packed[i2]) for i2 in chunk], axis, k_star, s_star)
 
     def _run_chunk(self, kind: str, items, axis: int, k: int, s: int) -> None:
-        from ..metrics import span
-        from ..ops.merge import MergePlan, pad_size
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        from ..metrics import merge_metrics, span
+        from ..ops.merge import MergePlan, _count_download, _count_kernel, _nbytes, pad_size
 
         from .merge import bucket_parallel_dedup_fn, bucket_parallel_plan_fn
 
@@ -269,43 +325,55 @@ class MeshExecutor:
         while p2 < per_dev:
             p2 <<= 1
         b = p2 * axis
-        kl = np.full((b, m, k), 0xFFFFFFFF, dtype=np.uint32)
-        sl = np.zeros((b, m, s), dtype=np.uint32)
-        pad = np.ones((b, m), dtype=np.uint32)
-        total_valid = 0
-        for i, ((_, job), packed) in enumerate(items):
-            n = packed.shape[0]
-            total_valid += n
-            kl[i, :n, : packed.shape[1]] = packed
-            # missing lanes beyond a job's arity stay constant — constant
-            # lanes affect neither ordering nor segmentation
-            kl[i, :n, packed.shape[1] :] = 0
-            if job.seq_lanes is not None and job.seq_lanes.shape[1]:
-                sl[i, :n, : job.seq_lanes.shape[1]] = job.seq_lanes
-            pad[i, :n] = 0
+        with span("mesh.stack", shards=b, rows=b * m):
+            kl = np.full((b, m, k), 0xFFFFFFFF, dtype=np.uint32)
+            sl = np.zeros((b, m, s), dtype=np.uint32)
+            pad = np.ones((b, m), dtype=np.uint32)
+            total_valid = 0
+            for i, ((_, job), packed) in enumerate(items):
+                n = packed.shape[0]
+                total_valid += n
+                kl[i, :n, : packed.shape[1]] = packed
+                # missing lanes beyond a job's arity stay constant — constant
+                # lanes affect neither ordering nor segmentation
+                kl[i, :n, packed.shape[1] :] = 0
+                if job.seq_lanes is not None and job.seq_lanes.shape[1]:
+                    sl[i, :n, : job.seq_lanes.shape[1]] = job.seq_lanes
+                pad[i, :n] = 0
         g.counter("shards").inc()
         g.counter("pad_rows").inc(b * m - total_valid)
+        merge_metrics().counter("merges").inc(len(items))
         self.executed_batches += 1
+        fn = (bucket_parallel_dedup_fn if kind == "dedup" else bucket_parallel_plan_fn)(self.bucket_mesh, k, s)
         with span("mesh.batch", histogram=g.histogram("device_busy_ms"), shards=b, pad_rows=b * m - total_valid):
-            if kind == "dedup":
-                packed_out, counts = bucket_parallel_dedup_fn(self.bucket_mesh, k, s)(kl, sl, pad)
-                packed_out = np.asarray(packed_out)
-                counts = np.asarray(counts)
-                for i, ((jid, _), _p) in enumerate(items):
-                    self._results[jid] = packed_out[i, : int(counts[i])]
-            else:
-                perm, seg_start, keep_last, seg_id = map(
-                    np.asarray, bucket_parallel_plan_fn(self.bucket_mesh, k, s)(kl, sl, pad)
+            # no step below waits for the one before it: the upload and the
+            # call are enqueued, and the download blocks until the program is
+            # done. So mesh.d2h is the wait for the device and the copy back.
+            with span("mesh.h2d"):
+                operands = (kl, sl, pad)
+                _count_kernel(operands, total_valid, b * m, tiles=len(items))
+                operands = jax.device_put(operands, NamedSharding(self.bucket_mesh, PartitionSpec("bucket")))
+            with span("mesh.run"):
+                outs = fn(*operands)
+            with span("mesh.d2h"):
+                outs = [np.asarray(o) for o in outs]
+                winners = int(outs[1].sum()) if kind == "dedup" else 0  # pad shards select nothing
+                _count_download(_nbytes(outs), winners)
+        if kind == "dedup":
+            packed_out, counts = outs
+            for i, ((jid, _), _p) in enumerate(items):
+                self._results[jid] = packed_out[i, : int(counts[i])]
+        else:
+            perm, seg_start, keep_last, seg_id = outs
+            for i, ((jid, job), _p) in enumerate(items):
+                self._results[jid] = MergePlan(
+                    perm=perm[i],
+                    seg_start=seg_start[i],
+                    keep_last=keep_last[i],
+                    seg_id=seg_id[i],
+                    n=job.lanes.shape[0],
+                    m=m,
                 )
-                for i, ((jid, job), _p) in enumerate(items):
-                    self._results[jid] = MergePlan(
-                        perm=perm[i],
-                        seg_start=seg_start[i],
-                        keep_last=keep_last[i],
-                        seg_id=seg_id[i],
-                        n=job.lanes.shape[0],
-                        m=m,
-                    )
 
     def _run_key_axis(self, job: _Job) -> np.ndarray:
         """One oversized bucket's dedup range-shuffled over the key axis.

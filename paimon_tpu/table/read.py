@@ -533,11 +533,14 @@ class TableRead:
     def _mesh_batches(self, mex, splits: Sequence[DataSplit]):
         """merge.engine = mesh scan: the PR 4 SplitPipeline is the host-side
         feeder with one prefetch lane per device, so the IO + decode of
-        shard i+1 overlap the batched device merges of shard i. Each
-        continuation's first resolve executes every merge job dispatched so
-        far in family-batched shard_map calls over the mesh's bucket axis;
-        emission stays in strict split order, so output is bit-identical to
-        the single-device path."""
+        round i+1 overlap the batched device merges of round i. A round is
+        the next `feeder_lanes` data splits in split order, fixed here before
+        any split dispatches: its merge jobs run in family-batched shard_map
+        calls over the mesh's bucket axis once all of its dispatches are in,
+        and never with jobs of a later round that the feeder has submitted
+        already. So the calls an operation makes, and the rows they pad, are
+        the plan's and not a matter of thread timing. Emission stays in strict
+        split order, so output is bit-identical to the single-device path."""
         from ..metrics import mesh_metrics, span
         from ..parallel.pipeline import SplitPipeline
 
@@ -546,26 +549,35 @@ class TableRead:
         lanes = mex.feeder_lanes
         pipe = SplitPipeline(parallelism=lanes, depth=lanes, stage="scan")
         wait = mesh_metrics().histogram("feeder_wait_ms")
+        # changelog splits have no merge to batch: read on the consumer
+        data = [i for i, s in enumerate(splits) if not s.is_changelog]
+        round_of = {i: n // lanes for n, i in enumerate(data)}
+        round_ends = {round_of[i]: i for i in data}  # a round's last split
+        # the split whose dispatch has to be in before split i resolves
+        need = [round_ends[round_of[i]] if i in round_of else i for i in range(len(splits))]
 
-        def dispatch(s: DataSplit):
-            # changelog splits have no merge to batch: read on the consumer
-            if s.is_changelog:
+        def dispatch(i: int):
+            if i not in round_of:
                 return None
             # the mesh context is a ContextVar — invisible inside pipeline
             # worker threads unless re-installed, and without it the dispatch
             # would silently merge eagerly on the worker instead of enqueuing
-            # the job for the batched shard_map
-            token = _ACTIVE.set(mex)
+            # the job for its round's shard_map
+            token = _ACTIVE.set(mex.round(round_of[i]))
             try:
-                return self._dispatch(s)
+                return self._dispatch(splits[i])
             finally:
                 _ACTIVE.reset(token)
 
-        it = pipe.map_ordered(splits, dispatch)
+        it = pipe.map_ordered(range(len(splits)), dispatch)
+        conts: list = []
         try:
-            for s in splits:
-                with span("mesh.feed", histogram=wait, shards=lanes):
-                    cont = next(it)
+            for i, s in enumerate(splits):
+                # a split resolves once its whole round has dispatched
+                while len(conts) <= need[i]:
+                    with span("mesh.feed", histogram=wait, shards=lanes):
+                        conts.append(next(it))
+                cont, conts[i] = conts[i], None
                 yield self.read(s) if cont is None else cont()
         finally:
             it.close()
