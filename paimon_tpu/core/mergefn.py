@@ -17,7 +17,7 @@ import numpy as np
 
 from ..data.batch import Column, ColumnBatch
 from ..data.keys import build_string_pool, encode_key_lanes, split_int64_lanes
-from ..metrics import span
+from ..metrics import read_metrics, span
 from ..options import CoreOptions, MergeEngine
 from ..ops import (
     AggregateSpec,
@@ -294,8 +294,12 @@ class MergeExecutor:
 
     @staticmethod
     def gather(kv: KVBatch, take: np.ndarray) -> KVBatch:
-        with span("gather", rows_in=kv.num_rows, rows_out=len(take), columns=len(kv.data.schema.fields)):
-            return kv.take(take)
+        columns = len(kv.data.schema.fields)
+        with span("gather", rows_in=kv.num_rows, rows_out=len(take), columns=columns):
+            out = kv.take(take)
+        # the whole batch was concatenated: every column, seq and kind, none from parts
+        read_metrics().counter("rows_gathered").inc(len(take) * (columns + 2))
+        return out
 
     def supports_keys_only_pipeline(self) -> bool:
         """True when merge needs only (key cols, seq, kind) to pick winners —
@@ -307,13 +311,22 @@ class MergeExecutor:
         With run_offsets and no explicit seq lanes, dispatches key-range tiles
         so transfers of one tile overlap the device sort of another. On the
         host engine (explicit or platform-adaptive) the select runs
-        synchronously — same handle contract, no device round trip."""
+        synchronously — same handle contract, no device round trip. Under a
+        MeshExecutor (a reader's round) the select is a job of the round's
+        shard_map: RAW lanes, as merge_async submits them, and no tiling —
+        a round is the tile."""
         lanes, seq_lanes = self._lanes(kv_keys, seq_ascending)
         from ..options import SortEngine
+        from ..parallel.executor import current_mesh_context
 
         engine = self.effective_sort_engine()
         if engine == SortEngine.NUMPY:
             return ("numpy", _numpy_dedup_select(lanes, seq_lanes, self._compress))
+        ctx = current_mesh_context()
+        if getattr(ctx, "plans_globally", False):
+            from ..ops.lanes import resolve_compress
+
+            return ("mesh", (ctx, ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress))))
         from ..ops.merge import deduplicate_select_async, deduplicate_tiled_dispatch
 
         backend = "pallas" if engine == SortEngine.PALLAS else "xla"
@@ -334,6 +347,9 @@ class MergeExecutor:
         tag, h = handle
         if tag == "numpy":
             return h
+        if tag == "mesh":
+            ctx, job_id = h
+            return ctx.result(job_id)
         from ..ops.merge import deduplicate_resolve, deduplicate_resolve_tiled
 
         return deduplicate_resolve_tiled(h) if tag == "tiled" else deduplicate_resolve(h)

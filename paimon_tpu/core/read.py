@@ -156,11 +156,18 @@ class MergeFileSplitRead:
         runs, seq_ascending = order_runs_for_merge(section)
         ordered_files = [f for run in runs for f in run.files]
         has_dv = any(f.file_name in dvs for f in ordered_files)
-        if current_mesh_context() is None and self.merge.supports_keys_only_pipeline() and not has_dv:
-            # single-device: overlap host decode with the device sort
-            kv = self._pipelined_dedup(ordered_files, key_filter, seq_ascending)
+        ctx = current_mesh_context()
+        batched = getattr(ctx, "plans_globally", False)  # a MeshExecutor (a reader's round), not the legacy MeshBatchContext
+        if (ctx is None or batched) and self.merge.supports_keys_only_pipeline() and not has_dv:
+            resolve = self._pipelined_dedup(ordered_files, key_filter, seq_ascending)
+            if batched:
+                # the select is a job of the round's shard_map: the caller
+                # resolves once every split of the round has dispatched
+                return resolve
+            # single-device: the host decode has overlapped the device sort
+            kv = resolve()
             return lambda: kv
-        # mesh/DV/engine path
+        # legacy mesh context/DV/engine path
         kv = self._read_files(ordered_files, key_filter, dvs)
         handle = self.merge.merge_async(kv, seq_ascending=seq_ascending)
         return lambda: self.merge.merge_resolve(handle)
@@ -210,12 +217,13 @@ class MergeFileSplitRead:
         mask = ~dv.deleted_mask(kv.num_rows)
         return kv.filter(mask) if not mask.all() else kv
 
-    def _pipelined_dedup(self, ordered_files, key_filter, seq_ascending: bool) -> KVBatch:
+    def _pipelined_dedup(self, ordered_files, key_filter, seq_ascending: bool):
         """Overlap host decode with the device merge: decode just the key
         columns, dispatch the dedup kernel (async), decode the value columns
-        while the device sorts, then gather the winners from the per-file
-        value columns. The two decode passes share the predicate, so their
-        row sets are identical (datafile.read contract)."""
+        while the device sorts; the zero-arg continuation returned resolves
+        the select and gathers the winners from the per-file value columns.
+        The two decode passes share the predicate, so their row sets are
+        identical (datafile.read contract)."""
         key_names = [n for n in self.reader_factory.read_schema.field_names if n in self.key_names]
         rest_names = [n for n in self.reader_factory.read_schema.field_names if n not in self.key_names]
         # run stability replaces sequence comparison when seq ranges are
@@ -231,11 +239,12 @@ class MergeFileSplitRead:
         with span("concat", rows=sum(h.num_rows for h in heads), columns=len(key_names)):
             kv_keys = KVBatch.concat(heads)
         if kv_keys.num_rows == 0:
-            return KVBatch(
+            empty = KVBatch(
                 ColumnBatch.empty(self.reader_factory.read_schema),
                 np.empty(0, dtype=np.int64),
                 np.empty(0, dtype=np.uint8),
             )
+            return lambda: empty
         # file -> run offsets for key-range tiling (files of one run are
         # consecutive in ordered_files and key-sorted)
         run_offsets = [0]
@@ -252,8 +261,7 @@ class MergeFileSplitRead:
                     ordered_files,
                     parallelism=self.parallelism,
                 )
-        take = self.merge.dedup_resolve(handle)
-        return self._gather_winners(kv_keys, tails, run_offsets, take)
+        return lambda: self._gather_winners(kv_keys, tails, run_offsets, self.merge.dedup_resolve(handle))
 
     def _gather_winners(self, kv_keys: KVBatch, tails: list[KVBatch], run_offsets: list[int], take: np.ndarray) -> KVBatch:
         """The winners of the keys-only pipeline, a column a task on the

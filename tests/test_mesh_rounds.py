@@ -190,13 +190,14 @@ def test_jobs_outside_a_round_run_together_and_rounds_apart(axis):
 
 MESH_SPANS = {  # span -> the span that causes it
     "mesh.feed": "read_all", "split": "read_all", "mesh.plan": "split", "mesh.stack": "split", "mesh.batch": "split",
-    "mesh.h2d": "mesh.batch", "mesh.run": "mesh.batch", "mesh.d2h": "mesh.batch", "gather": "split"}
+    "mesh.h2d": "mesh.batch", "mesh.run": "mesh.batch", "mesh.d2h": "mesh.batch", "gather": "split",
+    "gather.plan": "gather"}
 
 
 @pytest.fixture
 def traced_mesh_read(tables, axis, tmp_path):
     """One mesh read under a profiler session: its `pt:` events as (name,
-    start_ns, end_ns, line, stats)."""
+    start_ns, end_ns, line, stats), and what it counted under read{...}."""
     import glob
 
     mesh_t, _, _, rows_in = tables
@@ -207,25 +208,32 @@ def traced_mesh_read(tables, axis, tmp_path):
     options = jax.profiler.ProfileOptions()
     options.host_tracer_level = 1
     options.python_tracer_level = 0
+    before = registry.snapshot()["read"]
     jax.profiler.start_trace(str(tmp_path), profiler_options=options)
     try:
         _read(mesh_t)
     finally:
         jax.profiler.stop_trace()
+    counted = {k: v - before.get(k, 0) for k, v in registry.snapshot()["read"].items()}
     path = sorted(glob.glob(str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")))[-1]
     return [(e.name[3:], e.start_ns, e.start_ns + e.duration_ns, (pi, li), dict(e.stats))
             for pi, plane in enumerate(jax.profiler.ProfileData.from_file(path).planes)
-            for li, line in enumerate(plane.lines) for e in line.events if e.name.startswith("pt:")], rows_in
+            for li, line in enumerate(plane.lines) for e in line.events if e.name.startswith("pt:")], rows_in, counted
 
 
-def test_a_traced_mesh_read_opens_the_spans_nested_and_numbered(traced_mesh_read, axis):
-    events, rows_in = traced_mesh_read
-    (read_all,) = [e for e in events if e[0] == "read_all"]
-    op, reader = read_all[4]["op"], read_all[3]
-    calls = -(-BUCKETS // axis)
+def _by_name(events):
     by_name = {}
     for e in events:
         by_name.setdefault(e[0], []).append(e)
+    return by_name
+
+
+def test_a_traced_mesh_read_opens_the_spans_nested_and_numbered(traced_mesh_read, axis):
+    events, rows_in, _ = traced_mesh_read
+    (read_all,) = [e for e in events if e[0] == "read_all"]
+    op, reader = read_all[4]["op"], read_all[3]
+    calls = -(-BUCKETS // axis)
+    by_name = _by_name(events)
     for name, parent in MESH_SPANS.items():
         assert by_name.get(name), (name, sorted(by_name))
         mine = [e for e in by_name[name] if e[3] == reader]
@@ -246,10 +254,99 @@ def test_a_traced_mesh_read_opens_the_spans_nested_and_numbered(traced_mesh_read
     assert sum(e[4]["winners"] for e in by_name["mesh.d2h"]) == KEYS
     assert sum(e[4]["d2h_bytes"] for e in by_name["mesh.d2h"]) == stacked * 4 + calls * axis * 8
     # what the feeder's threads open for a split names the operation too
-    for name in ("pipeline.scan", "decode.all", "decode.file", "lanes.encode"):
+    for name in ("pipeline.scan", "decode.keys", "decode.values", "decode.file", "lanes.encode"):
         others = [e for e in by_name.get(name, []) if e[3] != reader]
         assert others and all(e[4]["op"] == op for e in others), name
     assert len([e for e in by_name["split"] if e[3] != reader]) == BUCKETS  # the dispatch half, on the feeder
+
+
+def test_a_mesh_read_takes_the_keys_only_pipeline(traced_mesh_read, axis):
+    """ISSUE 30: under a round a split sends its key lanes only and takes its
+    winners from the per-file value parts: the two decode passes on the feeder's
+    threads, the gather's plan on the reading thread, a column a task on the pool."""
+    events, rows_in, counted = traced_mesh_read
+    columns = len(SCHEMA.fields)
+    # every column, seq and kind of every winner (all +I here); from the parts the numeric value columns v and d:
+    # pyarrow joins the chunks of the arrow-backed s inside its take, and id, seq, kind come from the key pass
+    assert counted["rows_gathered"] == KEYS * (columns + 2)
+    assert counted["rows_gathered_from_parts"] == KEYS * 2
+    by_name = _by_name(events)
+    (read_all,) = by_name["read_all"]
+    op, reader = read_all[4]["op"], read_all[3]
+    assert "decode.all" not in by_name
+    for name in ("decode.keys", "decode.values"):
+        assert len(by_name[name]) == BUCKETS and all(e[3] != reader and e[4]["files"] == RUNS for e in by_name[name]), name
+    assert sorted(e[4]["pass"] for e in by_name["decode.file"]) == ["keys"] * BUCKETS * RUNS + ["values"] * BUCKETS * RUNS
+    # a feeder thread joins the key pass alone; whole rows are joined by the reader (sections, then splits)
+    feeder_concats = [e for e in by_name["concat"] if e[3] != reader]
+    assert len(feeder_concats) == BUCKETS and all(e[4]["columns"] == 1 for e in feeder_concats)
+    assert sum(e[4]["rows"] for e in feeder_concats) == rows_in
+    for name in ("gather", "gather.plan"):
+        assert len(by_name[name]) == BUCKETS and all(e[3] == reader for e in by_name[name]), name
+    assert all(e[4]["parts"] == RUNS and e[4]["columns"] == columns for e in by_name["gather"])
+    assert sum(e[4]["rows_in"] for e in by_name["gather"]) == rows_in
+    assert sum(e[4]["rows_out"] for e in by_name["gather"]) == KEYS
+    cols = by_name["gather.column"]
+    assert sorted(e[4]["column"] for e in cols) == sorted(["id", "v", "d", "s", "_seq", "_kind"] * BUCKETS)
+    assert all(e[3] != reader and e[4]["op"] == op and e[4]["parent"] == "gather" for e in cols)
+    assert all(e[4]["parts"] == (RUNS if e[4]["column"] in ("v", "d", "s") else 1) for e in cols)
+
+
+# ---- what stays on the whole-batch merge under the mesh engine ---------------
+
+def _kinds(ids, run):
+    """A tenth of a later run's rows are deletes."""
+    return ["-D" if run and i % 10 == run else "+I" for i in ids.tolist()]
+
+
+WHOLE_BATCH = {  # case -> (table options, a write's kinds)
+    "deletion-vector": ({"deletion-vectors.enabled": "true"}, None),
+    "partial-update": ({"merge-engine": "partial-update"}, None),
+    "ignore-delete": ({"ignore-delete": "true"}, _kinds),
+    "sequence-field": ({"sequence.field": "v"}, None),
+}
+
+
+@pytest.mark.parametrize("case", list(WHOLE_BATCH))
+def test_the_paths_that_stay_on_the_whole_batch_merge_read_as_the_single_engine(case, axis, tmp_path):
+    """A split with a deletion vector, another merge engine, ignore-delete and
+    a user sequence field need more than the key pass to pick a winner: they
+    keep `_read_files` + `merge_async` under a round, and read what the single
+    engine reads."""
+    from paimon_tpu.data.predicate import less_than
+
+    options, kinds = WHOLE_BATCH[case]
+    cat = FileSystemCatalog(str(tmp_path), commit_user="mesh-rounds")
+    opts = {"bucket": str(BUCKETS), "write-only": "true", **options}
+    mesh_t = cat.create_table("db.mesh", SCHEMA, primary_keys=["id"], options={**opts, "merge.engine": "mesh"})
+    single_t = cat.create_table("db.single", SCHEMA, primary_keys=["id"], options=opts)
+    runs, ids, _ = _runs(30)
+    for t in (mesh_t, single_t):
+        for r, run_ids in enumerate(runs):
+            wb = t.new_batch_write_builder()
+            w = wb.new_write()
+            cols = _columns(run_ids, r)
+            if case == "sequence-field":  # the first writer wins: the user's order is not the run order
+                cols["v"] = run_ids * 10 - r
+            w.write(cols, **({"kinds": kinds(run_ids, r)} if kinds else {}))
+            wb.new_commit().commit(w.prepare_commit())
+        if case == "deletion-vector":  # some 130 keys over 8 buckets: every split has a vector
+            assert t.delete_where(less_than("id", 400)) > BUCKETS
+    splits = mesh_t.new_read_builder().new_scan().plan()
+    assert len(splits) == BUCKETS and (case != "deletion-vector" or all(s.dv_index_file for s in splits))
+    before = registry.snapshot()
+    got = _read(mesh_t)
+    now = registry.snapshot()
+    assert now["mesh"]["buckets_sharded"] - before["mesh"]["buckets_sharded"] == BUCKETS  # the rounds ran them
+    assert now["read"].get("rows_gathered_from_parts", 0) == before["read"].get("rows_gathered_from_parts", 0)
+    want = _read(single_t)
+    assert 0 < got.num_rows <= KEYS and got.to_pylist() == want.to_pylist()
+    if case == "sequence-field":
+        first = {}
+        for r, run_ids in enumerate(runs):
+            for i in run_ids.tolist():
+                first.setdefault(i, r)
+        assert _by_key(got)["v"] == [i * 10 - first[i] for i in ids.tolist()]
 
 
 @pytest.mark.parametrize("name,build", [
