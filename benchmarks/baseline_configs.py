@@ -117,7 +117,7 @@ def config3(scale: float):
             "fields.sum_col.aggregate-function": "sum",
             "fields.max_col.aggregate-function": "max",
             "write-only": "true",
-            **({"parallel.mesh.enabled": "true"} if mesh_ok else {}),
+            **({"merge.engine": "mesh"} if mesh_ok else {}),
         })
         per = rows // 4
         rng = np.random.default_rng(1)
@@ -185,7 +185,7 @@ def config5(scale: float):
         schema = pt.RowType.of(("id", pt.BIGINT(False)), ("x", pt.BIGINT()), ("y", pt.BIGINT()), ("v", pt.DOUBLE()))
         t = _mk(tmp, "db.c5", schema, ["id"], {
             "bucket": str(buckets), "write-only": "true",
-            **({"parallel.mesh.enabled": "true"} if mesh_ok else {}),
+            **({"merge.engine": "mesh"} if mesh_ok else {}),
         })
         rng = np.random.default_rng(3)
         per = rows // 4

@@ -248,12 +248,12 @@ class MergeTreeCompactRewriter:
         """Phase 1: read every section's runs and dispatch their merges.
         Under a mesh context the merges of ALL sections (and all buckets
         whose compactions dispatched in the same batch window) execute in
-        batched shard_map calls over the mesh; with the MeshExecutor active
-        the section reads additionally stream through the SplitPipeline
-        feeder (one prefetch lane per device) instead of running serially."""
+        batched shard_map calls over the mesh, and the section reads stream
+        through the SplitPipeline feeder (one prefetch lane per device)
+        instead of running serially."""
         import threading
 
-        from ..parallel.executor import current_mesh_context
+        from ..parallel.mesh_exec import current_mesh_context
         from ..parallel.pipeline import PIPELINE_THREAD_PREFIX
 
         ctx = current_mesh_context()
@@ -261,12 +261,7 @@ class MergeTreeCompactRewriter:
         # worker (table/write.compact fans buckets out), the serial loop below
         # still fans its file reads over the shared pool
         in_worker = threading.current_thread().name.startswith(PIPELINE_THREAD_PREFIX)
-        if (
-            ctx is not None
-            and getattr(ctx, "plans_globally", False)
-            and len(sections) > 1
-            and not in_worker
-        ):
+        if ctx is not None and len(sections) > 1 and not in_worker:
             from ..parallel.pipeline import SplitPipeline
 
             lanes = ctx.feeder_lanes
@@ -337,8 +332,7 @@ class MergeTreeCompactManager:
 
     def trigger_compaction(self, full: bool = False) -> CompactResult | None:
         from ..metrics import registry, timed
-        from ..parallel.executor import current_mesh_context
-        from ..parallel.mesh_exec import maybe_mesh_exec
+        from ..parallel.mesh_exec import current_mesh_context, maybe_mesh_exec
         from ..parallel.pipeline import pipeline_config
 
         depth, parallelism = pipeline_config(self.options)
@@ -407,9 +401,9 @@ class MergeTreeCompactManager:
 
     def compact_dispatch(self, full: bool = False):
         """Phase 1: plan the unit, then read inputs and dispatch the section
-        merges (under a MeshBatchContext every bucket's merges batch into one
-        shard_map). Returns opaque state for compact_complete, or None when
-        nothing to compact."""
+        merges (under a mesh context every bucket's merges batch into
+        shard_maps over the mesh). Returns opaque state for compact_complete,
+        or None when nothing to compact."""
         plan = self._plan_unit(full)
         if plan is None:
             return None

@@ -190,15 +190,16 @@ class MergeExecutor:
         return self.merge_resolve(self.merge_async(kv, seq_ascending))
 
     def merge_async(self, kv: KVBatch, seq_ascending: bool = False):
-        """Dispatch half of merge(). When a MeshBatchContext is active, the
-        bucket's merge becomes a job — every job dispatched in the batch
-        window runs in one shard_map over the mesh at the first resolve;
-        without a context the merge computes eagerly inside the handle. One
+        """Dispatch half of merge(). When a mesh context is active (a
+        MeshExecutor or one of its rounds), the bucket's merge becomes a job
+        — the jobs of one round, or of one batch window, run in family-batched
+        shard_maps over the mesh at the first resolve; without a context the
+        merge computes eagerly inside the handle. One
         copy of the preamble (ignore-delete, sorted-unique shortcut, lane
         encoding) serves both paths, so mesh and single-device execution
         cannot diverge. Resolve with merge_resolve()."""
         from ..options import SortEngine
-        from ..parallel.executor import current_mesh_context
+        from ..parallel.mesh_exec import current_mesh_context
 
         ctx = current_mesh_context()
         if kv.num_rows == 0:
@@ -221,25 +222,18 @@ class MergeExecutor:
             if engine == SortEngine.NUMPY:
                 return ("sync", kv.take(_numpy_dedup_select(lanes, seq_lanes, self._compress)))
             if ctx is not None:
-                if getattr(ctx, "plans_globally", False):
-                    # MeshExecutor: submit RAW lanes — compression is decided
-                    # ONCE per family batch from stats reduced over every
-                    # shard (ops.lanes.plan_lanes_global), so all shards of
-                    # one shard_map agree on packed widths (ISSUE 7 fix)
-                    from ..ops.lanes import resolve_compress
+                # submit RAW lanes — compression is decided ONCE per family
+                # batch from stats reduced over every shard
+                # (ops.lanes.plan_lanes_global), so all shards of one
+                # shard_map agree on packed widths (ISSUE 7 fix)
+                from ..ops.lanes import resolve_compress
 
-                    return (
-                        "dedup",
-                        ctx,
-                        ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress)),
-                        kv,
-                    )
-                # legacy MeshBatchContext: compress before submit (per-job
-                # plans are safe there — jobs never share a comparator)
-                from ..ops.lanes import compress_key_lanes
-
-                cl, _ = compress_key_lanes(lanes, self._compress, enable_ovc=False)
-                return ("dedup", ctx, ctx.submit_dedup(cl, seq_lanes), kv)
+                return (
+                    "dedup",
+                    ctx,
+                    ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress)),
+                    kv,
+                )
             backend = "pallas" if engine == SortEngine.PALLAS else "xla"
             from ..ops.merge import deduplicate_resolve, deduplicate_select_async
 
@@ -250,19 +244,14 @@ class MergeExecutor:
         lanes, seq_lanes = self._lanes(kv, seq_ascending)
         engine = self.effective_sort_engine()
         if ctx is not None and engine != SortEngine.NUMPY:
-            if getattr(ctx, "plans_globally", False):
-                from ..ops.lanes import resolve_compress
+            from ..ops.lanes import resolve_compress
 
-                return (
-                    "plan",
-                    ctx,
-                    ctx.submit_plan(lanes, seq_lanes, compress=resolve_compress(self._compress)),
-                    kv,
-                )
-            from ..ops.lanes import compress_key_lanes
-
-            cl, _ = compress_key_lanes(lanes, self._compress, enable_ovc=False)
-            return ("plan", ctx, ctx.submit_plan(cl, seq_lanes), kv)
+            return (
+                "plan",
+                ctx,
+                ctx.submit_plan(lanes, seq_lanes, compress=resolve_compress(self._compress)),
+                kv,
+            )
         if engine != SortEngine.NUMPY:
             # single-device fast paths: sort + segment + engine selection in
             # ONE kernel call (no plan download, no per-field round trips)
@@ -317,13 +306,13 @@ class MergeExecutor:
         a round is the tile."""
         lanes, seq_lanes = self._lanes(kv_keys, seq_ascending)
         from ..options import SortEngine
-        from ..parallel.executor import current_mesh_context
+        from ..parallel.mesh_exec import current_mesh_context
 
         engine = self.effective_sort_engine()
         if engine == SortEngine.NUMPY:
             return ("numpy", _numpy_dedup_select(lanes, seq_lanes, self._compress))
         ctx = current_mesh_context()
-        if getattr(ctx, "plans_globally", False):
+        if ctx is not None:
             from ..ops.lanes import resolve_compress
 
             return ("mesh", (ctx, ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress))))

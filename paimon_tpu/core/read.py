@@ -121,10 +121,10 @@ class MergeFileSplitRead:
         """Phase 1 of the (possibly mesh-batched) merge-read: read the
         section inputs and dispatch their merges; returns a zero-arg
         continuation producing the final ColumnBatch. Under an active
-        MeshBatchContext, the merges of every split dispatched in the same
-        batch window execute as ONE shard_map over the mesh's bucket axis —
-        the TPU equivalent of the reference shipping one split per task
-        (MergeTreeSplitGenerator.java:38)."""
+        mesh context (parallel/mesh_exec.py), the merges of every split
+        dispatched in the same round execute in family-batched shard_maps
+        over the mesh's bucket axis — the TPU equivalent of the reference
+        shipping one split per task (MergeTreeSplitGenerator.java:38)."""
         key_parts = []
         if predicate is not None:
             parts = PredicateBuilder.split_and(predicate)
@@ -146,7 +146,7 @@ class MergeFileSplitRead:
     def _dispatch_section(self, section, predicate, key_filter, dvs: dict):
         """Read one section's inputs and dispatch its merge; returns the
         zero-arg continuation that gives the section's merged KVBatch."""
-        from ..parallel.executor import current_mesh_context
+        from ..parallel.mesh_exec import current_mesh_context
 
         if len(section) == 1:
             # single sorted run: keys are unique — no merge needed; full
@@ -156,18 +156,16 @@ class MergeFileSplitRead:
         runs, seq_ascending = order_runs_for_merge(section)
         ordered_files = [f for run in runs for f in run.files]
         has_dv = any(f.file_name in dvs for f in ordered_files)
-        ctx = current_mesh_context()
-        batched = getattr(ctx, "plans_globally", False)  # a MeshExecutor (a reader's round), not the legacy MeshBatchContext
-        if (ctx is None or batched) and self.merge.supports_keys_only_pipeline() and not has_dv:
+        if self.merge.supports_keys_only_pipeline() and not has_dv:
             resolve = self._pipelined_dedup(ordered_files, key_filter, seq_ascending)
-            if batched:
+            if current_mesh_context() is not None:
                 # the select is a job of the round's shard_map: the caller
                 # resolves once every split of the round has dispatched
                 return resolve
             # single-device: the host decode has overlapped the device sort
             kv = resolve()
             return lambda: kv
-        # legacy mesh context/DV/engine path
+        # deletion vectors and the engines that merge whole batches
         kv = self._read_files(ordered_files, key_filter, dvs)
         handle = self.merge.merge_async(kv, seq_ascending=seq_ascending)
         return lambda: self.merge.merge_resolve(handle)

@@ -356,8 +356,8 @@ class KeyValueFileStore:
         deletion_vectors: dict | None = None,
     ):
         """Two-phase read_bucket for mesh execution: returns a continuation;
-        the merge jobs of all buckets dispatched in one MeshBatchContext run
-        in a single batched shard_map."""
+        the merge jobs of all buckets dispatched in one round of the mesh
+        executor run in a single batched shard_map."""
         expire = self.record_expire_predicate()
         if expire is not None:
             from ..data.predicate import and_

@@ -177,7 +177,7 @@ class MergeTreeWriter:
         """Drain the memtable; run the complete phase on the flush worker
         when pipelining is on (so the caller returns to filling the next
         memtable), inline otherwise. FIFO on one worker = sequential order."""
-        from ..parallel.executor import current_mesh_context
+        from ..parallel.mesh_exec import current_mesh_context
 
         state = self.flush_dispatch()
         if state is None:
@@ -277,7 +277,7 @@ class MergeTreeWriter:
     def flush_dispatch(self):
         """Phase 1 of a (possibly mesh-batched) flush: drain the memtable,
         persist the input changelog, and dispatch the merge. Under an active
-        MeshBatchContext the merge job is only enqueued — every bucket's job
+        mesh context the merge job is only enqueued — every bucket's job
         runs in one batched mesh call when the first flush_complete resolves.
 
         Any offloaded flush_complete still in flight lands first (and its

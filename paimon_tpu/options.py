@@ -434,13 +434,6 @@ class CoreOptions:
         "1-device or shard_map-less environment degrades to 'single' "
         "automatically (cpu fallback). PAIMON_TPU_MERGE_ENGINE overrides.",
     )
-    PARALLEL_MESH_ENABLED = ConfigOption.bool_(
-        "parallel.mesh.enabled",
-        False,
-        "Execute write flush / compaction rewrite / merge-read over the device "
-        "mesh: per-bucket merge jobs batch into one shard_map over the bucket "
-        "axis; oversized buckets range-shuffle over the key axis.",
-    )
     DATA_FILE_INCLUDE_KEY_COLUMNS = ConfigOption.bool_(
         "data-file.include-key-columns",
         False,

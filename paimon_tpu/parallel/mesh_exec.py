@@ -16,8 +16,11 @@ all_to_all — the RangeShuffle.java analog), and sort-compact / dynamic-bucket
 rescale use the same collective through `mesh_cluster_permutation` /
 `range_partition_rows`.
 
-Three properties distinguish it from the older `MeshBatchContext`
-(parallel.mesh.enabled), which it supersedes when enabled:
+This module is the one seam: it owns the active context (a ContextVar no
+other module names). Table operations ask `maybe_mesh_exec(options)` for an
+executor, dispatch code asks `current_mesh_context()` and gets the executor,
+one of its rounds, or None, and a feeder thread installs either with
+`MeshExecutor.active`. Three properties of the engine:
 
   GLOBAL LANE PLANNING — every job in a family batch shares ONE `LanePlan`
   computed from lane stats reduced across all shards
@@ -50,9 +53,12 @@ pk-8bucket-mesh.merge-read.
 
 from __future__ import annotations
 
+import contextvars
+import functools
 import os
 import threading
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass
 
 import numpy as np
@@ -62,6 +68,8 @@ __all__ = [
     "mesh_available",
     "resolve_merge_engine",
     "maybe_mesh_exec",
+    "current_mesh_context",
+    "distributed_dedup_select",
     "mesh_cluster_permutation",
     "mesh_feeder_lanes",
 ]
@@ -97,36 +105,48 @@ def resolve_merge_engine(options) -> str:
     return "mesh" if v == "mesh" else "single"
 
 
+_ACTIVE: contextvars.ContextVar["MeshExecutor | _Round | None"] = contextvars.ContextVar(
+    "paimon_mesh_context", default=None
+)
+
+
+def current_mesh_context() -> "MeshExecutor | _Round | None":
+    """The mesh context of this thread: the executor, the round of it that
+    this dispatch belongs to, or None (single-device execution)."""
+    return _ACTIVE.get()
+
+
+@contextmanager
 def maybe_mesh_exec(options):
-    """Context manager: install a MeshExecutor as the active mesh context iff
-    `merge.engine = mesh` resolves, the mesh is usable, and no context is
-    already active (nesting would double-batch); yields None otherwise so
-    callers keep their single-device path unchanged."""
-    from contextlib import contextmanager
+    """The one mesh-entry seam for table operations: install a MeshExecutor
+    as the active mesh context iff `merge.engine = mesh` resolves, the mesh
+    is usable, and no context is already active (nesting would double-batch);
+    yields None otherwise so callers keep their single-device path
+    unchanged."""
+    if (
+        resolve_merge_engine(options) != "mesh"
+        or current_mesh_context() is not None
+        or not mesh_available()
+    ):
+        yield None
+        return
+    from ..options import CoreOptions
 
-    from .executor import _ACTIVE, current_mesh_context
+    mex = MeshExecutor(key_axis_rows=options.options.get(CoreOptions.PARALLEL_KEY_AXIS_ROWS))
+    with mex.active():
+        yield mex
 
-    @contextmanager
-    def _cm():
-        if (
-            resolve_merge_engine(options) != "mesh"
-            or current_mesh_context() is not None
-            or not mesh_available()
-        ):
-            yield None
-            return
-        from ..options import CoreOptions
 
-        ctx = MeshExecutor(
-            key_axis_rows=options.options.get(CoreOptions.PARALLEL_KEY_AXIS_ROWS)
-        )
-        token = _ACTIVE.set(ctx)
-        try:
-            yield ctx
-        finally:
-            _ACTIVE.reset(token)
+@functools.lru_cache(maxsize=None)
+def _meshes():
+    """(bucket_mesh, key_mesh) over every visible device: all devices on the
+    bucket axis for batched per-bucket jobs, all on the key axis for the
+    range-shuffle path of one oversized bucket."""
+    from .mesh import make_mesh
 
-    return _cm()
+    bucket = make_mesh(None)  # {"bucket": N, "key": 1}
+    key = make_mesh(None, bucket_parallel=1)  # {"bucket": 1, "key": N}
+    return bucket, key
 
 
 # one batched call is chunked so padded lanes stay under this many uint32s
@@ -146,8 +166,6 @@ class _Round:
     """The executor as the dispatch of one split sees it (the mesh-context
     protocol of core.mergefn): a job submitted through it belongs to round
     `n` and runs with that round's jobs only."""
-
-    plans_globally = True
 
     def __init__(self, mex: "MeshExecutor", n: int):
         self.mex, self.n = mex, n
@@ -171,15 +189,11 @@ class MeshExecutor:
     shard_map calls over the bucket mesh. Implements the mesh-context
     protocol of core.mergefn (submit_dedup / submit_plan / result), so every
     dispatch/complete consumer (merge read, compaction, writer flush) routes
-    through it unchanged. `plans_globally` tells submitters to hand over RAW
-    lanes — compression is decided here, once per family batch, from stats
-    reduced over every shard (ops.lanes.plan_lanes_global)."""
-
-    plans_globally = True
+    through it unchanged. Submitters hand over RAW lanes — compression is
+    decided here, once per family batch, from stats reduced over every shard
+    (ops.lanes.plan_lanes_global)."""
 
     def __init__(self, mesh=None, key_axis_rows: int = 1 << 22):
-        from .executor import _meshes
-
         self.bucket_mesh, self.key_mesh = (mesh, mesh) if mesh is not None else _meshes()
         self.key_axis_rows = key_axis_rows
         self._jobs: dict[int, _Job] = {}
@@ -215,6 +229,19 @@ class MeshExecutor:
         dispatches; the caller resolves a round's jobs only once all of its
         dispatches are in."""
         return _Round(self, n)
+
+    @contextmanager
+    def active(self, round: int | None = None):
+        """Install this executor, or its round `round`, as the mesh context
+        of the calling thread for the extent of the block. A ContextVar does
+        not cross into pipeline worker threads by itself: a feeder's dispatch
+        wraps itself in this, or it would merge eagerly on the worker instead
+        of enqueuing its job."""
+        token = _ACTIVE.set(self if round is None else self.round(round))
+        try:
+            yield
+        finally:
+            _ACTIVE.reset(token)
 
     def result(self, job_id: int):
         if job_id not in self._results:
@@ -382,8 +409,6 @@ class MeshExecutor:
         exchanged lanes stay comparable."""
         from ..metrics import timed
 
-        from .executor import distributed_dedup_select
-
         g = _metrics()
         lanes = job.lanes
         if job.compress:
@@ -404,6 +429,66 @@ class MeshExecutor:
             return distributed_dedup_select(self.key_mesh, lanes, job.seq_lanes)
 
 
+# ---------------------------------------------------------------------------
+# key-axis path: one oversized bucket range-partitioned over all devices
+# ---------------------------------------------------------------------------
+
+
+@functools.lru_cache(maxsize=None)
+def _key_axis_dedup_fn(mesh, k: int, s: int):
+    """jitted range-shuffle dedup over the mesh's key axis (cached per
+    (mesh, lane arity) like the bucket-axis programs of parallel/merge.py)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from .merge import _local_plan, _range_exchange
+
+    p = mesh.shape["key"]
+    sentinel = np.uint32(0xFFFFFFFF)
+
+    def shard_fn(klx, slx, pfx):
+        rk, rs, rp = _range_exchange(klx.T, slx.T, pfx, "key", p, k, s + 1)
+        perm, _, keep_last, _ = _local_plan(k, s + 1, rk, rs, rp)
+        sel = keep_last & (rp[perm] == 0)
+        rowids = rs[s][perm]
+        return jnp.where(sel, rowids, sentinel)
+
+    fn = shard_map(
+        shard_fn,
+        mesh=mesh,
+        in_specs=(P("key", None), P("key", None), P("key")),
+        out_specs=P("key"),
+    )
+    return jax.jit(fn)
+
+
+def distributed_dedup_select(mesh, key_lanes: np.ndarray, seq_lanes: np.ndarray | None = None) -> np.ndarray:
+    """Dedup selection for ONE bucket whose rows are sharded over the mesh's
+    "key" axis: sample splitters (all_gather), range-shuffle rows to their
+    owner (all_to_all over ICI), locally sort + keep-last, return the winning
+    INPUT row indices in global key order. The row id rides the shuffle as the
+    final sort lane, which reproduces input-order tie-break across devices."""
+    n, k = key_lanes.shape
+    p = mesh.shape["key"]
+    if seq_lanes is None:
+        seq_lanes = np.zeros((n, 0), dtype=np.uint32)
+    s = seq_lanes.shape[1]
+    m_loc = -(-n // p)  # ceil
+    total = m_loc * p
+    kl = np.full((total, k), 0xFFFFFFFF, dtype=np.uint32)
+    kl[:n] = key_lanes
+    sl = np.zeros((total, s + 1), dtype=np.uint32)
+    sl[:n, :s] = seq_lanes
+    sl[:, s] = np.arange(total, dtype=np.uint32)  # row id = last tie-break lane
+    pad = np.zeros(total, dtype=np.uint32)
+    pad[n:] = 1
+    out = np.asarray(_key_axis_dedup_fn(mesh, k, s)(kl, sl, pad))
+    # shards own ascending key ranges and emit sorted order -> already key order
+    return out[out != np.uint32(0xFFFFFFFF)].astype(np.int32)
+
+
 def mesh_feeder_lanes(options) -> int:
     """Feeder width for mesh-driven host pipelines outside an installed
     executor (sort-compact's bucket loop): one lane per device on the bucket
@@ -411,8 +496,6 @@ def mesh_feeder_lanes(options) -> int:
     serial loop)."""
     if resolve_merge_engine(options) != "mesh" or not mesh_available():
         return 0
-    from .executor import _meshes
-
     return int(_meshes()[0].shape["bucket"])
 
 
@@ -439,7 +522,6 @@ def mesh_cluster_permutation(lanes: np.ndarray, options) -> np.ndarray | None:
     if n < max(int(threshold), 2):
         return None
     from ..ops.lanes import apply_plan, plan_lanes_global
-    from .executor import _meshes
     from .merge import range_partition_rows
 
     key_mesh = _meshes()[1]

@@ -9,7 +9,6 @@ IntervalPartition), DataTableBatchScan with time travel via scan options
 
 from __future__ import annotations
 
-from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Sequence
 
@@ -401,11 +400,6 @@ def _pack_bucket_splits(files, target: int, open_cost: int, keyed: bool) -> list
     return packs
 
 
-@contextmanager
-def _null_ctx():
-    yield None
-
-
 class TableRead:
     def __init__(
         self,
@@ -478,28 +472,29 @@ class TableRead:
         every split before the first row is visible. Three execution modes,
         picked per call:
 
-        * mesh execution (merge.engine = mesh, >1 device): the SplitPipeline
-          becomes the host-side feeder — one prefetch lane per device — so
-          IO/decode of split i+1 overlaps the batched shard_map merges of
-          split i (parallel/mesh_exec.py);
-        * mesh batching (parallel.mesh.enabled, >1 device): dispatch every
-          split first so all merges run in one shard_map, then complete;
-        * pipelined (scan.prefetch-splits > 0, the default): split i+1
-          fetches bytes through RetryingFileIO and decodes on a pipeline
-          worker while split i merges on device — output is bit-identical
-          to the sequential path (parallel/pipeline.py contract);
-        * sequential (scan.prefetch-splits = 0, or a limit wanting
-          split-by-split early exit)."""
-        from ..parallel.executor import maybe_mesh_batch
+        * mesh execution (merge.engine = mesh, >1 device, no limit): the
+          SplitPipeline becomes the host-side feeder — one prefetch lane per
+          device — so IO/decode of round i+1 overlaps the batched shard_map
+          merges of round i (_mesh_batches; any number of splits, a single
+          split is a round of one job);
+        * pipelined (scan.prefetch-splits > 0, the default, several splits,
+          no limit): split i+1 fetches bytes through RetryingFileIO and
+          decodes on a pipeline worker while split i merges on device —
+          output is bit-identical to the sequential path
+          (parallel/pipeline.py contract);
+        * sequential (scan.prefetch-splits = 0, a single split, or a limit:
+          a limit wants early exit split by split — dispatching every split
+          up front would turn a point query into a full scan)."""
+        from ..parallel.mesh_exec import maybe_mesh_exec
 
         splits = list(splits)
         remaining = self.limit
-        # a limit wants early-exit split by split — dispatching every split
-        # up front would turn a point query into a full scan, so limited
-        # reads stay on the sequential path
-        use_mesh = remaining is None
-        with maybe_mesh_batch(self.table.store) if use_mesh else _null_ctx() as ctx:
-            if ctx is None and remaining is None and len(splits) > 1:
+        if remaining is None:
+            with maybe_mesh_exec(self.table.store.options) as mex:
+                if mex is not None:
+                    yield from self._mesh_batches(mex, splits)
+                    return
+            if len(splits) > 1:
                 depth, parallelism = self.table.store.pipeline_config()
                 if depth > 0:
                     from ..parallel.pipeline import SplitPipeline
@@ -507,28 +502,15 @@ class TableRead:
                     pipe = SplitPipeline(parallelism, depth, stage="scan")
                     yield from pipe.map_ordered(splits, self.read)
                     return
-            if ctx is not None and getattr(ctx, "plans_globally", False) and len(splits) > 1:
-                # merge.engine = mesh: feeder-driven dispatch (one prefetch
-                # lane per device) instead of reading every split up front
-                yield from self._mesh_batches(ctx, splits)
-                return
-            if ctx is not None:
-                # mesh mode: dispatch every split first — their merges run as
-                # one batched shard_map over the bucket axis — then complete
-                pending = [(s, self._dispatch(s)) for s in splits if not s.is_changelog]
-                conts = dict((id(s), c) for s, c in pending)
-            for s in splits:
-                if ctx is not None and not s.is_changelog:
-                    b = conts[id(s)]()
-                else:
-                    b = self.read(s)
-                if remaining is not None:
-                    if remaining <= 0:
-                        break
-                    if b.num_rows > remaining:
-                        b = b.slice(0, remaining)
-                    remaining -= b.num_rows
-                yield b
+        for s in splits:
+            b = self.read(s)
+            if remaining is not None:
+                if remaining <= 0:
+                    break
+                if b.num_rows > remaining:
+                    b = b.slice(0, remaining)
+                remaining -= b.num_rows
+            yield b
 
     def _mesh_batches(self, mex, splits: Sequence[DataSplit]):
         """merge.engine = mesh scan: the PR 4 SplitPipeline is the host-side
@@ -544,8 +526,6 @@ class TableRead:
         from ..metrics import mesh_metrics, span
         from ..parallel.pipeline import SplitPipeline
 
-        from ..parallel.executor import _ACTIVE
-
         lanes = mex.feeder_lanes
         pipe = SplitPipeline(parallelism=lanes, depth=lanes, stage="scan")
         wait = mesh_metrics().histogram("feeder_wait_ms")
@@ -559,15 +539,8 @@ class TableRead:
         def dispatch(i: int):
             if i not in round_of:
                 return None
-            # the mesh context is a ContextVar — invisible inside pipeline
-            # worker threads unless re-installed, and without it the dispatch
-            # would silently merge eagerly on the worker instead of enqueuing
-            # the job for its round's shard_map
-            token = _ACTIVE.set(mex.round(round_of[i]))
-            try:
+            with mex.active(round_of[i]):
                 return self._dispatch(splits[i])
-            finally:
-                _ACTIVE.reset(token)
 
         it = pipe.map_ordered(range(len(splits)), dispatch)
         conts: list = []

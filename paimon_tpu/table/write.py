@@ -228,7 +228,7 @@ class TableWrite:
     def compact(self, full: bool = False) -> None:
         """Compact every bucket this write touched — or, when no rows were
         written (dedicated compact job), every live bucket of the table.
-        Under parallel.mesh.enabled the per-bucket flushes and rewrite merges
+        Under merge.engine = mesh the per-bucket flushes and rewrite merges
         batch into shard_map calls over the mesh (the TPU analog of the
         reference's one-compaction-task-per-bucket topology)."""
         if not self._writers:
@@ -236,33 +236,27 @@ class TableWrite:
             for partition, buckets in plan.grouped().items():
                 for bucket in buckets:
                     self._writer(partition, bucket)
-        from ..parallel.executor import maybe_mesh_batch
+        from ..parallel.mesh_exec import maybe_mesh_exec
 
-        with maybe_mesh_batch(self.table.store) as ctx:
-            if ctx is None:
+        with maybe_mesh_exec(self.table.store.options) as mex:
+            if mex is None:
                 for w in self._writers.values():
                     w.compact(full=full)
                 return
             self._batched_flush()
             writers = list(self._writers.values())
-            if getattr(ctx, "plans_globally", False) and len(writers) > 1:
-                # merge.engine = mesh: bucket dispatches (input reads + merge
-                # enqueue) stream through the feeder, one lane per device, so
-                # bucket i+1's IO overlaps while bucket i's merges batch
-                from ..parallel.executor import _ACTIVE
+            if len(writers) > 1:
+                # bucket dispatches (input reads + merge enqueue) stream
+                # through the feeder, one lane per device, so bucket i+1's IO
+                # overlaps while bucket i's merges batch
                 from ..parallel.pipeline import SplitPipeline
 
-                lanes = ctx.feeder_lanes
+                lanes = mex.feeder_lanes
                 pipe = SplitPipeline(parallelism=lanes, depth=lanes, stage="compact")
 
                 def dispatch(w):
-                    # re-install the mesh context: ContextVars don't cross
-                    # into pipeline worker threads by themselves
-                    token = _ACTIVE.set(ctx)
-                    try:
+                    with mex.active():
                         return w.compact_dispatch(full)
-                    finally:
-                        _ACTIVE.reset(token)
 
                 states = list(zip(writers, pipe.map_ordered(writers, dispatch)))
             else:
@@ -287,10 +281,10 @@ class TableWrite:
 
         if self.table.options.options.get(CoreOptions.COMMIT_FORCE_COMPACT) and not self.table.options.write_only:
             self.compact(full=True)
-        from ..parallel.executor import maybe_mesh_batch
+        from ..parallel.mesh_exec import maybe_mesh_exec
 
-        with maybe_mesh_batch(self.table.store) as ctx:
-            if ctx is not None:
+        with maybe_mesh_exec(self.table.store.options) as mex:
+            if mex is not None:
                 self._batched_flush()
             msgs = [m for m in (w.prepare_commit() for w in self._writers.values()) if not m.is_empty()]
         if self._assigner is not None:

@@ -41,7 +41,7 @@ def test_grand_tour(tmp_warehouse):
             "bucket": "2",
             "manifest.format": "avro",
             "data-file.include-key-columns": "true",
-            **({"parallel.mesh.enabled": "true"} if mesh_ok else {}),
+            **({"merge.engine": "mesh"} if mesh_ok else {}),
         },
     )
     # 2. CDC stream lands the initial state + churn (schema drift: 'email')

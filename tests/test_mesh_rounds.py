@@ -85,14 +85,14 @@ def _by_key(batch):
 def axis(request, monkeypatch):
     """A bucket axis of `param` devices for every MeshExecutor built inside
     the test: 4 is the benchmark cell's host."""
-    from paimon_tpu.parallel import executor
+    from paimon_tpu.parallel import mesh_exec
     from paimon_tpu.parallel.mesh import make_mesh
 
     n = request.param
     if len(jax.devices()) < n:
         pytest.skip(f"needs {n} devices")
     meshes = (make_mesh(n), make_mesh(n, bucket_parallel=1))
-    monkeypatch.setattr(executor, "_meshes", lambda: meshes)
+    monkeypatch.setattr(mesh_exec, "_meshes", lambda: meshes)
     return n
 
 
@@ -355,7 +355,7 @@ def test_the_shard_map_programs_have_names_the_sort_readers_take(axis, name, bui
     """`jit_dedup_select_mesh` / `jit_merge_plan_mesh` on the trace's XLA
     Modules line: perfbench's sort and non-sort readers go by these prefixes."""
     from paimon_tpu.parallel import merge as PM
-    from paimon_tpu.parallel.executor import _meshes
+    from paimon_tpu.parallel.mesh_exec import _meshes
 
     fn = getattr(PM, build)(_meshes()[0], 1, 0)
     shapes = [jax.ShapeDtypeStruct(s, np.uint32) for s in ((axis, 128, 1), (axis, 128, 0), (axis, 128))]

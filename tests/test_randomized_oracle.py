@@ -211,7 +211,7 @@ def test_random_ops_partitioned_dynamic_bucket(tmp_warehouse):
 )
 @pytest.mark.parametrize("seed", [13])
 def test_random_ops_mesh_mode_matches_oracle(tmp_warehouse, seed):
-    """The same randomized churn with parallel.mesh.enabled + avro manifests:
+    """The same randomized churn with merge.engine=mesh + avro manifests:
     the mesh execution path and the interop metadata plane must be invisible
     to semantics."""
     rng = np.random.default_rng(seed)
@@ -224,7 +224,7 @@ def test_random_ops_mesh_mode_matches_oracle(tmp_warehouse, seed):
             "bucket": "4",
             "num-sorted-run.compaction-trigger": "3",
             "target-file-size": "4 kb",
-            "parallel.mesh.enabled": "true",
+            "merge.engine": "mesh",
             "manifest.format": "avro",
         },
     )
