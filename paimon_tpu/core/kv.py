@@ -21,7 +21,7 @@ import numpy as np
 from ..data.batch import Column, ColumnBatch, concat_batches
 from ..types import BIGINT, TINYINT, DataField, RowKind, RowType
 
-__all__ = ["KVBatch", "SEQUENCE_FIELD_NAME", "VALUE_KIND_FIELD_NAME", "kv_disk_schema", "LEVEL_FIELD_ID_BASE"]
+__all__ = ["KVBatch", "SEQUENCE_FIELD_NAME", "VALUE_KIND_FIELD_NAME", "kv_disk_schema", "retracts", "LEVEL_FIELD_ID_BASE"]
 
 SEQUENCE_FIELD_NAME = "_SEQUENCE_NUMBER"
 VALUE_KIND_FIELD_NAME = "_VALUE_KIND"
@@ -117,5 +117,10 @@ class KVBatch:
     def drop_deletes(self) -> "KVBatch":
         """Batch reads strip -D/-U rows after merging (reference
         DropDeleteReader.java)."""
-        keep = ~np.isin(self.kind, (int(RowKind.DELETE), int(RowKind.UPDATE_BEFORE)))
-        return self.filter(keep) if not keep.all() else self
+        drop = retracts(self.kind)
+        return self.filter(~drop) if drop.any() else self
+
+
+def retracts(kind: np.ndarray) -> np.ndarray:
+    """Mask of the -D and -U rows: those a batch read drops."""
+    return (kind == int(RowKind.DELETE)) | (kind == int(RowKind.UPDATE_BEFORE))

@@ -13,11 +13,11 @@ from typing import Sequence
 
 import numpy as np
 
-from ..data.batch import Column, ColumnBatch, PartsTake, concat_batches
+from ..data.batch import BatchSink, Column, ColumnBatch, PartsTake, concat_batches
 from ..data.predicate import Predicate, PredicateBuilder, and_
 from ..metrics import read_metrics, span
 from .datafile import DataFileMeta, KeyValueFileReaderFactory
-from .kv import KVBatch
+from .kv import KVBatch, retracts
 from .levels import IntervalPartition
 from .mergefn import MergeExecutor
 
@@ -119,12 +119,16 @@ class MergeFileSplitRead:
         deletion_vectors: dict | None = None,
     ):
         """Phase 1 of the (possibly mesh-batched) merge-read: read the
-        section inputs and dispatch their merges; returns a zero-arg
-        continuation producing the final ColumnBatch. Under an active
+        section inputs and dispatch their merges; returns the continuation
+        producing the final ColumnBatch. Under an active
         mesh context (parallel/mesh_exec.py), the merges of every split
         dispatched in the same round execute in family-batched shard_maps
         over the mesh's bucket axis — the TPU equivalent of the reference
-        shipping one split per task (MergeTreeSplitGenerator.java:38)."""
+        shipping one split per task (MergeTreeSplitGenerator.java:38).
+
+        The continuation takes the BatchSink that its caller appends the
+        batch to next, if there is one: a split that can then writes its
+        winners where they belong (_complete); the batch is the same."""
         key_parts = []
         if predicate is not None:
             parts = PredicateBuilder.split_and(predicate)
@@ -137,38 +141,35 @@ class MergeFileSplitRead:
             sp.add(sections=len(sections))
             section_conts = [self._dispatch_section(section, predicate, key_filter, dvs) for section in sections]
 
-        def complete() -> ColumnBatch:
+        def complete(sink: BatchSink | None = None) -> ColumnBatch:
             with span("split", files=len(files), sections=len(sections)):
-                return self._complete(section_conts, predicate, projection, drop_delete)
+                return self._complete(section_conts, predicate, projection, drop_delete, sink)
 
         return complete
 
     def _dispatch_section(self, section, predicate, key_filter, dvs: dict):
         """Read one section's inputs and dispatch its merge; returns the
-        zero-arg continuation that gives the section's merged KVBatch."""
-        from ..parallel.mesh_exec import current_mesh_context
-
+        continuation that gives the section's merged KVBatch. It takes the
+        place _complete has for the rows, which only the keys-only
+        pipeline's gather can use."""
         if len(section) == 1:
             # single sorted run: keys are unique — no merge needed; full
             # predicate pushdown is safe (reference RawFileSplitRead)
             kv = self._read_files(section[0].files, predicate, dvs)
-            return lambda: kv
+            return lambda place=None: kv
         runs, seq_ascending = order_runs_for_merge(section)
         ordered_files = [f for run in runs for f in run.files]
         has_dv = any(f.file_name in dvs for f in ordered_files)
         if self.merge.supports_keys_only_pipeline() and not has_dv:
-            resolve = self._pipelined_dedup(ordered_files, key_filter, seq_ascending)
-            if current_mesh_context() is not None:
-                # the select is a job of the round's shard_map: the caller
-                # resolves once every split of the round has dispatched
-                return resolve
-            # single-device: the host decode has overlapped the device sort
-            kv = resolve()
-            return lambda: kv
+            # resolved by the caller: on a single device the host decode has
+            # overlapped the device sort; under a mesh context the select is
+            # a job of the round's shard_map, which runs once every split of
+            # the round has dispatched
+            return self._pipelined_dedup(ordered_files, key_filter, seq_ascending)
         # deletion vectors and the engines that merge whole batches
         kv = self._read_files(ordered_files, key_filter, dvs)
         handle = self.merge.merge_async(kv, seq_ascending=seq_ascending)
-        return lambda: self.merge.merge_resolve(handle)
+        return lambda place=None: self.merge.merge_resolve(handle)
 
     def _read_files(self, files, predicate, dvs: dict) -> KVBatch:
         """Whole files, concatenated in the order given: the per-file reads
@@ -179,12 +180,16 @@ class MergeFileSplitRead:
         with span("concat", rows=sum(p.num_rows for p in parts), columns=len(self.reader_factory.read_schema.fields)):
             return KVBatch.concat(parts)
 
-    def _complete(self, section_conts, predicate, projection, drop_delete: bool) -> ColumnBatch:
+    def _complete(self, section_conts, predicate, projection, drop_delete: bool, sink: BatchSink | None = None) -> ColumnBatch:
         """Phase 2: resolve every section's merge, then drop deletes, apply
-        the predicate and the projection, and concatenate the sections."""
+        the predicate and the projection, and concatenate the sections.
+        `sink`: the result the caller appends this batch to next. Several
+        sections are joined and a predicate filters; otherwise the gather's
+        rows stay where they are written, so it gets the sink as its place."""
+        place = sink if len(section_conts) == 1 and predicate is None else None
         out: list[ColumnBatch] = []
         for cont in section_conts:
-            kv = cont()
+            kv = cont(place)
             with span("finish", rows=kv.num_rows):
                 if drop_delete:
                     kv = kv.drop_deletes()
@@ -218,8 +223,9 @@ class MergeFileSplitRead:
     def _pipelined_dedup(self, ordered_files, key_filter, seq_ascending: bool):
         """Overlap host decode with the device merge: decode just the key
         columns, dispatch the dedup kernel (async), decode the value columns
-        while the device sorts; the zero-arg continuation returned resolves
-        the select and gathers the winners from the per-file value columns.
+        while the device sorts; the continuation returned resolves the
+        select and gathers the winners from the per-file value columns,
+        into `place` where it is given one (_gather_winners).
         The two decode passes share the predicate, so their row sets are
         identical (datafile.read contract)."""
         key_names = [n for n in self.reader_factory.read_schema.field_names if n in self.key_names]
@@ -242,7 +248,7 @@ class MergeFileSplitRead:
                 np.empty(0, dtype=np.int64),
                 np.empty(0, dtype=np.uint8),
             )
-            return lambda: empty
+            return lambda place=None: empty
         # file -> run offsets for key-range tiling (files of one run are
         # consecutive in ordered_files and key-sorted)
         run_offsets = [0]
@@ -259,14 +265,22 @@ class MergeFileSplitRead:
                     ordered_files,
                     parallelism=self.parallelism,
                 )
-        return lambda: self._gather_winners(kv_keys, tails, run_offsets, self.merge.dedup_resolve(handle))
+        return lambda place=None: self._gather_winners(kv_keys, tails, run_offsets, self.merge.dedup_resolve(handle), place)
 
-    def _gather_winners(self, kv_keys: KVBatch, tails: list[KVBatch], run_offsets: list[int], take: np.ndarray) -> KVBatch:
+    def _gather_winners(
+        self, kv_keys: KVBatch, tails: list[KVBatch], run_offsets: list[int], take: np.ndarray, place: BatchSink | None = None
+    ) -> KVBatch:
         """The winners of the keys-only pipeline, a column a task on the
         shared pool. A value column is taken straight from its per-file parts
         (Column.take_from_parts: the value pass is never concatenated); the
-        key columns, seq and kind, which the key pass joined for the lanes,
-        are taken whole. Same batch as the concatenation's take would give."""
+        key columns and seq, which the key pass joined for the lanes, are
+        taken whole. Same batch as the concatenation's take would give.
+
+        kind goes first, on this thread: where no winner is a -D or -U, the
+        read's finish drops no row, and a numpy-valued column of the schema
+        is written into the rows that `place` reserves for this split: its
+        values are then a view of the operation's result, which an append
+        leaves where they are."""
         schema = self.reader_factory.read_schema
         rows_out = len(take)
         with span("gather", rows_in=kv_keys.num_rows, rows_out=rows_out, columns=len(schema.fields), parts=len(run_offsets) - 1):
@@ -274,24 +288,36 @@ class MergeFileSplitRead:
             if tails:
                 with span("gather.plan", rows=rows_out, parts=len(tails)):
                     plan = PartsTake(run_offsets, take, lambda fn, items: _parallel_map(fn, items, self.parallelism))
-            # a task a column: a key column, seq or kind whole, or a value column's per-file parts
+            with span("gather.column", column="_kind", rows_out=rows_out, parts=1):
+                kind = kv_keys.kind.take(take)  # raises for a take beyond the key pass
+            dest = place.reserve(rows_out) if place is not None and not retracts(kind).any() else {}
+            # a task a column: a key column or seq whole, or a value column's per-file parts; only a column
+            # of the schema has a destination, whatever its name
             tasks = [
-                (n, kv_keys.data.column(n) if n in self.key_names else [t.data.column(n) for t in tails])
+                (n, kv_keys.data.column(n) if n in self.key_names else [t.data.column(n) for t in tails], dest.get(n))
                 for n in schema.field_names
             ]
-            tasks += [("_seq", kv_keys.seq), ("_kind", kv_keys.kind)]
+            tasks.append(("_seq", Column(kv_keys.seq), None))
 
             def gather_column(task):
-                name, col = task
+                name, col, out = task
                 whole = not isinstance(col, list)
                 with span("gather.column", column=name, rows_out=rows_out, parts=1 if whole else len(col)):
-                    return (col.take(take), False) if whole else Column.take_from_parts(col, plan)
+                    if not whole:
+                        return Column.take_from_parts(col, plan, out)
+                    plain = col._values is not None and col.dict_cache is None  # values and validity, nothing else to carry
+                    if out is None or not plain or col._values.dtype != out.dtype:
+                        return col.take(take), False
+                    # kind's take has checked the indices: numpy writes straight into `out` only
+                    # under a mode that cannot raise halfway
+                    values = np.take(col._values, take, out=out, mode="wrap")
+                    return Column(values, None if col.validity is None else col.validity.take(take)), False
 
             gathered = _parallel_map(gather_column, tasks, self.parallelism)
-            *cols, seq, kind = (col for col, _ in gathered)
-            read_metrics().counter("rows_gathered").inc(rows_out * len(tasks))
+            *cols, seq = (col for col, _ in gathered)
+            read_metrics().counter("rows_gathered").inc(rows_out * (len(tasks) + 1))
             read_metrics().counter("rows_gathered_from_parts").inc(rows_out * sum(from_parts for _, from_parts in gathered))
-        return KVBatch(ColumnBatch(schema, cols), seq, kind)
+        return KVBatch(ColumnBatch(schema, cols), seq.values, kind)
 
     def read_kv(
         self, files: list[DataFileMeta], drop_delete: bool = False, deletion_vectors: dict | None = None

@@ -444,6 +444,6 @@ class AppendOnlyFileStore(KeyValueFileStore):
 
     def read_bucket_dispatch(self, *args, **kwargs):
         """Append reads have no merge to batch: the continuation just wraps
-        the eager concat read."""
+        the eager concat read (and writes nothing into a caller's `sink`)."""
         out = self.read_bucket(*args, **kwargs)
-        return lambda: out
+        return lambda sink=None: out

@@ -35,7 +35,7 @@ import pyarrow  # noqa: F401
 
 from ..types import DataField, DataType, RowType, TypeRoot
 
-__all__ = ["Column", "ColumnBatch", "PartsTake", "concat_batches"]
+__all__ = ["BatchSink", "Column", "ColumnBatch", "PartsTake", "concat_batches"]
 
 
 class PartsTake:
@@ -314,10 +314,11 @@ class Column:
         return Column(values, validity)
 
     @staticmethod
-    def take_from_parts(parts: Sequence["Column"], plan: PartsTake) -> tuple["Column", bool]:
+    def take_from_parts(parts: Sequence["Column"], plan: PartsTake, out: np.ndarray | None = None) -> tuple["Column", bool]:
         """Column.concat(parts).take(plan.take), cell for cell, and whether
         no one concatenated the parts to make it. True: numpy-valued parts of
-        one dtype (one fresh output and, a pick of the plan at a time,
+        one dtype (one output, `out` where it is given and of that dtype and
+        else a fresh one, and, a pick of the plan at a time,
         `out[positions] = part.take(rows)`) and code-backed parts (the pools
         unified, the winners' codes re-mapped only). False: arrow-backed
         parts, which go to pyarrow's take over the chunks (it joins
@@ -331,8 +332,10 @@ class Column:
         if not all(c.validity is None for c in parts):
             validity = _scatter_parts([c.validity for c in parts], plan, np.ones(n, dtype=np.bool_))
         if all(c._values is not None for c in parts) and len({c._values.dtype for c in parts}) == 1:
-            values = _scatter_parts([c._values for c in parts], plan, np.empty(n, dtype=parts[0]._values.dtype))
-            return Column(values, validity), True
+            dtype = parts[0]._values.dtype
+            if out is None or out.dtype != dtype:
+                out = np.empty(n, dtype=dtype)
+            return Column(_scatter_parts([c._values for c in parts], plan, out), validity), True
         if all(c.is_code_backed for c in parts):
             from ..ops.dicts import remap_codes, unify_column_pools
 
@@ -720,3 +723,103 @@ def concat_batches(batches: Sequence[ColumnBatch]) -> ColumnBatch:
         n: Column.concat([b.columns[n] for b in batches]) for n in schema.field_names
     }
     return ColumnBatch(schema, cols)
+
+
+class BatchSink:
+    """concat_batches of the batches appended, cell for cell and backing for
+    backing, built once: the result of a read of several splits without the
+    whole-table copy at its end.
+
+    A column of a fixed-width type has one array of `capacity` rows, made
+    before the first batch arrives (np.empty: a page nobody writes to is
+    never faulted, and the result's columns are the views [:rows]). It fills
+    through two entries:
+
+      * reserve(rows): the arrays' next `rows` rows, name -> view, for a
+        producer that can write its rows where they belong (the keys-only
+        pipeline's gather, core/read.py). The cursor stays.
+      * append(batch): the next rows of the result. A column whose values
+        ARE the view reserved for it is in place already; any other
+        numpy-valued column of the array's dtype is copied in at the cursor.
+
+    A column that cannot be sized beforehand (arrow-backed, code-backed,
+    object-valued), or whose batches disagree with its array (another backing
+    or dtype), keeps its parts for one Column.concat in result(): what
+    concat_batches does to every column; with `capacity` None (the caller
+    has no bound of the order of its result) that is every column, and no
+    array is made. A validity array is made when the first batch brings one.
+    `placed` counts the cells (rows x columns) written through reserve,
+    `joined` those that append or result() copied.
+
+    reserve and append belong to one thread, in batch order; a producer may
+    fill the reserved views from any thread before its batch is appended."""
+
+    def __init__(self, schema: RowType, capacity: int | None):
+        self.schema = schema
+        self.capacity = capacity
+        self.rows = 0
+        dtypes = {} if capacity is None else {f.name: f.type.numpy_dtype() for f in schema.fields}
+        self._arrays = {n: np.empty(capacity, dtype=d) for n, d in dtypes.items() if d != np.dtype(object)}
+        self._validity: dict[str, np.ndarray] = {}
+        self._parts: dict[str, list[Column]] = {n: [] for n in schema.field_names if n not in self._arrays}
+        self._placed = dict.fromkeys(self._arrays, 0)
+        self._reserved: dict[str, np.ndarray] = {}
+
+    @property
+    def placed(self) -> int:
+        return sum(self._placed.values())
+
+    @property
+    def joined(self) -> int:
+        return self.rows * len(self.schema.fields) - self.placed
+
+    def reserve(self, rows: int) -> dict[str, np.ndarray]:
+        self._reserved = {n: a[self.rows : self.rows + rows] for n, a in self._arrays.items()}
+        return self._reserved
+
+    def append(self, batch: ColumnBatch) -> None:
+        start, stop = self.rows, self.rows + batch.num_rows
+        if self.capacity is not None and stop > self.capacity:
+            raise ValueError(f"row {stop} of a result sized for {self.capacity}")
+        if start == stop:  # concat_batches drops an empty batch too, whatever backs its columns
+            return
+        for name in self.schema.field_names:
+            col = batch.columns[name]
+            array = self._arrays.get(name)
+            if array is not None and (col._values is None or col._values.dtype != array.dtype):
+                # what is in the array becomes the column's first part
+                self._parts[name] = [self._view(name, start)] if start else []
+                del self._arrays[name], self._placed[name]
+                array = None
+            if array is None:
+                self._parts[name].append(col)
+                continue
+            if col._values is self._reserved.get(name):
+                self._placed[name] += stop - start
+            else:
+                array[start:stop] = col._values
+            valid = self._validity.get(name)
+            if valid is None and col.validity is not None:
+                valid = self._validity[name] = np.empty(self.capacity, dtype=np.bool_)
+                valid[:start] = True
+            if valid is not None:
+                valid[start:stop] = True if col.validity is None else col.validity
+        self.rows = stop
+        self._reserved = {}
+
+    def _view(self, name: str, rows: int) -> Column:
+        valid = self._validity.get(name)
+        return Column(self._arrays[name][:rows], None if valid is None else valid[:rows])
+
+    def result(self, map_fn=map) -> ColumnBatch:
+        """`map_fn(fn, names)` joins the columns kept as parts (the read path
+        gives its pool's map: a column a task)."""
+
+        def join(name: str) -> Column:
+            parts = self._parts[name]
+            if not parts:
+                return Column(np.empty(0, dtype=self.schema.field(name).type.numpy_dtype()))
+            return parts[0] if len(parts) == 1 else Column.concat(parts)
+
+        joined = dict(zip(self._parts, map_fn(join, list(self._parts))))
+        return ColumnBatch(self.schema, {n: joined[n] if n in joined else self._view(n, self.rows) for n in self.schema.field_names})

@@ -439,16 +439,21 @@ class TableRead:
         out = self.read(split)
         return out, np.full(out.num_rows, int(RowKind.INSERT), dtype=np.uint8)
 
-    def read(self, split: DataSplit):
+    def read(self, split: DataSplit, sink=None):
+        """One split's batch. `sink`: the BatchSink of the read_all that
+        appends this batch next (see _dispatch)."""
         if split.is_changelog:
             return self.read_with_kinds(split)[0]
-        out = self._dispatch(split)()
+        out = self._dispatch(split)(sink)
         if self.limit is not None and out.num_rows > self.limit:
             out = out.slice(0, self.limit)
         return out
 
     def _dispatch(self, split: DataSplit):
-        """Phase-1 read of one data split: returns a continuation."""
+        """Phase-1 read of one data split: returns a continuation. Called
+        with the BatchSink that its batch is appended to next, by the thread
+        that calls it, a split that can writes its winners into the sink's
+        rows and not into arrays of its own."""
         dvs = None
         if split.dv_index_file:
             from ..core.deletionvectors import DeletionVectorsIndexFile
@@ -465,7 +470,7 @@ class TableRead:
             deletion_vectors=dvs,
         )
 
-    def batches(self, splits: Sequence[DataSplit]):
+    def batches(self, splits: Sequence[DataSplit], sink=None):
         """Ordered generator of per-split batches (the ConcatRecordReader
         analog): each split's output is yielded as soon as its merge stage
         completes, in deterministic split order, instead of materializing
@@ -484,7 +489,13 @@ class TableRead:
           (parallel/pipeline.py contract);
         * sequential (scan.prefetch-splits = 0, a single split, or a limit:
           a limit wants early exit split by split — dispatching every split
-          up front would turn a point query into a full scan)."""
+          up front would turn a point query into a full scan).
+
+        `sink`: the BatchSink of a caller that appends each batch as it is
+        yielded (read_all). The mesh and the sequential mode resolve a split
+        on this thread in split order, so they hand it its sink (_dispatch); the
+        pipelined mode finishes splits out of order on its workers, and a
+        limit cuts a batch short: their batches are appended as they are."""
         from ..parallel.mesh_exec import maybe_mesh_exec
 
         splits = list(splits)
@@ -492,7 +503,7 @@ class TableRead:
         if remaining is None:
             with maybe_mesh_exec(self.table.store.options) as mex:
                 if mex is not None:
-                    yield from self._mesh_batches(mex, splits)
+                    yield from self._mesh_batches(mex, splits, sink)
                     return
             if len(splits) > 1:
                 depth, parallelism = self.table.store.pipeline_config()
@@ -503,7 +514,7 @@ class TableRead:
                     yield from pipe.map_ordered(splits, self.read)
                     return
         for s in splits:
-            b = self.read(s)
+            b = self.read(s, sink if remaining is None else None)
             if remaining is not None:
                 if remaining <= 0:
                     break
@@ -512,7 +523,7 @@ class TableRead:
                 remaining -= b.num_rows
             yield b
 
-    def _mesh_batches(self, mex, splits: Sequence[DataSplit]):
+    def _mesh_batches(self, mex, splits: Sequence[DataSplit], sink=None):
         """merge.engine = mesh scan: the PR 4 SplitPipeline is the host-side
         feeder with one prefetch lane per device, so the IO + decode of
         round i+1 overlap the batched device merges of round i. A round is
@@ -551,29 +562,47 @@ class TableRead:
                     with span("mesh.feed", histogram=wait, shards=lanes):
                         conts.append(next(it))
                 cont, conts[i] = conts[i], None
-                yield self.read(s) if cont is None else cont()
+                yield self.read(s) if cont is None else cont(sink)
         finally:
             it.close()
 
     def read_all(self, splits: Sequence[DataSplit]):
-        from ..data.batch import ColumnBatch, concat_batches
+        """Every split's rows in one batch. Several splits build it once, in
+        a BatchSink sized by the plan: a split that can writes its winners
+        into their rows of it (batches), any other batch is copied in as it
+        arrives, and what could not be sized beforehand is joined at the end,
+        a column a task on the shared pool. Under a predicate or a limit the
+        plan's rows bound nothing the result comes near: no array is sized,
+        and the join at the end is of every column."""
+        from ..data.batch import BatchSink, ColumnBatch
         from ..metrics import read_metrics, span
+        from ..parallel.pipeline import bounded_map
 
         splits = list(splits)
         rows_in = sum(s.row_count for s in splits)
+        schema = self.table.row_type if self.projection is None else self.table.row_type.project(self.projection)
+        placed = joined = 0
         # one operation: every span below, on this thread or a pool's,
         # carries the id allotted here
         with span("read_all", new_op=True, splits=len(splits), rows_in=rows_in) as sp:
-            batches = list(self.batches(splits))
-            if not batches:
-                schema = self.table.row_type if self.projection is None else self.table.row_type.project(self.projection)
-                out = ColumnBatch.empty(schema)
+            if len(splits) > 1:
+                with span("concat", rows=0, columns=len(schema.fields)):  # the result's arrays, mapped
+                    sink = BatchSink(schema, rows_in if self.predicate is None and self.limit is None else None)
+                for b in self.batches(splits, sink):
+                    with span("concat", rows=b.num_rows, columns=len(schema.fields)):
+                        sink.append(b)
+                parallelism = self.table.store.pipeline_config()[1]
+                with span("concat", rows=sink.rows, columns=len(schema.fields)):
+                    out = sink.result(lambda fn, names: bounded_map(fn, names, parallelism))
+                placed, joined = sink.placed, sink.joined
             else:
-                with span("concat", rows=sum(b.num_rows for b in batches), columns=len(batches[0].schema.fields)):
-                    out = concat_batches(batches)
+                only = list(self.batches(splits))  # no split, or the one split's batch as it is
+                out = only[0] if only else ColumnBatch.empty(schema)
             sp.add(rows_out=out.num_rows)
         g = read_metrics()
         g.counter("ops").inc()
         g.counter("rows_in").inc(rows_in)
         g.counter("rows_out").inc(out.num_rows)
+        g.counter("rows_placed").inc(placed)
+        g.counter("rows_joined").inc(joined)
         return out

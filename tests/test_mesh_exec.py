@@ -556,3 +556,42 @@ def test_mesh_partial_update_sequence_groups(tmp_warehouse, rng):
         _write(par, data)
         _write(ser, data)
     assert _canon(par) == _canon(ser)
+
+
+def test_mesh_read_all_writes_the_winners_into_its_result(tmp_warehouse, rng):
+    """An 8-bucket table of the benchmark's 15 columns under merge.engine=mesh:
+    every split's continuation runs on the reading thread in split order, so
+    the gathers write the 11 fixed-width columns straight into read_all's one
+    result and only the 4 STRING columns are joined; same rows as the
+    single-device read, which finishes its splits on the pipeline's workers
+    and copies every batch in."""
+    names = ["id"] + [f"b{i}" for i in range(6)] + [f"d{i}" for i in range(4)] + [f"s{i}" for i in range(4)]
+    schema = pt.RowType.of(*[(n, pt.BIGINT(False) if n == "id" else pt.STRING() if n[0] == "s" else pt.DOUBLE() if n[0] == "d" else pt.BIGINT())
+                             for n in names])
+    cat = FileSystemCatalog(tmp_warehouse, commit_user="mesh-sink")
+    opts = {"bucket": "8", "write-only": "true"}
+    mesh_t = cat.create_table("db.sink_mesh", schema, primary_keys=["id"], options={**opts, "merge.engine": "mesh"})
+    single_t = cat.create_table("db.sink_single", schema, primary_keys=["id"], options=opts)
+    for r in range(3):  # overlapping runs, so every bucket has to merge
+        ids = rng.choice(4_000, 2_500, replace=False).astype(np.int64)
+        data = {n: ids if n == "id" else np.array([f"{n}-{i % 13}-{r}" for i in ids], dtype=object) if n[0] == "s"
+                else ids / 3 + r if n[0] == "d" else ids * 7 + r for n in names}
+        _write(mesh_t, data)
+        _write(single_t, data)
+
+    def read(t):
+        rb = t.new_read_builder()
+        splits = rb.new_scan().plan()
+        assert len(splits) == 8
+        before = dict(registry.snapshot().get("read", {}))
+        out = rb.new_read().read_all(splits)
+        return out, {k: v - before.get(k, 0) for k, v in registry.snapshot()["read"].items()}
+
+    got, counted = read(mesh_t)
+    want, counted_single = read(single_t)
+    assert got.to_pylist() == want.to_pylist() and got.num_rows > 2_500
+    for n in names:
+        assert got.column(n).null_count == 0 and (got.column(n)._values is None) == (n[0] == "s")
+    assert (counted_single["rows_placed"], counted_single["rows_joined"]) == (0, 15 * want.num_rows)
+    if not MESH_FORCED_OFF:
+        assert (counted["rows_placed"], counted["rows_joined"]) == (11 * got.num_rows, 4 * got.num_rows)

@@ -288,7 +288,8 @@ def test_a_mesh_read_takes_the_keys_only_pipeline(traced_mesh_read, axis):
     assert sum(e[4]["rows_out"] for e in by_name["gather"]) == KEYS
     cols = by_name["gather.column"]
     assert sorted(e[4]["column"] for e in cols) == sorted(["id", "v", "d", "s", "_seq", "_kind"] * BUCKETS)
-    assert all(e[3] != reader and e[4]["op"] == op and e[4]["parent"] == "gather" for e in cols)
+    # kind first and on the reading thread: it says whether the winners may be written into read_all's result
+    assert all((e[3] == reader) == (e[4]["column"] == "_kind") and e[4]["op"] == op and e[4]["parent"] == "gather" for e in cols)
     assert all(e[4]["parts"] == (RUNS if e[4]["column"] in ("v", "d", "s") else 1) for e in cols)
 
 

@@ -140,9 +140,22 @@ def test_spans_nest_as_documented(reads, name, parent):
     assert list(first) == sorted(first, key=first.get)
 
 
-def test_concat_is_opened_under_split_and_read_all(reads):
-    assert {e[4]["parent"] for e in _named(reads["cold"], "concat")} == {"split", "read_all"}
+def test_concat_is_opened_under_split(reads):
+    # one split: read_all returns its batch as it is, and joins nothing
+    assert {e[4]["parent"] for e in _named(reads["cold"], "concat")} == {"split"}
     assert "parent" not in _named(reads["cold"], "read_all")[0][4]  # nothing caused it
+
+
+def test_a_read_of_several_splits_opens_concat_under_read_all(tmp_path):
+    table = _table(tmp_path / "warehouse", bucket="3")
+    with traced(tmp_path / "trace") as events:
+        out = _read(table)
+    (read_all,) = _named(events, "read_all")
+    joins = [e for e in _named(events, "concat") if e[4]["parent"] == "read_all"]
+    # the result's arrays, a split's batch as it arrives, then what could not be sized beforehand (s), once
+    assert [e[4]["rows"] for e in joins][0] == 0
+    assert [e[4]["rows"] for e in joins][-1] == sum(e[4]["rows"] for e in joins[:-1]) == out.num_rows
+    assert len(joins) == read_all[4]["splits"] + 2 == 5 and all(_inside(e, read_all) for e in joins)
 
 
 def test_a_file_decoded_on_a_pool_thread_names_its_operation_and_who_asked(reads):
@@ -172,8 +185,8 @@ def test_a_column_gathered_on_a_pool_thread_names_its_operation_and_the_gather(r
         assert c[4]["op"] == read_all[4]["op"] and c[4]["parent"] == "gather"
         assert c[4]["rows_out"] == gather[4]["rows_out"] == read_all[4]["rows_out"]
         assert gather[1] <= c[1] and c[2] <= gather[2]  # inside the reader's gather, on whichever line
-    # the value pass (2 columns) is not concatenated: what is left joins the key pass, the sections and the splits
-    assert sorted(c[4]["columns"] for c in _named(reads["cold"], "concat")) == [1, 3, 3]
+    # the value pass (2 columns) is not concatenated: what is left joins the key pass and the sections
+    assert sorted(c[4]["columns"] for c in _named(reads["cold"], "concat")) == [1, 3]
 
 
 def test_a_cache_hit_opens_no_decode_file(reads):
@@ -335,8 +348,10 @@ def test_a_read_counts_rows_and_decodes(tmp_path):
     snap = registry.snapshot()
     # 3 columns, seq and kind gathered; from the per-file parts only v, the numeric value column:
     # pyarrow joins the chunks of the arrow-backed s inside its take, so s is no column "from parts"
+    # one split: nothing is written into a result of several, nothing is joined
     assert snap["read"] == {"ops": 1, "rows_in": RUNS * ROWS_A_RUN, "rows_out": out.num_rows,
-                            "rows_gathered": 5 * out.num_rows, "rows_gathered_from_parts": out.num_rows}
+                            "rows_gathered": 5 * out.num_rows, "rows_gathered_from_parts": out.num_rows,
+                            "rows_placed": 0, "rows_joined": 0}
     assert snap["datafile"]["files_decoded"] == 2 * RUNS and snap["datafile"]["rows_decoded"] == 2 * RUNS * ROWS_A_RUN
     assert snap["datafile"]["bytes_decoded"] > 0
     assert snap["merge"]["merges"] == 1 and snap["merge"]["winners"] == out.num_rows
