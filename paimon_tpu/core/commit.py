@@ -18,6 +18,7 @@ from dataclasses import dataclass, replace
 from typing import Callable, Sequence
 
 from ..fs import FileIO
+from ..metrics import registry, span
 from ..options import CoreOptions
 from ..resilience.faults import crash_point
 from ..utils import dumps, loads, new_file_name, now_millis
@@ -146,6 +147,14 @@ class FileStoreCommit:
     # ---- commit ---------------------------------------------------------
     def commit(self, committable: ManifestCommittable) -> list[int]:
         """Returns the snapshot ids written (0, 1, or 2)."""
+        # an operation of its own where none is open: the committer is another
+        # actor than the writer (a sink's committer operator)
+        with span("commit", new_op=span.current() is None) as sp:
+            written = self._commit(committable)
+            sp.add(snapshots=len(written))
+        return written
+
+    def _commit(self, committable: ManifestCommittable) -> list[int]:
         append_entries: list[ManifestEntry] = []
         compact_entries: list[ManifestEntry] = []
         append_changelog: list[ManifestEntry] = []
@@ -290,8 +299,6 @@ class FileStoreCommit:
         import random
         import time
 
-        from ..metrics import registry
-
         g = registry.group("commit")
         opts = self.options.options
         max_retries = opts.get(CoreOptions.COMMIT_MAX_RETRIES)
@@ -383,6 +390,9 @@ class FileStoreCommit:
                     if self.file_io.try_atomic_write(path, snapshot.to_json().encode()):
                         g.counter("commits").inc()
                         g.counter("retries").inc(retries)
+                        sp = span.current()
+                        if sp is not None and sp.name == "commit":
+                            sp.add(entries=len(entries), retries=retries)
                         g.histogram("duration_ms").update((time.perf_counter() - t_start) * 1000)
                         # committed: the snapshot now references these manifests —
                         # they must never be cleaned up, even if hints fail

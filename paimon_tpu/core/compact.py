@@ -16,6 +16,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Sequence
 
+from ..metrics import compaction_metrics, span
 from ..options import CoreOptions
 from ..utils import now_millis
 from .datafile import DataFileMeta, KeyValueFileReaderFactory, KeyValueFileWriterFactory
@@ -331,13 +332,13 @@ class MergeTreeCompactManager:
         return self.levels.number_of_sorted_runs() > self.options.num_sorted_runs_stop_trigger
 
     def trigger_compaction(self, full: bool = False) -> CompactResult | None:
-        from ..metrics import registry, timed
         from ..parallel.mesh_exec import current_mesh_context, maybe_mesh_exec
         from ..parallel.pipeline import pipeline_config
 
         depth, parallelism = pipeline_config(self.options)
-        g = registry.group("compaction")
-        with timed(g.histogram("duration_ms")):
+        g = compaction_metrics()
+        g.counter("rounds").inc()
+        with span("compact", histogram=g.histogram("duration_ms"), full=int(full)):
             # merge.engine = mesh and no context installed yet (standalone
             # compaction, not under a table-write batch window): install the
             # MeshExecutor so this bucket's section merges run as batched
@@ -362,13 +363,19 @@ class MergeTreeCompactManager:
         """Pick the unit and classify upgrade-vs-rewrite (reference
         MergeTreeCompactTask.doCompact) WITHOUT reading any input. Returns
         (unit, drop_delete, result, rewrite_sections) or None."""
-        runs = self.levels.level_sorted_runs()
-        if full:
-            unit = self.strategy.force_full(self.levels.num_levels, runs)
-        else:
-            unit = self.strategy.pick(self.levels.num_levels, runs)
-        if unit is None or not unit.files:
-            return None
+        with span("compact.pick") as sp:
+            runs = self.levels.level_sorted_runs()
+            if full:
+                unit = self.strategy.force_full(self.levels.num_levels, runs)
+            else:
+                unit = self.strategy.pick(self.levels.num_levels, runs)
+            sp.add(runs=len(runs), files=len(unit.files) if unit is not None else 0)
+            if unit is None or not unit.files:
+                return None
+            return self._classify(unit)
+
+    def _classify(self, unit: CompactUnit):
+        """Upgrade or rewrite, section by section, for a picked unit."""
         # drop deletes iff the output is the highest non-empty level's floor
         # (reference MergeTreeCompactManager.triggerCompaction :148-158)
         drop_delete = unit.output_level != 0 and unit.output_level >= self.levels.non_empty_highest_level()
@@ -446,6 +453,7 @@ class MergeTreeCompactManager:
         invalidate dead cache entries, update Levels."""
         if rewrite_sections:
             flat_before = [f for sec in rewrite_sections for r in sec for f in r.files]
+            self._count_rewrite(unit, rewrite_sections, flat_before, after)
             result.before.extend(flat_before)
             result.after.extend(after)
             result.changelog.extend(changelog)
@@ -460,6 +468,21 @@ class MergeTreeCompactManager:
         if not result.is_empty():
             self.levels.update(result.before, result.after)
         return result
+
+    @staticmethod
+    def _count_rewrite(unit, rewrite_sections, before, after) -> None:
+        """compaction{...} and the open `compact` span for one rewrite: what
+        it read and what it wrote (an upgrade writes nothing)."""
+        rows_in, rows_out = sum(f.row_count for f in before), sum(f.row_count for f in after)
+        g = compaction_metrics()
+        g.counter("rows_in").inc(rows_in)
+        g.counter("rows_out").inc(rows_out)
+        g.counter("files_out").inc(len(after))
+        g.counter("bytes_out").inc(sum(f.file_size for f in after))
+        sp = span.current()
+        if sp is not None and sp.name == "compact":
+            sp.add(runs_in=sum(len(sec) for sec in rewrite_sections), rows_in=rows_in, rows_out=rows_out,
+                   level_out=unit.output_level)
 
     @staticmethod
     def _can_upgrade(f: DataFileMeta, output_level: int, drop_delete: bool, min_size: int) -> bool:

@@ -21,6 +21,7 @@ from ..data.casting import cast_column
 from ..data.predicate import FieldStats, Predicate
 from ..format import collect_stats, get_format, stats_from_json, stats_to_json
 from ..fs import FileIO
+from ..metrics import span
 from ..types import DataField, RowKind, RowType
 from ..utils import new_file_name, now_millis
 from .kv import SEQUENCE_FIELD_NAME, VALUE_KIND_FIELD_NAME, KVBatch, kv_disk_schema
@@ -191,12 +192,13 @@ class KeyValueFileWriterFactory:
         row_bytes = measured_row_bytes or self._estimate_row_bytes(kv.data)
         rows_per_file = max(1, int(self.target_file_size / max(row_bytes, 1)))
         out: list[DataFileMeta] = []
+        format_id = self.per_level_format.get(level, self.format_id)
         for start in range(0, n, rows_per_file):
-            out.append(
-                self._write_one(
-                    kv.slice(start, min(start + rows_per_file, n)), level, file_source, prefix, sorted_input
-                )
-            )
+            part = kv.slice(start, min(start + rows_per_file, n))
+            with span("file.write", level=level, format=format_id, rows=part.num_rows) as sp:
+                meta = self._write_one(part, level, file_source, prefix, sorted_input)
+                sp.add(bytes=meta.file_size)
+            out.append(meta)
         return out
 
     def _key_min_max(self, batch: ColumnBatch, sorted_input: bool) -> tuple[tuple, tuple]:

@@ -15,6 +15,7 @@ from __future__ import annotations
 import numpy as np
 
 from ..data.batch import ColumnBatch
+from ..metrics import carried, flush_metrics, span
 from ..options import CoreOptions
 from ..types import RowKind
 from .compact import CompactResult, MergeTreeCompactManager
@@ -221,22 +222,27 @@ class MergeTreeWriter:
                     self.admission.flush_end()
                 busy.update((_time.perf_counter() - t0) * 1000)
 
-        self._flush_pending.append(self._flush_pool.submit(run))
+        # carried: the worker's flush, file.write and compact spans name the
+        # operation and the span (prepare_commit, write) that handed them over
+        self._flush_pending.append(self._flush_pool.submit(carried(run)))
 
     def _drain_flushes(self) -> None:
         """Wait for offloaded flushes; the FIRST failure re-raises after the
         rest were cancelled/awaited (a failed flush must not silently let a
         later one keep mutating levels)."""
         pending, self._flush_pending = self._flush_pending, []
+        if not pending:
+            return
         error = None
-        for f in pending:
-            if error is not None:
-                f.cancel()
-                continue
-            try:
-                f.result()
-            except BaseException as exc:  # noqa: BLE001 — re-raised below
-                error = exc
+        with span("flush.wait", flushes=len(pending)):
+            for f in pending:
+                if error is not None:
+                    f.cancel()
+                    continue
+                try:
+                    f.result()
+                except BaseException as exc:  # noqa: BLE001 — re-raised below
+                    error = exc
         if error is not None:
             self._shutdown_flush_pool()
             raise error
@@ -288,6 +294,10 @@ class MergeTreeWriter:
         self._drain_flushes()
         if not self._buffer:
             return None
+        with span("flush", rows_in=self._buffered_rows):
+            return self._flush_dispatch()
+
+    def _flush_dispatch(self):
         gate = self.debt_gate() if self.debt_gate is not None else None
         if gate is not None:
             # block (bounded) while this bucket's projected sorted-run count
@@ -304,7 +314,12 @@ class MergeTreeWriter:
         # memtable full, nothing drained: a kill here loses only rows no
         # commit ever acknowledged
         crash_point("flush:before-dispatch")
-        kv = KVBatch.concat(self._buffer) if len(self._buffer) > 1 else self._buffer[0]
+        if len(self._buffer) > 1:
+            with span("concat", rows=self._buffered_rows, columns=len(self._buffer[0].data.schema.fields)):
+                kv = KVBatch.concat(self._buffer)
+        else:
+            kv = self._buffer[0]
+        flush_metrics().counter("rows_in").inc(kv.num_rows)
         drained_bytes = self._buffered_bytes
         self._inflight_delta.append(kv)  # visible to delta_snapshot until the L0 files land
         self._buffer.clear()
@@ -337,7 +352,19 @@ class MergeTreeWriter:
         handle, buffer_seq_ordered, drained_bytes, gate, kv = state
         landed = False
         try:
-            self._flush_complete_inner(handle, buffer_seq_ordered)
+            with span("flush") as sp:
+                files = self._flush_complete_inner(handle, buffer_seq_ordered)
+                rows_out, nbytes = sum(f.row_count for f in files), sum(f.file_size for f in files)
+                sp.add(rows_out=rows_out, files=len(files))
+            g = flush_metrics()
+            g.counter("rows_out").inc(rows_out)
+            g.counter("files").inc(len(files))
+            g.counter("bytes").inc(nbytes)
+            if self.compact_manager is not None and not self.options.write_only:
+                # a round of compaction is a span of its own (compact), after the flush's
+                for f in files:
+                    self.compact_manager.levels.level0.insert(0, f)
+                self._maybe_compact()
             landed = True
         finally:
             self._acct_release(drained_bytes)
@@ -350,7 +377,9 @@ class MergeTreeWriter:
             if gate is not None:
                 gate.settle([(self.partition, self.bucket)], landed=landed)
 
-    def _flush_complete_inner(self, handle, buffer_seq_ordered) -> None:
+    def _flush_complete_inner(self, handle, buffer_seq_ordered) -> list[DataFileMeta]:
+        """The merge resolved and the level-0 files (and changelog) written;
+        returns the level-0 files."""
         merged = self.merge.merge_resolve(handle)
         from ..options import ChangelogProducer
 
@@ -382,10 +411,7 @@ class MergeTreeWriter:
         # here strews orphan data files for remove_orphan_files to reclaim
         crash_point("flush:files-written")
         self._new_files.extend(files)
-        if self.compact_manager is not None and not self.options.write_only:
-            for f in files:
-                self.compact_manager.levels.level0.insert(0, f)
-            self._maybe_compact()
+        return files
 
     def _lookup_changelog(self, merged: KVBatch, buffer_seq_ordered: bool = True) -> KVBatch:
         """Diff the bucket's visible state before vs after this flush,

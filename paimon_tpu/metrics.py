@@ -32,6 +32,7 @@ __all__ = [
     "decode_metrics",
     "dict_metrics",
     "encode_metrics",
+    "flush_metrics",
     "gateway_metrics",
     "get_metrics",
     "io_metrics",
@@ -45,6 +46,7 @@ __all__ = [
     "soak_metrics",
     "sql_metrics",
     "sub_metrics",
+    "write_metrics",
 ]
 
 
@@ -343,10 +345,31 @@ def pallas_metrics() -> MetricGroup:
     return registry.group("pallas")
 
 
+def write_metrics() -> MetricGroup:
+    """The write{...} group (TableWrite, paimon_tpu.table.write). Canonical
+    members, counters: rows (rows handed to TableWrite.write), commits
+    (prepare_commit calls). Resolved per call so registry.reset() in tests
+    swaps the group out."""
+    return registry.group("write")
+
+
+def flush_metrics() -> MetricGroup:
+    """The flush{...} group (MergeTreeWriter, paimon_tpu.core.writer: one
+    memtable through the merge into level-0 files). Canonical members,
+    counters: rows_in (memtable rows drained), rows_out (rows of the level-0
+    files landed: one a key), files, bytes (those files' sizes). Resolved per
+    call so registry.reset() in tests swaps the group out."""
+    return registry.group("flush")
+
+
 def compaction_metrics() -> MetricGroup:
     """The compaction{...} group (LSM compaction execution, core.compact,
     plus the adaptive scheduler, table.compactor.AdaptiveCompactorService).
-    Canonical members — counters: compactions, files_rewritten (execution
+    Canonical members — counters: rounds (trigger_compaction calls, whether
+    or not the strategy picked a unit), rows_in / rows_out / files_out /
+    bytes_out (rows of the files a rewrite read, and rows, count and sizes
+    of the files it wrote: an upgrade moves a file between levels and counts
+    in none of them), compactions, files_rewritten (execution
     side, incremented per committed rewrite), adaptive_runs (buckets the
     adaptive scheduler compacted), deferred_buckets (buckets with pending
     sorted runs the policy deliberately left for later — cold or below
@@ -532,16 +555,18 @@ class span:
     given. `add` sums numbers known only later (rows decoded, tiles, bytes)
     onto the span; code deeper in the call reaches the innermost open span
     of its thread through `span.current()`. `new_op=True` allots the next
-    operation id (TableRead.read_all does). `histogram=` also records the
-    span's wall milliseconds there, traced or not: the one clock read for a
-    timing on the read path."""
+    operation id (TableRead.read_all does; TableWrite at the first write of a
+    checkpoint); `op=<id>` continues an operation that an earlier call began
+    (TableWrite.prepare_commit takes up the id its writes were given).
+    `histogram=` also records the span's wall milliseconds there, traced or
+    not: the one clock read for a timing on the read path."""
 
     __slots__ = ("name", "op", "_note", "_token", "_sums", "_histogram", "_t0")
 
-    def __init__(self, name: str, histogram: Histogram | None = None, new_op: bool = False, **stats):
-        op, parent, _ = _CURRENT.get()
+    def __init__(self, name: str, histogram: Histogram | None = None, new_op: bool = False, op: int = 0, **stats):
+        current, parent, _ = _CURRENT.get()
         self.name = name
-        self.op = next(_OP_IDS) if new_op else op
+        self.op = next(_OP_IDS) if new_op else (op or current)
         self._sums: dict | None = None
         self._histogram = histogram
         self._note = TraceAnnotation(SPAN_PREFIX + name, op=self.op, parent=parent, **stats)

@@ -100,6 +100,30 @@ def _count_download(nbytes: int, winners: int) -> None:
         sp.add(d2h_bytes=nbytes, winners=winners)
 
 
+# A device array of at most this many elements comes down whole and is cut on
+# the host; a longer one is first cut on the device to one of _FETCH_STEPS
+# lengths. Either way the XLA programs a download asks for do not follow the
+# winner count: `x[:c]` on a device array is a new program for every c.
+_FETCH_WHOLE_ELEMS = 1 << 20
+_FETCH_STEPS = 8
+
+
+def _fetch(arr, length: int, row: int | None = None) -> tuple[np.ndarray, int]:
+    """(arr[..., :length] on the host, bytes that crossed the link); with
+    `row`, of that row of a 2-D array alone, row and cut in one program."""
+    m = arr.shape[-1]
+    cut = m
+    if (m if row is not None else arr.size) > _FETCH_WHOLE_ELEMS:
+        step = max(1, m // _FETCH_STEPS)
+        cut = min(m, -(-length // step) * step)
+    if row is not None:
+        arr = arr[row, :cut]
+    elif cut < m:
+        arr = arr[..., :cut]
+    host = np.asarray(arr)
+    return host[..., :length], host.nbytes
+
+
 def sorted_segments(
     num_key_lanes: int, num_seq_lanes: int, key_lanes, seq_lanes, pad_flag, extra_keys=(), engine: str = "xla"
 ):
@@ -201,9 +225,10 @@ def _bitpack_rows(vals, rbits: int):
 
 
 def _unpack_runids(packed: np.ndarray, c: int, rbits: int) -> np.ndarray:
-    """Host: first c rbits-wide values from a _bitpack_rows byte stream."""
+    """Host: first c rbits-wide values from a _bitpack_rows byte stream
+    (already on the host, at least ceil(c * rbits / 8) bytes of it)."""
     per = 8 // rbits
-    pk = np.asarray(packed[: (c + per - 1) // per])
+    pk = packed[: (c + per - 1) // per]
     if rbits == 8:
         return pk[:c]
     lanes = [(pk >> (i * rbits)) & ((1 << rbits) - 1) for i in range(per)]
@@ -246,30 +271,24 @@ def pack_selection_compact(sel, perm, starts):
         return mask_bytes, byte, sel.sum()
 
 
-def unpack_selection_compact(mask_bytes, runs_packed, count, n: int, num_runs: int, rbits: int) -> np.ndarray:
+def unpack_selection_compact(mask_bytes, runs_packed, count, n: int, num_runs: int, rbits: int):
     """Host half of pack_selection_compact: (bit mask, packed run-ids, count)
-    -> selected input-row indices in global key order. Downloads only
-    ceil(n/8) + ceil(c*rbits/8) bytes off the device. rbits comes from the
-    dispatch handle (single source: _runid_bits over the padded starts the
-    kernel actually saw)."""
+    -> (selected input-row indices in global key order, bytes downloaded):
+    the mask's first ceil(n/8) bytes and, with more than one run, the
+    bit-packed run-ids of the winners, each through _fetch; nothing where
+    nothing was selected. rbits comes from the dispatch handle (single
+    source: _runid_bits over the padded starts the kernel actually saw)."""
     c = int(count)
     if c == 0:
-        return np.empty(0, dtype=np.int32)
-    keep = np.unpackbits(np.asarray(mask_bytes[: (n + 7) // 8]), count=n).astype(bool)
+        return np.empty(0, dtype=np.int32), 0
+    mask, nbytes = _fetch(mask_bytes, (n + 7) // 8)
+    keep = np.unpackbits(mask, count=n).astype(bool)
     winners = np.flatnonzero(keep).astype(np.int32)  # grouped by run, ascending
     if num_runs <= 1:
-        return winners
-    return _interleave_winners(winners, _unpack_runids(runs_packed, c, rbits))
-
-
-def _compact_selection_nbytes(c: int, n: int, num_runs: int, rbits: int) -> int:
-    """Bytes unpack_selection_compact fetches for c winners among n rows:
-    the mask's first ceil(n/8) bytes and, with more than one run, the
-    bit-packed run-ids of the winners; nothing where nothing was selected."""
-    if c == 0:
-        return 0
+        return winners, nbytes
     per = 8 // rbits
-    return (n + 7) // 8 + ((c + per - 1) // per if num_runs > 1 else 0)
+    runs, runs_nbytes = _fetch(runs_packed, (c + per - 1) // per)
+    return _interleave_winners(winners, _unpack_runids(runs, c, rbits)), nbytes + runs_nbytes
 
 
 def narrow_lane(col: np.ndarray) -> np.ndarray:
@@ -577,7 +596,79 @@ def deduplicate_select_async(
     all-constant key short-circuits to the scalar winner without any device
     dispatch."""
     with span("merge.dispatch", rows=len(key_lanes)):
+        if len(key_lanes) > _STREAM_TILE_ROWS:
+            handle = _stream_dispatch(key_lanes, seq_lanes, backend, compress)
+            if handle is not None:
+                return handle
         return _select_async(key_lanes, seq_lanes, backend, compress, merges=1)
+
+
+# A merge of more rows than this runs as key-range tiles of this one padded
+# shape, a call a tile: the writers' merges (a flush, a compaction round) grow
+# with the table, and a sort program per power of two that a cascade reaches
+# costs seconds to a minute each, met at any time in a table's life. One
+# shape is one program, whatever the merge's size; it is also the pad bucket
+# of a memtable of 65,537 to 131,072 rows, so such a writer's flushes and its
+# compaction rounds share it.
+_STREAM_TILE_ROWS = 1 << 17
+
+
+def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None):
+    """Key-range tiles of an input in ANY row order, all of the padded shape
+    (_STREAM_TILE_ROWS,) and of u32 lanes, so every tile of every merge with
+    the same lane arity is the same program. Tiles cut the key space on the
+    most significant packed lane, so every duplicate of a key lands in one
+    tile; rows keep their input order inside a tile, so stability carries
+    the tie-break as it does for the whole. Returns ("stream", [(handle,
+    input rows of the tile)]) in ascending key-range order, or None where
+    the keys cannot be cut that finely (one lane-0 value holds more rows
+    than a tile): the caller then sorts the whole at its own pad bucket."""
+    from .lanes import compress_key_lanes, resolve_compress
+
+    n = key_lanes.shape[0]
+    if resolve_compress(compress):
+        lanes, plan = compress_key_lanes(np.ascontiguousarray(key_lanes), True)
+    else:
+        lanes, plan = drop_constant_lanes(np.ascontiguousarray(key_lanes)), None
+    if lanes.shape[1] == 0:
+        return None  # all keys equal: the scalar path, no device trip
+    seqs = drop_constant_lanes(np.ascontiguousarray(seq_lanes)) if seq_lanes is not None else None
+    lane0 = lanes[:, 0]
+    num_tiles = -(-n * 5 // (_STREAM_TILE_ROWS * 4))  # aim at tiles four fifths full
+    sample = np.sort(lane0[:: max(1, n // 65536)])
+    for _ in range(3):
+        cuts = np.unique(sample[np.linspace(0, len(sample) - 1, num_tiles + 1).astype(np.int64)[1:-1]])
+        tile_of = np.searchsorted(cuts, lane0, side="right").astype(np.uint16)
+        sizes = np.bincount(tile_of, minlength=len(cuts) + 1)
+        if sizes.max() <= _STREAM_TILE_ROWS:
+            break
+        num_tiles *= 2
+    else:
+        return None
+    order = np.argsort(tile_of, kind="stable").astype(np.int32)  # radix: O(n)
+    use_ovc = plan is not None and plan.use_ovc
+    k, s, m = lanes.shape[1], 0 if seqs is None else seqs.shape[1], _STREAM_TILE_ROWS
+    fn = _dedup_select_fn(k, s, backend, plan.ovc_vbits if use_ovc else 0)
+    base = (np.asarray(plan.base, dtype=np.uint32),) if use_ovc else ()
+    merge_metrics().counter("merges").inc()
+    handles, at = [], 0
+    for size in sizes.tolist():
+        if not size:
+            continue
+        rows = order[at : at + size]
+        at += size
+        klp = [pad_to(lanes[rows, i], m, 0xFFFFFFFF) for i in range(k)]
+        slp = [pad_to(seqs[rows, i], m) for i in range(s)]
+        pad = np.zeros(m, dtype=np.uint8)
+        pad[size:] = 1
+        if backend == "pallas":
+            from .pallas_kernels import note_dispatch
+
+            note_dispatch(m)
+        operands = (klp, slp, pad) + base
+        _count_kernel(operands, size, m)
+        handles.append((fn(*operands), rows))  # async: the next tile assembles while this sorts
+    return ("stream", handles)
 
 
 def _select_async(key_lanes, seq_lanes, backend: str, compress: bool | None, merges: int = 0):
@@ -848,12 +939,17 @@ def _resolve(handle) -> np.ndarray:
     if isinstance(handle, tuple) and handle[0] == "compact":
         _, (mask_bytes, runs_packed, count), n, num_runs, rbits = handle
         c = int(count)
-        _count_download(count.nbytes + _compact_selection_nbytes(c, n, num_runs, rbits), c)
-        return unpack_selection_compact(mask_bytes, runs_packed, c, n, num_runs, rbits)
+        take, nbytes = unpack_selection_compact(mask_bytes, runs_packed, c, n, num_runs, rbits)
+        _count_download(count.nbytes + nbytes, c)
+        return take
+    if isinstance(handle, tuple) and handle[0] == "stream":
+        out = [rows[_resolve(tile)] for tile, rows in handle[1]]
+        return np.concatenate(out) if out else np.empty(0, dtype=np.int32)
     packed, count = handle
     c = int(count)
-    _count_download(count.nbytes + c * packed.dtype.itemsize, c)
-    return np.asarray(packed[:c])
+    take, nbytes = _fetch(packed, c)
+    _count_download(count.nbytes + nbytes, c)
+    return take
 
 
 def deduplicate_select(
@@ -1053,12 +1149,23 @@ def _resolve_tiled(handles) -> np.ndarray:
         out = []
         for (packed, counts), rows_list in handles[1]:
             counts_np = np.asarray(counts)
-            winners = int(counts_np[: len(rows_list)].sum())
-            _count_download(counts_np.nbytes + winners * packed.dtype.itemsize, winners)
+            live = counts_np[: len(rows_list)]
+            # a small chunk comes down in one piece; a long one a tile at a
+            # time, as separate host arrays: one array of both 8M-row tiles
+            # is past glibc's largest mmap threshold (32 MB) and would be
+            # mapped and faulted anew in every operation
+            whole = packed.size <= _FETCH_WHOLE_ELEMS
+            packed_np, nbytes = _fetch(packed, int(live.max())) if whole else (None, 0)
             for t, rows in enumerate(rows_list):
                 c = int(counts_np[t])
                 if c:
-                    out.append(rows[np.asarray(packed[t, :c])])
+                    if whole:
+                        take = packed_np[t, :c]
+                    else:
+                        take, tile_nbytes = _fetch(packed, c, row=t)
+                        nbytes += tile_nbytes
+                    out.append(rows[take])
+            _count_download(counts_np.nbytes + nbytes, int(live.sum()))
         return np.concatenate(out) if out else np.empty(0, dtype=np.int32)
     out = []
     for handle, rows in handles:
@@ -1270,20 +1377,15 @@ def fused_partial_update(
             )
         with span("merge.resolve"):
             kk = int(count)
-            last_take = unpack_selection_compact(
-                mask_last, runs_last, count, n, len(starts_real), rbits
-            )
-            exists = np.unpackbits(np.asarray(exists_bits[: (kk + 7) // 8]), count=kk).astype(bool)
+            last_take, nbytes = unpack_selection_compact(mask_last, runs_last, count, n, len(starts_real), rbits)
+            exists_np, exists_nbytes = _fetch(exists_bits, (kk + 7) // 8)
+            exists = np.unpackbits(exists_np, count=kk).astype(bool)
             # one download per tensor (not per field): 3 link round-trips total
             per = 8 // rbits
-            winb = np.asarray(win_bits[:, : (n + 7) // 8])
-            prb = np.asarray(present_bits[:, : (kk + 7) // 8])
-            blb = np.asarray(blk_bits[:, : max(1, (kk + per - 1) // per)])
-            _count_download(
-                count.nbytes + _compact_selection_nbytes(kk, n, len(starts_real), rbits) + (kk + 7) // 8
-                + _nbytes((winb, prb, blb)),
-                kk,
-            )
+            winb, win_nbytes = _fetch(win_bits, (n + 7) // 8)
+            prb, pr_nbytes = _fetch(present_bits, (kk + 7) // 8)
+            blb, bl_nbytes = _fetch(blk_bits, max(1, (kk + per - 1) // per))
+            _count_download(count.nbytes + nbytes + exists_nbytes + win_nbytes + pr_nbytes + bl_nbytes, kk)
         src_out = np.full((F, kk), -1, dtype=np.int32)
         for f in range(F):
             present, vals = unpack_field_selection_compact(winb[f], prb[f], blb[f], kk, n, rbits)
@@ -1294,10 +1396,10 @@ def fused_partial_update(
         src, exists, packed, count = _fused_partial_update_fn(k, s, fv.shape[0], engine)(*operands)
     with span("merge.resolve"):
         kk = int(count)
-        # device-side slicing: only (F, k) + 2k elements cross the link
-        out = (np.asarray(src[:F, :kk]), np.asarray(exists[:kk]), np.asarray(packed[:kk]))
-        _count_download(count.nbytes + _nbytes(out), kk)
-    return out
+        (src_np, src_nbytes), (exists_np, exists_nbytes), (packed_np, packed_nbytes) = (
+            _fetch(src, kk), _fetch(exists, kk), _fetch(packed, kk))
+        _count_download(count.nbytes + src_nbytes + exists_nbytes + packed_nbytes, kk)
+    return src_np[:F], exists_np, packed_np
 
 
 def partial_update_takes(

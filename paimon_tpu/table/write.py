@@ -19,6 +19,7 @@ import numpy as np
 from ..core.commit import BATCH_COMMIT_IDENTIFIER
 from ..core.manifest import CommitMessage, ManifestCommittable
 from ..data.batch import ColumnBatch
+from ..metrics import span, write_metrics
 from ..types import RowKind
 
 if TYPE_CHECKING:
@@ -45,6 +46,9 @@ class TableWrite:
         self.dynamic = table.is_primary_key_table and store.options.bucket == -1
         self.num_buckets = max(store.options.bucket, 1)
         self._writers: dict[tuple, object] = {}
+        # the operation id (metrics.span) of the checkpoint being written:
+        # allotted at its first write, taken up by prepare_commit, then 0
+        self._op = 0
         self._assigner = None
         self._cross = None
         if (
@@ -134,8 +138,15 @@ class TableWrite:
         self._route(data.take(take), kind.take(take))
 
     def write(self, data: ColumnBatch | dict, kinds: np.ndarray | Sequence[str] | None = None) -> None:
-        if isinstance(data, dict):
-            data = ColumnBatch.from_pydict(self.table.row_type, data)
+        with span("write", new_op=not self._op, op=self._op) as sp:
+            self._op = sp.op
+            if isinstance(data, dict):
+                data = ColumnBatch.from_pydict(self.table.row_type, data)
+            sp.add(rows=data.num_rows)
+            write_metrics().counter("rows").inc(data.num_rows)
+            self._write(data, kinds)
+
+    def _write(self, data: ColumnBatch, kinds: np.ndarray | Sequence[str] | None) -> None:
         if kinds is not None and not isinstance(kinds, np.ndarray):
             kinds = np.array([int(RowKind.from_short_string(k)) for k in kinds], dtype=np.uint8)
         if kinds is None:
@@ -163,6 +174,7 @@ class TableWrite:
         if self.dynamic:
             self._write_dynamic(data, kinds)
             return
+        buckets = 0
         for partition, bucket, rows in group_by_partition_bucket(
             data, self.partition_keys, self.bucket_keys, self.num_buckets
         ):
@@ -170,6 +182,10 @@ class TableWrite:
             sub = data.take(rows) if len(rows) != data.num_rows else data
             sub_kinds = kinds.take(rows) if kinds is not None and len(rows) != data.num_rows else kinds
             w.write(sub, sub_kinds)
+            buckets += 1
+        sp = span.current()
+        if sp is not None:
+            sp.add(buckets=buckets)
 
     def _write_dynamic(self, data: ColumnBatch, kinds) -> None:
         """Dynamic bucket: assign each key a durable bucket via the hash
@@ -273,6 +289,12 @@ class TableWrite:
                 w.flush_complete(st)
 
     def prepare_commit(self) -> list[CommitMessage]:
+        op, self._op = self._op, 0
+        write_metrics().counter("commits").inc()
+        with span("prepare_commit", new_op=not op, op=op):
+            return self._prepare_commit()
+
+    def _prepare_commit(self) -> list[CommitMessage]:
         if self._cross is not None:
             return self._cross.prepare_commit()
         if self._local_merge_cap:
