@@ -35,17 +35,21 @@ from .kv import KVBatch
 __all__ = ["MergeExecutor"]
 
 
-def _numpy_dedup_select(lanes: np.ndarray, seq_lanes: np.ndarray | None, compress: bool | None = None) -> np.ndarray:
+def _numpy_dedup_select(
+    lanes: np.ndarray, seq_lanes: np.ndarray | None, compress: bool | None = None, plan=None
+) -> np.ndarray:
     """sort-engine=numpy: the pure-host oracle path (useful when no
     accelerator is attached, and as the reference implementation the device
     kernels are tested against). Lane compression applies here too — fewer
     lexsort key arrays and fewer boundary compares, same selection — with an
-    all-constant key short-circuiting to the scalar winner."""
+    all-constant key short-circuiting to the scalar winner. Lanes that come
+    with their `plan` are packed already."""
     from ..data.keys import lexsort_rows
     from ..ops.lanes import compress_key_lanes, scalar_dedup_winner
 
     n = lanes.shape[0]
-    lanes, plan = compress_key_lanes(lanes, compress, enable_ovc=False)
+    if plan is None:
+        lanes, plan = compress_key_lanes(lanes, compress, enable_ovc=False)
     if plan is not None and lanes.shape[1] == 0:
         return scalar_dedup_winner(seq_lanes, n)
     tiebreakers = [] if seq_lanes is None else [seq_lanes[:, i] for i in range(seq_lanes.shape[1])]
@@ -126,6 +130,25 @@ class MergeExecutor:
             lanes = encode_key_lanes_with_pools(kv.data, self.key_names)
             sp.add(lanes=lanes.shape[1])
         return lanes
+
+    def _sort_lanes(self, kv: KVBatch, enable_ovc: bool):
+        """(key lanes, LanePlan or None) for a deduplicate dispatcher. Where
+        every key column is a plain integer column the sort operands are
+        planned and packed straight from the columns
+        (ops.lanes.compress_key_columns) and come with their plan, which
+        tells the dispatcher not to pack again; any other key, a plan with an
+        OVC lane, or the layer off, gives the raw (n, K) matrix and None."""
+        from ..data.keys import integer_key_columns
+        from ..ops.lanes import compress_key_columns
+
+        columns = integer_key_columns(kv.data, self.key_names)
+        if columns is not None:
+            with span("lanes.encode", rows=kv.num_rows) as sp:
+                packed = compress_key_columns(columns, self._compress, enable_ovc)
+                if packed is not None:
+                    sp.add(packed=1, lanes=packed[0].shape[1])
+                    return packed
+        return self._key_lanes(kv), None
 
     def _lanes(self, kv: KVBatch, seq_ascending: bool) -> tuple[np.ndarray, np.ndarray | None]:
         return self._key_lanes(kv), self._seq_lanes(kv, seq_ascending)
@@ -211,16 +234,20 @@ class MergeExecutor:
                 if kv.num_rows == 0:
                     return ("sync", kv)
         if self.engine == MergeEngine.DEDUPLICATE:
-            lanes = self._key_lanes(kv)
+            engine = self.effective_sort_engine()
+            if ctx is not None and engine != SortEngine.NUMPY:
+                lanes, plan = self._key_lanes(kv), None  # a round packs its shards itself
+            else:
+                lanes, plan = self._sort_lanes(kv, enable_ovc=engine != SortEngine.NUMPY)
             if self._strictly_increasing(lanes):
                 # already key-sorted with unique keys (bulk loads, replayed
                 # sorted runs): dedup is the identity — skip the device trip
-                # (sequence lanes are never built on this path)
+                # (sequence lanes are never built on this path; packing keeps
+                # order and equality, so packed lanes answer as raw ones do)
                 return ("sync", kv)
             seq_lanes = self._seq_lanes(kv, seq_ascending)
-            engine = self.effective_sort_engine()
             if engine == SortEngine.NUMPY:
-                return ("sync", kv.take(_numpy_dedup_select(lanes, seq_lanes, self._compress)))
+                return ("sync", kv.take(_numpy_dedup_select(lanes, seq_lanes, self._compress, plan)))
             if ctx is not None:
                 # submit RAW lanes — compression is decided ONCE per family
                 # batch from stats reduced over every shard
@@ -238,7 +265,7 @@ class MergeExecutor:
             from ..ops.merge import deduplicate_resolve, deduplicate_select_async
 
             take = deduplicate_resolve(
-                deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress)
+                deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress, plan=plan)
             )
             return ("sync", self.gather(kv, take))
         lanes, seq_lanes = self._lanes(kv, seq_ascending)
@@ -303,21 +330,26 @@ class MergeExecutor:
         synchronously — same handle contract, no device round trip. Under a
         MeshExecutor (a reader's round) the select is a job of the round's
         shard_map: RAW lanes, as merge_async submits them, and no tiling —
-        a round is the tile."""
-        lanes, seq_lanes = self._lanes(kv_keys, seq_ascending)
+        a round is the tile. Everywhere else the key lanes come packed from
+        the key columns where those are integers (_sort_lanes), so the
+        (n, K) matrix is built only for a consumer of one."""
         from ..options import SortEngine
         from ..parallel.mesh_exec import current_mesh_context
 
+        seq_lanes = self._seq_lanes(kv_keys, seq_ascending)
         engine = self.effective_sort_engine()
         if engine == SortEngine.NUMPY:
-            return ("numpy", _numpy_dedup_select(lanes, seq_lanes, self._compress))
+            lanes, plan = self._sort_lanes(kv_keys, enable_ovc=False)
+            return ("numpy", _numpy_dedup_select(lanes, seq_lanes, self._compress, plan))
         ctx = current_mesh_context()
         if ctx is not None:
             from ..ops.lanes import resolve_compress
 
+            lanes = self._key_lanes(kv_keys)
             return ("mesh", (ctx, ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress))))
         from ..ops.merge import deduplicate_select_async, deduplicate_tiled_dispatch
 
+        lanes, plan = self._sort_lanes(kv_keys, enable_ovc=True)
         backend = "pallas" if engine == SortEngine.PALLAS else "xla"
         if seq_lanes is None and run_offsets is not None:
             tile_rows = self.options.options.get(CoreOptions.MERGE_READ_BATCH_ROWS)
@@ -326,10 +358,13 @@ class MergeExecutor:
             return (
                 "tiled",
                 deduplicate_tiled_dispatch(
-                    lanes, run_offsets, tile_rows, backend=backend, compress=self._compress
+                    lanes, run_offsets, tile_rows, backend=backend, compress=self._compress, plan=plan
                 ),
             )
-        return ("single", deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress))
+        return (
+            "single",
+            deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress, plan=plan),
+        )
 
     @staticmethod
     def dedup_resolve(handle) -> np.ndarray:

@@ -36,6 +36,7 @@ __all__ = [
     "NormalizedKeys",
     "encode_key_lanes",
     "lane_count",
+    "integer_key_columns",
     "build_string_pool",
     "exact_string_pool",
     "split_int64_lanes",
@@ -76,6 +77,36 @@ def _lanes_for(root: TypeRoot) -> int:
     raise ValueError(f"type {root} not supported as a key column")
 
 
+_INT32_ROOTS = (TypeRoot.TINYINT, TypeRoot.SMALLINT, TypeRoot.INT, TypeRoot.DATE, TypeRoot.TIME)
+_INT64_ROOTS = (TypeRoot.BIGINT, TypeRoot.TIMESTAMP, TypeRoot.TIMESTAMP_LTZ, TypeRoot.DECIMAL)
+
+
+def integer_key_columns(batch: ColumnBatch, key_names: Sequence[str]) -> list[np.ndarray] | None:
+    """The key columns' own value arrays where EVERY key column is a plain
+    fixed-width signed integer column: an int8/16/32 array for the one-lane
+    roots, an int64 array for the two-lane roots, all present, values in
+    hand. ops.lanes.compress_key_columns then plans and packs the sort
+    operands straight from them (the dtype says how many lanes a column
+    is), and the (n, K) lane matrix of encode_key_lanes is never built.
+    None for anything else (a string, bytes, BOOLEAN, FLOAT or DOUBLE key, a
+    code-backed column, a null, an array of another dtype than its type's):
+    the caller encodes the matrix, which also raises what there is to
+    raise."""
+    out = []
+    for name in key_names:
+        root = batch.schema.field(name).type.root
+        if root not in _INT32_ROOTS and root not in _INT64_ROOTS:
+            return None
+        col = batch.column(name)
+        if col.is_code_backed or col.validity is not None:
+            return None
+        values = col.values
+        if values.dtype.kind != "i" or (values.dtype.itemsize == 8) != (root in _INT64_ROOTS):
+            return None
+        out.append(values)
+    return out
+
+
 def split_int64_lanes(v: np.ndarray, signed: bool = True) -> tuple[np.ndarray, np.ndarray]:
     """int64 -> (hi, lo) uint32 lanes, order preserving."""
     u = v.astype(np.int64).view(np.uint64)
@@ -89,10 +120,10 @@ def split_int64_lanes(v: np.ndarray, signed: bool = True) -> tuple[np.ndarray, n
 def _encode_column(values: np.ndarray, root: TypeRoot, pool: np.ndarray | None) -> list[np.ndarray]:
     if root == TypeRoot.BOOLEAN:
         return [values.astype(np.uint32)]
-    if root in (TypeRoot.TINYINT, TypeRoot.SMALLINT, TypeRoot.INT, TypeRoot.DATE, TypeRoot.TIME):
+    if root in _INT32_ROOTS:
         v32 = values.astype(np.int32)
         return [v32.view(np.uint32) ^ np.uint32(0x80000000)]
-    if root in (TypeRoot.BIGINT, TypeRoot.TIMESTAMP, TypeRoot.TIMESTAMP_LTZ, TypeRoot.DECIMAL):
+    if root in _INT64_ROOTS:
         hi, lo = split_int64_lanes(values)
         return [hi, lo]
     if root == TypeRoot.FLOAT:

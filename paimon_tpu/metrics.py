@@ -229,7 +229,9 @@ def pipeline_metrics() -> MetricGroup:
 
 def lanes_metrics() -> MetricGroup:
     """The lanes{...} group (key-lane compression layer, paimon_tpu.ops.lanes).
-    Canonical members — counters: plans (merges planned), lanes_in (logical
+    Canonical members — counters: plans (merges planned), plans_from_columns
+    (those of them planned and packed straight from integer key columns,
+    ops.lanes.compress_key_columns: no lane matrix was built), lanes_in (logical
     uint32 key lanes entering the planner), lanes_out (physical sort operands
     after truncation + packing, incl. the OVC lane when present), bytes_saved
     (host->device key-lane bytes elided vs the uncompressed upload),

@@ -51,6 +51,7 @@ verify stages can force both paths.
 from __future__ import annotations
 
 import os
+import sys
 from dataclasses import dataclass
 
 import numpy as np
@@ -63,6 +64,7 @@ __all__ = [
     "lane_stats",
     "apply_plan",
     "compress_key_lanes",
+    "compress_key_columns",
     "resolve_compress",
     "ovc_codes_np",
     "ovc_codes_jax",
@@ -150,10 +152,11 @@ def lane_stats(key_lanes: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
     return key_lanes.min(axis=0), key_lanes.max(axis=0)
 
 
-def _truncate_and_group(k: int, los, his):
-    """The shared stats -> (keep, bits, lo_kept, groups, vbits) decision of
-    every planner entry point: drop constant lanes, width each survivor to
-    its exact ptp bit length, fuse adjacent widths into <=32-bit operands."""
+def _truncate_and_group(k: int, los, his, enable_ovc: bool = False):
+    """The shared stats -> (keep, bits, lo_kept, groups, vbits, use_ovc)
+    decision of every planner entry point: drop constant lanes, width each
+    survivor to its exact ptp bit length, fuse adjacent widths into <=32-bit
+    operands, and say whether an OVC lane pays (never unless enable_ovc)."""
     keep: list[int] = []
     bits: list[int] = []
     lo_kept: list[int] = []
@@ -175,7 +178,15 @@ def _truncate_and_group(k: int, los, his):
     if cur:
         groups.append(tuple(cur))
     vbits = max((sum(bits[p] for p in grp) for grp in groups), default=0)
-    return keep, bits, lo_kept, groups, vbits
+    g = len(groups)
+    use_ovc = enable_ovc and g >= _OVC_MIN_GROUPS and g.bit_length() + vbits <= 32
+    if not use_ovc and all(len(grp) == 1 for grp in groups):
+        # nothing fuses and no code lane needs a bounded value field: the
+        # min-shift would be a pure copy (order and equality are shift-
+        # invariant, and the upload tier re-shifts in narrow_lane anyway) —
+        # zero the shifts so packing takes the no-arithmetic path
+        lo_kept = [0] * len(lo_kept)
+    return keep, bits, lo_kept, groups, vbits, use_ovc
 
 
 def plan_lanes_from_stats(lanes_in: int, los, his) -> LanePlan:
@@ -185,10 +196,7 @@ def plan_lanes_from_stats(lanes_in: int, los, his) -> LanePlan:
     packed operands stay comparable across devices (range-shuffle splitters,
     stacked shard_map lanes). Never emits an OVC lane: the code needs the
     batch-min row, and the mesh kernels carry plain packed lanes."""
-    keep, bits, lo_kept, groups, _vbits = _truncate_and_group(lanes_in, los, his)
-    if all(len(grp) == 1 for grp in groups):
-        # same zero-shift rule as the local planner: a pure column selection
-        lo_kept = [0] * len(lo_kept)
+    keep, bits, lo_kept, groups, _vbits, _ovc = _truncate_and_group(lanes_in, los, his)
     return LanePlan(lanes_in, tuple(keep), tuple(lo_kept), tuple(bits), tuple(groups))
 
 
@@ -221,15 +229,7 @@ def plan_lanes(key_lanes: np.ndarray, enable_ovc: bool = True) -> LanePlan:
         # 0/1 rows: every lane is batch-constant — a zero-width key
         return LanePlan(k, (), (), (), ())
     los, his = lane_stats(key_lanes)
-    keep, bits, lo_kept, groups, vbits = _truncate_and_group(k, los, his)
-    g = len(groups)
-    use_ovc = enable_ovc and g >= _OVC_MIN_GROUPS and g.bit_length() + vbits <= 32
-    if not use_ovc and all(len(grp) == 1 for grp in groups):
-        # nothing fuses and no code lane needs a bounded value field: the
-        # min-shift would be a pure copy (order and equality are shift-
-        # invariant, and the upload tier re-shifts in narrow_lane anyway) —
-        # zero the shifts so apply_plan can take the no-arithmetic path
-        lo_kept = [0] * len(lo_kept)
+    keep, bits, lo_kept, groups, vbits, use_ovc = _truncate_and_group(k, los, his, enable_ovc)
     base: tuple[int, ...] = ()
     if use_ovc:
         # the batch's lexicographically minimal row (over kept lanes), found
@@ -270,13 +270,25 @@ def apply_plan(plan: LanePlan, key_lanes: np.ndarray) -> np.ndarray:
             return key_lanes.astype(np.uint32, copy=False)
         sel = [plan.keep[g[0]] for g in plan.groups]
         return np.ascontiguousarray(key_lanes[:, sel].astype(np.uint32, copy=False))
-    out = np.empty((n, len(plan.groups)), dtype=np.uint32)
+    return _pack(plan, n, lambda i: key_lanes[:, i].astype(np.uint32))
+
+
+def _pack(plan: LanePlan, n: int, lane) -> np.ndarray:
+    """The plan's shift-and-fuse arithmetic over logical lanes fetched one at
+    a time: lane(i) is a fresh (n,) uint32 array of logical lane i, cut from
+    a lane matrix (apply_plan) or made from a key column
+    (compress_key_columns). A sole group is the result itself, reshaped."""
+    g = len(plan.groups)
+    out = None if g == 1 else np.empty((n, g), dtype=np.uint32)
     for gi, grp in enumerate(plan.groups):
-        first = grp[0]
-        acc = key_lanes[:, plan.keep[first]].astype(np.uint32) - np.uint32(plan.los[first])
-        for pos in grp[1:]:
-            lane = key_lanes[:, plan.keep[pos]].astype(np.uint32) - np.uint32(plan.los[pos])
-            acc = (acc << np.uint32(plan.bits[pos])) | lane
+        acc = None
+        for pos in grp:
+            v = lane(plan.keep[pos])
+            if plan.los[pos]:
+                v -= np.uint32(plan.los[pos])
+            acc = v if acc is None else (acc << np.uint32(plan.bits[pos])) | v
+        if out is None:
+            return acc.reshape(n, 1)
         out[:, gi] = acc
     return out
 
@@ -302,11 +314,77 @@ def compress_key_lanes(
     return packed, plan
 
 
-def _record(plan: LanePlan, n: int) -> None:
+def compress_key_columns(
+    columns,
+    compress: bool | None = None,
+    enable_ovc: bool = True,
+) -> tuple[np.ndarray, LanePlan] | None:
+    """The seam's entry for callers that still hold the key COLUMNS, each a
+    fixed-width signed integer array (data.keys.integer_key_columns: int64
+    is two logical lanes, a narrower column one): the same (lanes', plan) as
+    compress_key_lanes over the encoded (n, K) matrix, bit for bit, made in
+    one pass — a column's min and max give every lane's, and each packed
+    operand is written straight from the columns — so that neither the
+    matrix nor its re-packing exists. Declines with None, and counts
+    nothing, where the layer is off or the plan would carry an OVC lane
+    (its base is the batch-minimum ROW, found on the matrix): the caller
+    encodes the matrix and takes compress_key_lanes as before."""
+    if not resolve_compress(compress):
+        return None
+    columns = [np.ascontiguousarray(c) for c in columns]
+    lanes = [(c, part) for c in columns for part in range(1 if c.dtype.itemsize <= 4 else 2)]
+    n, k = (len(columns[0]) if columns else 0), len(lanes)
+    if n <= 1 or k == 0:
+        plan = LanePlan(k, (), (), (), ())  # as plan_lanes: a zero-width key
+    else:
+        stats = [s for c in columns for s in _column_lane_stats(c)]
+        keep, bits, lo_kept, groups, _vbits, use_ovc = _truncate_and_group(
+            k, [lo for lo, _ in stats], [hi for _, hi in stats], enable_ovc
+        )
+        if use_ovc:
+            return None
+        plan = LanePlan(k, tuple(keep), tuple(lo_kept), tuple(bits), tuple(groups))
+    packed = _pack(plan, n, lambda i: _column_lane(*lanes[i]))
+    _record(plan, n, from_columns=True)
+    return packed, plan
+
+
+_LOW_WORD = 0 if sys.byteorder == "little" else 1
+
+
+def _column_lane_stats(col: np.ndarray) -> list[tuple[int, int]]:
+    """(min, max) of each logical lane of one integer key column, as
+    lane_stats reads them off the encoded matrix, from the column's own min
+    and max: the sign-flipped encoding is monotone in the value, and so is an
+    int64's high word; its low word spans the column's where the high word
+    is constant and otherwise costs one more pass over the column."""
+    a, b = int(col.min()), int(col.max())
+    if col.dtype.itemsize <= 4:
+        return [(a + (1 << 31), b + (1 << 31))]
+    ua, ub = a + (1 << 63), b + (1 << 63)
+    if ua >> 32 == ub >> 32:
+        return [(ua >> 32, ub >> 32), (ua & 0xFFFFFFFF, ub & 0xFFFFFFFF)]
+    low = col.view(np.uint32)[_LOW_WORD::2]
+    return [(ua >> 32, ub >> 32), (int(low.min()), int(low.max()))]
+
+
+def _column_lane(col: np.ndarray, part: int) -> np.ndarray:
+    """Logical lane `part` of an integer key column as a fresh (n,) uint32
+    array: element for element what data.keys._encode_column writes."""
+    if col.dtype.itemsize <= 4:
+        return col.astype(np.int32, copy=False).view(np.uint32) ^ np.uint32(0x80000000)
+    u = col.view(np.uint64)
+    if part:
+        return u.astype(np.uint32)  # the low word: the sign flip is bit 63
+    return (u >> np.uint64(32)).astype(np.uint32) ^ np.uint32(0x80000000)
+
+
+def _record(plan: LanePlan, n: int, from_columns: bool = False) -> None:
     from ..metrics import lanes_metrics
 
     g = lanes_metrics()
     g.counter("plans").inc()
+    g.counter("plans_from_columns").inc(int(from_columns))
     g.counter("lanes_in").inc(plan.lanes_in)
     g.counter("lanes_out").inc(plan.sort_width)
     if plan.use_ovc:

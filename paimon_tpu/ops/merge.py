@@ -340,15 +340,33 @@ def prepare_lanes(key_lanes: np.ndarray, seq_lanes: np.ndarray | None, narrow: b
     return klp, slp, pad, n, k, s, m
 
 
+def _packed(key_lanes, compress: bool | None, plan):
+    """(lanes', plan) behind the compression seam, for every dispatcher:
+    lanes handed over WITH their plan were packed from the key columns
+    (ops.lanes.compress_key_columns) and are not packed again; a raw (n, K)
+    matrix is, or with the layer off loses its constant lanes (plan None)."""
+    from .lanes import compress_key_lanes, resolve_compress
+
+    if plan is not None:
+        return key_lanes, plan
+    key_lanes = np.ascontiguousarray(key_lanes)
+    if resolve_compress(compress):
+        return compress_key_lanes(key_lanes, True)
+    return drop_constant_lanes(key_lanes), None
+
+
 def prepare_lanes_planned(
     key_lanes: np.ndarray,
     seq_lanes: np.ndarray | None,
     narrow: bool = True,
     compress: bool | None = None,
+    plan=None,
 ):
     """prepare_lanes behind the key-lane compression seam (ops/lanes.py):
     the key matrix is truncated/packed per a LanePlan before the usual
-    narrowing + padding. Returns (klp, slp, pad, n, k, s, m, plan); plan is
+    narrowing + padding; lanes that come with their `plan` are packed
+    already (ops.lanes.compress_key_columns) and pass the seam as they are.
+    Returns (klp, slp, pad, n, k, s, m, plan); plan is
     None when the layer is off (k then counts post-drop_constant_lanes key
     lanes, exactly the legacy path). Either way an all-constant key yields
     k == 0 — callers take the zero-width scalar fast path instead of the old
@@ -357,7 +375,9 @@ def prepare_lanes_planned(
 
     from .lanes import compress_key_lanes
 
-    kl, plan = compress_key_lanes(np.ascontiguousarray(key_lanes), compress)
+    kl = key_lanes
+    if plan is None:
+        kl, plan = compress_key_lanes(np.ascontiguousarray(key_lanes), compress)
     klp, slp, pad, n, k, s, m = prepare_lanes(kl, seq_lanes, narrow=narrow)
     if plan is not None and plan.use_ovc and kl.shape[0]:
         # narrow_lane min-shifts every uploaded column; the OVC base must
@@ -588,19 +608,21 @@ def deduplicate_select_async(
     seq_lanes: np.ndarray | None = None,
     backend: str = "xla",
     compress: bool | None = None,
+    plan=None,
 ):
     """Dispatch the dedup kernel without blocking: returns (packed_device,
     count_device). jax's async dispatch lets the host keep decoding value
     columns while the device sorts — resolve with deduplicate_resolve().
-    The key matrix goes through the lane-compression seam first; an
+    The key matrix goes through the lane-compression seam first (key lanes
+    that come with their `plan` have passed it: _packed); an
     all-constant key short-circuits to the scalar winner without any device
     dispatch."""
     with span("merge.dispatch", rows=len(key_lanes)):
         if len(key_lanes) > _STREAM_TILE_ROWS:
-            handle = _stream_dispatch(key_lanes, seq_lanes, backend, compress)
+            handle = _stream_dispatch(key_lanes, seq_lanes, backend, compress, plan)
             if handle is not None:
                 return handle
-        return _select_async(key_lanes, seq_lanes, backend, compress, merges=1)
+        return _select_async(key_lanes, seq_lanes, backend, compress, merges=1, plan=plan)
 
 
 # A merge of more rows than this runs as key-range tiles of this one padded
@@ -613,7 +635,7 @@ def deduplicate_select_async(
 _STREAM_TILE_ROWS = 1 << 17
 
 
-def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None):
+def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None, plan=None):
     """Key-range tiles of an input in ANY row order, all of the padded shape
     (_STREAM_TILE_ROWS,) and of u32 lanes, so every tile of every merge with
     the same lane arity is the same program. Tiles cut the key space on the
@@ -623,13 +645,8 @@ def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None):
     input rows of the tile)]) in ascending key-range order, or None where
     the keys cannot be cut that finely (one lane-0 value holds more rows
     than a tile): the caller then sorts the whole at its own pad bucket."""
-    from .lanes import compress_key_lanes, resolve_compress
-
     n = key_lanes.shape[0]
-    if resolve_compress(compress):
-        lanes, plan = compress_key_lanes(np.ascontiguousarray(key_lanes), True)
-    else:
-        lanes, plan = drop_constant_lanes(np.ascontiguousarray(key_lanes)), None
+    lanes, plan = _packed(key_lanes, compress, plan)
     if lanes.shape[1] == 0:
         return None  # all keys equal: the scalar path, no device trip
     seqs = drop_constant_lanes(np.ascontiguousarray(seq_lanes)) if seq_lanes is not None else None
@@ -671,11 +688,11 @@ def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None):
     return ("stream", handles)
 
 
-def _select_async(key_lanes, seq_lanes, backend: str, compress: bool | None, merges: int = 0):
+def _select_async(key_lanes, seq_lanes, backend: str, compress: bool | None, merges: int = 0, plan=None):
     """deduplicate_select_async without its span; `merges` is 1 where the
     call is a whole merge and 0 where it is one tile of a merge that its
     caller counts."""
-    klp, slp, pad, n, k, s, m, plan = prepare_lanes_planned(key_lanes, seq_lanes, compress=compress)
+    klp, slp, pad, n, k, s, m, plan = prepare_lanes_planned(key_lanes, seq_lanes, compress=compress, plan=plan)
     if k == 0:
         # all keys equal: one winner — the last row in (seq, input) order;
         # no key sort, no device trip (host lexsort of the seq lanes only)
@@ -1057,9 +1074,11 @@ def deduplicate_tiled_dispatch(
     tile_rows: int = 256 * 1024,
     backend: str = "xla",
     compress: bool | None = None,
+    plan=None,
 ):
     """Async dispatch of the key-range tiled dedup; resolve with
-    deduplicate_resolve_tiled.
+    deduplicate_resolve_tiled. Key lanes that come with their `plan` are
+    packed already (_packed).
 
     Uniform-batch design (VERDICT r4 #2): all tiles share one pad bucket
     m = pad_size(max tile rows), one narrowing dtype per lane (u16 iff every
@@ -1070,24 +1089,20 @@ def deduplicate_tiled_dispatch(
     than the device budget stream through as equal-shaped chunks (the
     reference spills to disk instead: MergeSorter.java:110-116)."""
     with span("merge.dispatch", rows=len(key_lanes)):
-        return _tiled_dispatch(key_lanes, run_offsets, tile_rows, backend, compress)
+        return _tiled_dispatch(key_lanes, run_offsets, tile_rows, backend, compress, plan)
 
 
-def _tiled_dispatch(key_lanes, run_offsets, tile_rows: int, backend: str, compress: bool | None):
-    key_lanes = np.ascontiguousarray(key_lanes)
+def _tiled_dispatch(key_lanes, run_offsets, tile_rows: int, backend: str, compress: bool | None, plan=None):
     n = key_lanes.shape[0]
     offsets = list(run_offsets)
     if n == 0:
         return []
-    from .lanes import compress_key_lanes, resolve_compress, scalar_dedup_winner
+    from .lanes import scalar_dedup_winner
 
     # one compression plan for the whole merge; tiles inherit the packed
     # lanes (row order is untouched, so run offsets and the per-run key
     # ascent the tiler depends on both survive the transform)
-    if resolve_compress(compress):
-        key_lanes, _plan = compress_key_lanes(key_lanes, True)
-    else:
-        key_lanes = drop_constant_lanes(key_lanes)
+    key_lanes, _plan = _packed(key_lanes, compress, plan)
     if key_lanes.shape[1] == 0:
         # all keys equal: one winner (no seq lanes on this path — run order
         # + stability carries the tie-break, so the winner is the last row)

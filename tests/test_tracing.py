@@ -49,17 +49,19 @@ def traced(directory):
                     events.append((e.name[3:], e.start_ns, e.start_ns + e.duration_ns, (pi, li), dict(e.stats)))
 
 
-def _table(warehouse, **options):
+def _table(warehouse, string_key=False, **options):
     catalog = FileSystemCatalog(str(warehouse), commit_user="tracing")
     table = catalog.create_table(
-        "db.t", pt.RowType.of(("id", pt.BIGINT(False)), ("v", pt.DOUBLE()), ("s", pt.STRING())),
+        "db.t", pt.RowType.of(("id", pt.STRING(False) if string_key else pt.BIGINT(False)), ("v", pt.DOUBLE()),
+                              ("s", pt.STRING())),
         primary_keys=["id"], options={"bucket": "1", "write-only": "true", **options})
     rng = np.random.default_rng(3)
     for r in range(RUNS):  # overlapping sorted runs, so the read has to merge
         ids = np.sort(rng.choice(KEYS, ROWS_A_RUN, replace=False)).astype(np.int64)
         wb = table.new_batch_write_builder()
         w = wb.new_write()
-        w.write({"id": ids, "v": ids * 1.0 + r, "s": np.array([f"s{i % 7}" for i in ids], dtype=object)})
+        keys = np.array([f"k{i:05d}" for i in ids], dtype=object) if string_key else ids
+        w.write({"id": keys, "v": ids * 1.0 + r, "s": np.array([f"s{i % 7}" for i in ids], dtype=object)})
         wb.new_commit().commit(w.prepare_commit())
     return table
 
@@ -78,7 +80,10 @@ def _columns(batch):
 def reads(tmp_path_factory):
     """One table read four times: untraced and cold (nothing recorded), in a
     session that sees nothing of it, then cold again for the trace (the cache
-    dropped) and warm (every file a cache hit)."""
+    dropped) and warm (every file a cache hit). And its twin under a STRING
+    key, traced: an integer key's sort operands come packed out of
+    `lanes.encode`, so only such a read re-packs a lane matrix
+    (`lanes.compress`)."""
     tmp = tmp_path_factory.mktemp("tracing")
     table = _table(tmp / "warehouse")
     untraced = _columns(_read(table))
@@ -91,7 +96,15 @@ def reads(tmp_path_factory):
         cold_out = _columns(_read(table))
     with traced(tmp / "warm") as warm:
         _read(table)
-    return {"untraced": untraced, "after": after, "cold": cold, "cold_out": cold_out, "warm": warm}
+    string_keyed = _table(tmp / "string_key_warehouse", string_key=True)
+    with traced(tmp / "string_key") as string_key:
+        _read(string_keyed)
+    return {"untraced": untraced, "after": after, "cold": cold, "cold_out": cold_out, "warm": warm,
+            "string_key": string_key}
+
+
+def _events(reads, name):
+    return reads["string_key" if name == "lanes.compress" else "cold"]
 
 
 def _named(events, name):
@@ -109,7 +122,14 @@ READ_PATH_SPANS = ("plan", "read_all", "split", "decode.keys", "decode.values", 
 
 @pytest.mark.parametrize("name", READ_PATH_SPANS)
 def test_a_traced_read_opens_the_span(reads, name):
-    assert _named(reads["cold"], name), sorted({e[0] for e in reads["cold"]})
+    assert _named(_events(reads, name), name), sorted({e[0] for e in _events(reads, name)})
+
+
+def test_an_integer_key_comes_packed_out_of_lanes_encode(reads):
+    assert not _named(reads["cold"], "lanes.compress")
+    (encode,) = _named(reads["cold"], "lanes.encode")  # one BIGINT key, no sequence lanes
+    assert encode[4]["packed"] == 1 and encode[4]["lanes"] == 1 and encode[4]["rows"] == RUNS * ROWS_A_RUN
+    assert all("packed" not in e[4] for e in _named(reads["string_key"], "lanes.encode"))
 
 
 def test_spans_of_one_read_share_one_operation_id(reads):
@@ -128,10 +148,12 @@ def test_spans_of_one_read_share_one_operation_id(reads):
     ("merge.dispatch", "split"), ("lanes.compress", "merge.dispatch"), ("merge.resolve", "split"),
     ("gather", "split"), ("gather.plan", "gather"), ("finish", "split")])
 def test_spans_nest_as_documented(reads, name, parent):
-    (read_all,) = _named(reads["cold"], "read_all")
-    for e in _named(reads["cold"], name):
+    events = _events(reads, name)
+    (read_all,) = _named(events, "read_all")
+    assert _named(events, name)
+    for e in _named(events, name):
         assert e[4]["parent"] == parent
-        assert any(_inside(e, p) for p in _named(reads["cold"], parent)), (name, parent)
+        assert any(_inside(e, p) for p in _named(events, parent)), (name, parent)
         assert _inside(e, read_all)
     # the reading thread's spans in their order: the dispatch comes before the
     # value pass (the device sorts while the host decodes), the resolve after it
