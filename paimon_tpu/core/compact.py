@@ -238,12 +238,17 @@ class MergeTreeCompactRewriter:
 
         runs, seq_ascending = order_runs_for_merge(section)
         files = [f for run in runs for f in run.files]
-        # per-file reads fan out over the shared pool (order preserved, so
-        # the concatenated runs — and the merge — are bit-identical to the
-        # old serial loop); this is leaf work per the pool contract
-        batches = bounded_map(self._read, files)
+        with span("compact.read", files=len(files)) as sp:
+            # per-file reads fan out over the shared pool (order preserved, so
+            # the concatenated runs — and the merge — are bit-identical to the
+            # old serial loop); this is leaf work per the pool contract
+            batches = bounded_map(self._read, files)
+            decoded = sum(b.byte_size() for b in batches)
+            kv = KVBatch.concat(batches)
+            sp.add(rows=kv.num_rows, bytes=decoded)
+        compaction_metrics().counter("bytes_in").inc(decoded)
         old_top = [b for f, b in zip(files, batches) if f.level == output_level]
-        return KVBatch.concat(batches), old_top, seq_ascending
+        return kv, old_top, seq_ascending
 
     def rewrite_dispatch(self, sections: list[list[SortedRun]], output_level: int):
         """Phase 1: read every section's runs and dispatch their merges.

@@ -198,6 +198,9 @@ class KeyValueFileWriterFactory:
             with span("file.write", level=level, format=format_id, rows=part.num_rows) as sp:
                 meta = self._write_one(part, level, file_source, prefix, sorted_input)
                 sp.add(bytes=meta.file_size)
+                # the write materialized values on the part's columns (stats over the strings): released
+                # here, inside the file's span, not between two files where no span names the time
+                del part
             out.append(meta)
         return out
 

@@ -368,8 +368,9 @@ def compaction_metrics() -> MetricGroup:
     """The compaction{...} group (LSM compaction execution, core.compact,
     plus the adaptive scheduler, table.compactor.AdaptiveCompactorService).
     Canonical members — counters: rounds (trigger_compaction calls, whether
-    or not the strategy picked a unit), rows_in / rows_out / files_out /
-    bytes_out (rows of the files a rewrite read, and rows, count and sizes
+    or not the strategy picked a unit), rows_in / bytes_in / rows_out /
+    files_out / bytes_out (rows of the files a rewrite read and their
+    decoded bytes as its read head joined them, and rows, count and sizes
     of the files it wrote: an upgrade moves a file between levels and counts
     in none of them), compactions, files_rewritten (execution
     side, incremented per committed rewrite), adaptive_runs (buckets the
