@@ -21,9 +21,9 @@ from ..data.casting import cast_column
 from ..data.predicate import FieldStats, Predicate
 from ..format import collect_stats, get_format, stats_from_json, stats_to_json
 from ..fs import FileIO
-from ..metrics import span
+from ..metrics import datafile_metrics, span
 from ..types import DataField, RowKind, RowType
-from ..utils import new_file_name, now_millis
+from ..utils import new_file_name, now_millis, on_shared_pool
 from .kv import SEQUENCE_FIELD_NAME, VALUE_KIND_FIELD_NAME, KVBatch, kv_disk_schema
 
 __all__ = ["DataFileMeta", "KeyValueFileWriterFactory", "KeyValueFileReaderFactory"]
@@ -136,6 +136,7 @@ class KeyValueFileWriterFactory:
         include_key_columns: bool = False,
         per_level_format: dict[int, str] | None = None,
         per_level_compression: dict[int, str] | None = None,
+        parallelism: int | None = None,
     ):
         self.file_io = file_io
         self.bucket_dir = bucket_dir
@@ -165,6 +166,9 @@ class KeyValueFileWriterFactory:
         # extension, so levels can mix freely
         self.per_level_format = per_level_format or {}
         self.per_level_compression = per_level_compression or {}
+        # scan.parallelism: how many files of one write may be on the shared
+        # pool at once (None = the pool's width, 1 = written in turn)
+        self.parallelism = parallelism
 
     def _estimate_row_bytes(self, batch: ColumnBatch) -> int:
         total = 0
@@ -191,17 +195,42 @@ class KeyValueFileWriterFactory:
             return []
         row_bytes = measured_row_bytes or self._estimate_row_bytes(kv.data)
         rows_per_file = max(1, int(self.target_file_size / max(row_bytes, 1)))
-        out: list[DataFileMeta] = []
         format_id = self.per_level_format.get(level, self.format_id)
-        for start in range(0, n, rows_per_file):
+        starts = range(0, n, rows_per_file)
+        failures: list[BaseException] = []
+
+        def write_part(start: int) -> DataFileMeta | None:
+            if failures:  # a file before this one failed: write no more, as the loop in turn stops
+                return None
             part = kv.slice(start, min(start + rows_per_file, n))
-            with span("file.write", level=level, format=format_id, rows=part.num_rows) as sp:
-                meta = self._write_one(part, level, file_source, prefix, sorted_input)
-                sp.add(bytes=meta.file_size)
-                # the write materialized values on the part's columns (stats over the strings): released
-                # here, inside the file's span, not between two files where no span names the time
-                del part
-            out.append(meta)
+            try:
+                with span("file.write", level=level, format=format_id, rows=part.num_rows) as sp:
+                    meta = self._write_one(part, level, file_source, prefix, sorted_input)
+                    sp.add(bytes=meta.file_size)
+                    # what the write materialized on the part's columns is released here, inside the
+                    # file's span, not between two files where no span names the time
+                    del part
+                return meta
+            except BaseException as e:  # noqa: BLE001 — raised below, once no file is being written any more
+                failures.append(e)
+                return None
+
+        # the files are independent (disjoint slices of one batch, a uuid name each): several go to the
+        # shared pool, a file a task, unless this thread is one of the pool's (a pool task submits nothing)
+        on_pool = len(starts) > 1 and (self.parallelism is None or self.parallelism > 1) and not on_shared_pool()
+        if len(starts) == 1:
+            out = [write_part(0)]
+        else:
+            from ..parallel.pipeline import bounded_map
+
+            with span("files.write", files=len(starts), rows=n, on_pool=int(on_pool)):
+                out = bounded_map(write_part, starts, self.parallelism if on_pool else 1)
+        if failures:
+            raise failures[0]
+        g = datafile_metrics()
+        g.counter("files_written").inc(len(out))
+        if on_pool:
+            g.counter("files_written_on_pool").inc(len(out))
         return out
 
     def _key_min_max(self, batch: ColumnBatch, sorted_input: bool) -> tuple[tuple, tuple]:
@@ -375,8 +404,6 @@ class KeyValueFileReaderFactory:
         fields: Sequence[str] | None,
         system_columns: bool | str,
     ) -> KVBatch:
-        from ..metrics import datafile_metrics, span
-
         which = "all" if fields is None else ("values" if system_columns is False else "keys")
         with span("decode.file", format=meta.file_name.rsplit(".", 1)[-1], **{"pass": which}) as sp:
             kv = self._decode_file(meta, predicate, fields, system_columns)

@@ -145,6 +145,7 @@ class KeyValueFileStore:
             include_key_columns=co.options.get(CoreOptions.DATA_FILE_INCLUDE_KEY_COLUMNS),
             per_level_format=_parse_per_level(co.options.get(CoreOptions.FILE_FORMAT_PER_LEVEL)),
             per_level_compression=_parse_per_level(co.options.get(CoreOptions.FILE_COMPRESSION_PER_LEVEL)),
+            parallelism=co.options.get(CoreOptions.SCAN_PARALLELISM),
         )
 
     def reader_factory(self, partition: tuple, bucket: int, read_schema: RowType | None = None) -> KeyValueFileReaderFactory:

@@ -117,6 +117,8 @@ def collect_stats(batch: ColumnBatch, truncate: int = _TRUNCATE_LEN) -> dict[str
                 if nulls:
                     codes = codes[col.validity]
                 lo, hi = pool[int(codes.min())], pool[int(codes.max())]
+            elif _arrow_orders_as_python(col.arrow):
+                lo, hi = _arrow_min_max(col, nulls)
             else:
                 v = col.values[col.valid_mask()] if nulls else col.values
                 lo, hi = min(v), max(v)
@@ -137,6 +139,33 @@ def collect_stats(batch: ColumnBatch, truncate: int = _TRUNCATE_LEN) -> dict[str
             lo, hi = _to_py(v.min()), _to_py(v.max())
         out[f.name] = FieldStats(lo, hi, nulls, n)
     return out
+
+
+def _arrow_orders_as_python(arr) -> bool:
+    """An arrow-backed STRING or BINARY column: arrow compares the bytes, and
+    UTF-8 byte order is code-point order, which is how Python compares str."""
+    if arr is None:
+        return False
+    import pyarrow as pa
+
+    t = arr.type
+    return pa.types.is_string(t) or pa.types.is_large_string(t) or pa.types.is_binary(t) or pa.types.is_large_binary(t)
+
+
+def _arrow_min_max(col, nulls: int):
+    """min and max of an arrow-backed column in arrow's kernel, over the
+    array the file is written from: no Python object a row (Column.values
+    stays unmaterialized) and no interpreter lock held while it runs. Nulls
+    are skipped; a validity kept beside the array that hides more than the
+    array's own nulls is applied first."""
+    import pyarrow as pa
+    import pyarrow.compute as pc
+
+    arr = col.arrow
+    if nulls != arr.null_count:
+        arr = arr.filter(pa.array(col.validity))
+    mm = pc.min_max(arr)
+    return mm["min"].as_py(), mm["max"].as_py()
 
 
 def _to_py(x):

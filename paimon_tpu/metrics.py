@@ -275,14 +275,18 @@ def read_metrics() -> MetricGroup:
 
 
 def datafile_metrics() -> MetricGroup:
-    """The datafile{...} group (KeyValueFileReaderFactory._decode,
-    paimon_tpu.core.datafile: one data file read through its format and
-    mapped onto the read schema, whichever decoder backs the format; a
-    data-file cache hit decodes nothing and counts nothing). Canonical
+    """The datafile{...} group (paimon_tpu.core.datafile:
+    KeyValueFileReaderFactory._decode, one data file read through its format
+    and mapped onto the read schema, whichever decoder backs the format; a
+    data-file cache hit decodes nothing and counts nothing; and
+    KeyValueFileWriterFactory.write). Canonical
     members, counters: files_decoded, rows_decoded, bytes_decoded (the
-    decoded batch's KVBatch.byte_size()). The decode{...} group stays the
-    native parquet decoder's own. Resolved per call so registry.reset() in
-    tests swaps the group out."""
+    decoded batch's KVBatch.byte_size()); files_written (data files a
+    KeyValueFileWriterFactory.write returned) and files_written_on_pool
+    (those of them written as tasks of the shared pool: the files of a
+    write that is cut into several, called from a thread that is not the
+    pool's). The decode{...} group stays the native parquet decoder's own.
+    Resolved per call so registry.reset() in tests swaps the group out."""
     return registry.group("datafile")
 
 

@@ -17,11 +17,13 @@ __all__ = [
     "enable_compile_cache",
     "require_device",
     "shared_executor",
+    "on_shared_pool",
 ]
 
 
 _SHARED_POOL = None
 _SHARED_POOL_LOCK = threading.Lock()
+SHARED_POOL_THREAD_PREFIX = "paimon-decode"
 
 
 def _reset_shared_pool_after_fork() -> None:
@@ -63,9 +65,16 @@ def shared_executor():
                 if workers <= 0:
                     workers = min(16, max(8, (os.cpu_count() or 4) + 4))
                 _SHARED_POOL = ThreadPoolExecutor(
-                    max_workers=workers, thread_name_prefix="paimon-decode"
+                    max_workers=workers, thread_name_prefix=SHARED_POOL_THREAD_PREFIX
                 )
     return _SHARED_POOL
+
+
+def on_shared_pool() -> bool:
+    """Whether the calling thread is a worker of the shared pool: work that
+    would fan out over the pool runs in turn there instead (see
+    shared_executor: a pool task never submits to its own pool)."""
+    return threading.current_thread().name.startswith(SHARED_POOL_THREAD_PREFIX)
 
 
 def enable_compile_cache() -> str:
