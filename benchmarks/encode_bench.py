@@ -144,7 +144,6 @@ def run_headline(n_rows=N_ROWS, iters=3):
             "dict_pages": g.counter("dict_pages").count,
             "files_native": g.counter("files_native").count,
             "files_fallback": g.counter("files_fallback").count,
-            "encode_ms_mean": round(g.histogram("encode_ms").mean, 2),
             "stats_ms_mean": round(g.histogram("stats_ms").mean, 3),
             "unit": "counters",
         }

@@ -244,7 +244,9 @@ class MergeTreeCompactRewriter:
             # old serial loop); this is leaf work per the pool contract
             batches = bounded_map(self._read, files)
             decoded = sum(b.byte_size() for b in batches)
-            kv = KVBatch.concat(batches)
+            with span("compact.join", bytes=decoded) as join:
+                kv = KVBatch.concat(batches)
+                join.add(rows=kv.num_rows)
             sp.add(rows=kv.num_rows, bytes=decoded)
         compaction_metrics().counter("bytes_in").inc(decoded)
         old_top = [b for f, b in zip(files, batches) if f.level == output_level]

@@ -264,43 +264,51 @@ class KeyValueFileWriterFactory:
         name = new_file_name(prefix, format_id)
         path = f"{self.bucket_dir}/{name}"
         key_cols = self.key_names if (self.keyed and self.include_key_columns) else None
-        disk = kv.to_disk_batch(key_cols) if self.keyed else kv.data
+        # the phases of the file (docs/tracing.md): the format's write opens encode.arrow, encode.format and
+        # encode.put for its own part
+        with span("encode.arrow", rows=kv.num_rows):
+            disk = kv.to_disk_batch(key_cols) if self.keyed else kv.data
         fmt.write(self.file_io, path, disk, compression, format_options=self.format_options)
-        extra: list[str] = []
-        embedded: bytes | None = None
-        if self.bloom_columns or self.key_bloom:
-            from ..format.fileindex import build_index_payload, index_path
+        with span("encode.put", rows=kv.num_rows):
+            file_size = self.file_io.get_status(path).size
+        with span("encode.stats", rows=kv.num_rows):
+            extra: list[str] = []
+            embedded: bytes | None = None
+            if self.bloom_columns or self.key_bloom:
+                from ..format.fileindex import build_index_payload, index_path
 
-            hashes = None
-            if self.key_bloom:
-                from ..table.bucket import key_hashes
+                hashes = None
+                if self.key_bloom:
+                    from ..table.bucket import key_hashes
 
-                hashes = key_hashes(kv.data, self.key_names)
-            payload = build_index_payload(
-                kv.data, self.bloom_columns, self.bloom_fpp,
-                key_hashes=hashes, key_fpp=self.key_bloom_fpp,
-            )
-            if payload is not None:
-                if len(payload) <= self.index_in_manifest_threshold:
-                    # small index rides in the manifest entry: zero extra
-                    # opens per file per scan (reference in-manifest-threshold)
-                    embedded = payload
-                else:
-                    self.file_io.write_bytes(index_path(path), payload, overwrite=True)
-                    extra.append(name + ".index")
-        value_stats = collect_stats(kv.data)
-        key_stats = {k: value_stats[k] for k in self.key_names}
-        delete_rows = int(np.isin(kv.kind, (int(RowKind.DELETE),)).sum())
+                    hashes = key_hashes(kv.data, self.key_names)
+                payload = build_index_payload(
+                    kv.data, self.bloom_columns, self.bloom_fpp,
+                    key_hashes=hashes, key_fpp=self.key_bloom_fpp,
+                )
+                if payload is not None:
+                    if len(payload) <= self.index_in_manifest_threshold:
+                        # small index rides in the manifest entry: zero extra
+                        # opens per file per scan (reference in-manifest-threshold)
+                        embedded = payload
+                    else:
+                        self.file_io.write_bytes(index_path(path), payload, overwrite=True)
+                        extra.append(name + ".index")
+            value_stats = collect_stats(kv.data)
+            key_stats = {k: value_stats[k] for k in self.key_names}
+            min_key, max_key = self._key_min_max(kv.data, sorted_input) if self.keyed else ((), ())
+            delete_rows = int(np.isin(kv.kind, (int(RowKind.DELETE),)).sum())
+            min_seq, max_seq = int(kv.seq.min()), int(kv.seq.max())
         return DataFileMeta(
             file_name=name,
-            file_size=self.file_io.get_status(path).size,
+            file_size=file_size,
             row_count=kv.num_rows,
-            min_key=self._key_min_max(kv.data, sorted_input)[0] if self.keyed else (),
-            max_key=self._key_min_max(kv.data, sorted_input)[1] if self.keyed else (),
+            min_key=min_key,
+            max_key=max_key,
             key_stats=key_stats,
             value_stats=value_stats,
-            min_sequence_number=int(kv.seq.min()),
-            max_sequence_number=int(kv.seq.max()),
+            min_sequence_number=min_seq,
+            max_sequence_number=max_seq,
             schema_id=self.schema_id,
             level=level,
             delete_row_count=delete_rows,

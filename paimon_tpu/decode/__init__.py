@@ -67,7 +67,6 @@ def read_native(
     decoded. Chunks outside the envelope (PLAIN pages, a dictionary past
     pool_limit) expand exactly as before, per chunk."""
     metrics = decode_metrics()
-    t0 = time.perf_counter()
     cols = list(projection) if projection is not None else list(schema.field_names)
     read_schema = schema.project(cols)
     data = file_io.read_bytes(path)
@@ -120,7 +119,6 @@ def read_native(
             columns[f.name] = Column(values, validity)
         out.append(ColumnBatch(read_schema, columns))
     metrics.counter("files_native").inc()
-    metrics.histogram("file_ms").update((time.perf_counter() - t0) * 1000)
     return out
 
 

@@ -38,7 +38,7 @@ from __future__ import annotations
 from ..data.batch import ColumnBatch
 from ..decode.container import UnsupportedParquetFeature
 from ..fs import FileIO
-from ..metrics import encode_metrics, timed
+from ..metrics import encode_metrics, span
 from .writer import encode_parquet_bytes
 
 __all__ = ["write_native", "encode_parquet_bytes", "UnsupportedParquetFeature"]
@@ -66,9 +66,10 @@ def write_native(
     outside the native envelope — the caller falls back to arrow per file."""
     global _files_native_total
     metrics = encode_metrics()
-    with timed(metrics.histogram("encode_ms")):
+    with span("encode.format", rows=batch.num_rows):
         data = encode_parquet_bytes(batch, compression, format_options, metrics=metrics)
-    file_io.write_bytes(path, data)
+    with span("encode.put", rows=batch.num_rows, bytes=len(data)):
+        file_io.write_bytes(path, data)
     metrics.counter("files_native").inc()
     metrics.counter("bytes_written").inc(len(data))
     _files_native_total += 1

@@ -24,6 +24,7 @@ import numpy as np
 from ..data.batch import ColumnBatch
 from ..data.predicate import FieldStats, Predicate
 from ..fs import FileIO
+from ..metrics import span
 from ..types import RowType, TypeRoot
 
 __all__ = [
@@ -83,6 +84,43 @@ def get_format(identifier: str) -> FileFormat:
     if identifier not in _FORMATS:
         raise ValueError(f"unknown file format {identifier!r}; known: {sorted(_FORMATS)}")
     return _FORMATS[identifier]()
+
+
+# ---- the phases of a file's decode and encode (docs/tracing.md) ------------
+# A span a call into the format's library, never a column or a page: the
+# library decodes and encodes every column of a stripe or row group in one
+# C++ call.
+
+def library_call(call: str, columns: int, **stats) -> span:
+    """`decode.format`: one call into the library that reads the file (the
+    file opened and its footer parsed, a stripe or a run of row groups
+    decoded, or the native decoder over the whole file), counted in the
+    `parts` of the span open around the read (`decode.file`)."""
+    owner = span.current()
+    if owner is not None:
+        owner.add(parts=1)
+    return span("decode.format", call=call, columns=columns, **stats)
+
+
+def from_arrow(table, schema: RowType) -> ColumnBatch:
+    """`decode.convert`: a decoded arrow table made Columns."""
+    with span("decode.convert", rows=table.num_rows, columns=table.num_columns):
+        return ColumnBatch.from_arrow(table, schema)
+
+
+def to_arrow(batch: ColumnBatch):
+    """`encode.arrow`: the Columns made an arrow table for the library."""
+    with span("encode.arrow", rows=batch.num_rows):
+        return batch.to_arrow()
+
+
+def put(file_io: FileIO, path: str, buf, rows: int) -> None:
+    """`encode.put`: the encoded file out of its buffer and onto the file
+    system."""
+    with span("encode.put", rows=rows) as sp:
+        data = buf.getvalue()
+        file_io.write_bytes(path, data)
+        sp.add(bytes=len(data))
 
 
 # ---- stats ---------------------------------------------------------------

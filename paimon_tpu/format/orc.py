@@ -15,8 +15,9 @@ from typing import Iterator, Sequence
 from ..data.batch import ColumnBatch
 from ..data.predicate import Predicate
 from ..fs import FileIO
+from ..metrics import span
 from ..types import RowType
-from . import FileFormat, register_format
+from . import FileFormat, from_arrow, library_call, put, register_format, to_arrow
 
 
 class OrcFormat(FileFormat):
@@ -34,12 +35,13 @@ class OrcFormat(FileFormat):
 
         import pyarrow.orc as po
 
-        table = batch.to_arrow()
+        table = to_arrow(batch)
         buf = _io.BytesIO()
         opts = format_options or {}
         stripe_size = int(opts.get("orc.stripe.size", opts.get("file.block-size", 64 << 20)))
-        po.write_table(table, buf, compression=compression, stripe_size=stripe_size)
-        file_io.write_bytes(path, buf.getvalue())
+        with span("encode.format", rows=batch.num_rows):
+            po.write_table(table, buf, compression=compression, stripe_size=stripe_size)
+        put(file_io, path, buf, batch.num_rows)
 
     def read(
         self,
@@ -68,17 +70,20 @@ class OrcFormat(FileFormat):
                 except Exception:  # malformed/foreign tail: read everything
                     tail = None
                 f.seek(0)
-            of = po.ORCFile(lp if lp is not None else f)
+            with library_call("open", len(cols), rows=0):
+                of = po.ORCFile(lp if lp is not None else f)
             for stripe in range(of.nstripes):
                 if tail is not None and stripe < tail.nstripes:
                     if not predicate.test_stats(tail.stripe_stats(stripe)):
                         registry.group("scan").counter("orc_stripes_skipped").inc()
                         continue
-                table = of.read_stripe(stripe, columns=cols)
-                if isinstance(table, pa.RecordBatch):
-                    table = pa.Table.from_batches([table])
+                with library_call("stripe", len(cols)) as sp:
+                    table = of.read_stripe(stripe, columns=cols)
+                    if isinstance(table, pa.RecordBatch):
+                        table = pa.Table.from_batches([table])
+                    sp.add(rows=table.num_rows)
                 if table.num_rows:
-                    yield ColumnBatch.from_arrow(table, read_schema)
+                    yield from_arrow(table, read_schema)
         finally:
             f.close()
 

@@ -36,8 +36,9 @@ import numpy as np
 from ..data.batch import ColumnBatch
 from ..data.predicate import FieldStats, Predicate
 from ..fs import FileIO
+from ..metrics import span
 from ..types import RowType, TypeRoot
-from . import FileFormat, register_format
+from . import FileFormat, from_arrow, library_call, put, register_format, to_arrow
 
 _OBJ_DTYPE = np.dtype(object)
 _STRING_ROOTS = (TypeRoot.CHAR, TypeRoot.VARCHAR, TypeRoot.BINARY, TypeRoot.VARBINARY)
@@ -109,7 +110,7 @@ class ParquetFormat(FileFormat):
 
                 encode_metrics().counter("files_fallback").inc()
 
-        table = batch.to_arrow()
+        table = to_arrow(batch)
         buf = _io.BytesIO()
         opts = format_options or {}
         kw = {}
@@ -129,8 +130,9 @@ class ParquetFormat(FileFormat):
             kw["data_page_version"] = str(opts["parquet.data-page-version"])
         if compression == "zstd" and "file.compression.zstd-level" in opts:
             kw["compression_level"] = int(opts["file.compression.zstd-level"])
-        pq.write_table(table, buf, compression=compression, **kw)
-        file_io.write_bytes(path, buf.getvalue())
+        with span("encode.format", rows=batch.num_rows):
+            pq.write_table(table, buf, compression=compression, **kw)
+        put(file_io, path, buf, batch.num_rows)
 
     def read(
         self,
@@ -145,7 +147,9 @@ class ParquetFormat(FileFormat):
         cols = list(projection) if projection is not None else schema.field_names
         read_schema = schema.project(cols)
         if self.decoder == "native":
-            batches = self._read_native(file_io, path, schema, cols, predicate)
+            with library_call("native", len(cols)) as sp:
+                batches = self._read_native(file_io, path, schema, cols, predicate)
+                sp.add(rows=sum(b.num_rows for b in batches or ()))
             if batches is not None:
                 # fully materialized before the first yield, so an
                 # unsupported feature can still fall back without
@@ -170,17 +174,18 @@ class ParquetFormat(FileFormat):
                 n for n in cols if read_schema.field(n).type.root in _STRING_ROOTS
             ]
         try:
-            try:
-                pf = pq.ParquetFile(f, memory_map=True, **kw)
-            except (KeyError, OSError, ValueError):
-                if not kw:
-                    raise
-                # a requested dictionary column isn't a plain leaf in this
-                # file (e.g. a collect aggregate stored the STRING field as
-                # a list) — read it expanded like before
-                kw = {}
-                pf = pq.ParquetFile(f, memory_map=True)
-            md = pf.metadata
+            with library_call("open", len(cols), rows=0):
+                try:
+                    pf = pq.ParquetFile(f, memory_map=True, **kw)
+                except (KeyError, OSError, ValueError):
+                    if not kw:
+                        raise
+                    # a requested dictionary column isn't a plain leaf in this
+                    # file (e.g. a collect aggregate stored the STRING field as
+                    # a list) — read it expanded like before
+                    kw = {}
+                    pf = pq.ParquetFile(f, memory_map=True)
+                md = pf.metadata
             name_to_idx = {md.schema.column(i).name: i for i in range(md.num_columns)}
             keep = [
                 rg
@@ -205,9 +210,11 @@ class ParquetFormat(FileFormat):
                     spent += md.row_group(keep[i]).total_byte_size
                     chunk.append(keep[i])
                     i += 1
-                table = pf.read_row_groups(chunk, columns=cols)
+                with library_call("row_groups", len(cols)) as sp:
+                    table = pf.read_row_groups(chunk, columns=cols)
+                    sp.add(rows=table.num_rows)
                 if table.num_rows:
-                    yield ColumnBatch.from_arrow(table, read_schema)
+                    yield from_arrow(table, read_schema)
         finally:
             if lp is None:
                 f.close()

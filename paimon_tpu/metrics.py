@@ -181,9 +181,10 @@ def decode_metrics() -> MetricGroup:
     paimon_tpu.decode). Canonical members — counters: pages_decoded,
     pages_skipped (dead under compressed-domain pushdown, never expanded),
     bytes_expanded (materialized value bytes), rows_pruned, files_native,
-    files_fallback (fell back to the arrow decoder); histograms: file_ms
-    (whole-file native decode wall millis), pushdown_ms (per row group).
-    Resolved per call so registry.reset() in tests swaps the group out."""
+    files_fallback (fell back to the arrow decoder); histogram: pushdown_ms
+    (per row group). The whole file's native decode is the span
+    `decode.format` (call "native"), in a trace. Resolved per call so
+    registry.reset() in tests swaps the group out."""
     return registry.group("decode")
 
 
@@ -208,9 +209,10 @@ def encode_metrics() -> MetricGroup:
     members — counters: pages_written (data pages), bytes_written (file
     bytes produced natively), dict_pages (dictionary pages emitted),
     files_native, files_fallback (fell back to the arrow writer on an
-    unsupported shape); histograms: encode_ms (whole-file native encode
-    wall millis), stats_ms (chunk min/max statistics portion). Resolved per
-    call so registry.reset() in tests swaps the group out."""
+    unsupported shape); histogram: stats_ms (chunk min/max statistics
+    portion). The whole file's native encode is the span `encode.format`,
+    in a trace. Resolved per call so registry.reset() in tests swaps the
+    group out."""
     return registry.group("encode")
 
 

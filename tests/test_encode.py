@@ -629,7 +629,6 @@ def test_encode_metric_group_members(tmp_path, rng):
     assert g.counter("pages_written").count > before["pages_written"]
     assert g.counter("bytes_written").count > before["bytes_written"]
     assert g.counter("dict_pages").count > before["dict_pages"]
-    assert g.histogram("encode_ms").count > 0
     assert g.histogram("stats_ms").count > 0
 
 

@@ -222,6 +222,24 @@ def test_files_write_spans_the_fan_out_and_the_wait_on_the_callers_thread(traced
     assert single[3] == pool[3] and single[4]["rows"] == ROWS and "parent" not in single[4]
 
 
+@pytest.mark.parametrize("sorted_input", [True, False], ids=["key-order", "event-order"])
+def test_the_key_range_of_a_file_is_found_once(tmp_path, monkeypatch, sorted_input):
+    calls = []
+    real = KeyValueFileWriterFactory._key_min_max
+
+    def counted(self, batch, sorted_input):
+        calls.append(batch.num_rows)
+        return real(self, batch, sorted_input)
+
+    monkeypatch.setattr(KeyValueFileWriterFactory, "_key_min_max", counted)
+    kv = _batch(sorted_input=sorted_input)
+    metas = _factory(tmp_path, parallelism=1).write(kv, level=0, sorted_input=sorted_input)
+    assert calls == [m.row_count for m in metas] == [1_100] * 5 + [500]  # once a file, not once an end
+    ids = kv.data.column("id").values
+    bounds = [(int(part.min()), int(part.max())) for part in np.split(ids, np.cumsum(calls)[:-1])]
+    assert [(m.min_key, m.max_key) for m in metas] == [((lo,), (hi,)) for lo, hi in bounds]
+
+
 # ---- collect_stats over arrow-backed columns ------------------------------------------------------------
 
 LONG = "k" * 15  # with one more character: longer than the truncation length of 16

@@ -263,6 +263,177 @@ def test_carried_hands_operation_and_span_name_to_another_thread():
     assert seen[2] == (0, (0, "child")) and seen[3] == (0, "", None)
 
 
+# ---- inside a data file: the phases of a decode and of an encode ---------------
+
+DECODERS = {"orc": {"file.format": "orc"}, "parquet": {}, "parquet-native": {"format.parquet.decoder": "native"}}
+
+
+@pytest.fixture(scope="module")
+def decodes(tmp_path_factory):
+    """The read's table as ORC, as parquet and through the native parquet
+    decoder: each read cold once without a session and once traced, in one
+    session."""
+    from paimon_tpu.utils.cache import clear_all
+
+    tmp = tmp_path_factory.mktemp("decodes")
+    tables = {name: _table(tmp / name, **options) for name, options in DECODERS.items()}
+    untraced, traced_out = {}, {}
+    for name, table in tables.items():
+        clear_all()
+        untraced[name] = _columns(_read(table))
+    with traced(tmp / "trace") as events:
+        for name, table in tables.items():
+            clear_all()
+            traced_out[name] = _columns(_read(table))
+    reads = sorted(_named(events, "read_all"), key=lambda e: e[1])  # one a table, in the order read
+    return {"events": events, "untraced": untraced, "traced": traced_out,
+            "op": {name: r[4]["op"] for name, r in zip(tables, reads)}}
+
+
+def _children(events, outer, prefix):
+    """The spans named `prefix`* that lie inside `outer` on its thread."""
+    return [e for e in events if e is not outer and e[0].startswith(prefix) and _inside(e, outer)]
+
+
+@pytest.mark.parametrize("decoder", sorted(DECODERS))
+def test_a_decoded_file_opens_its_phases_inside_decode_file(decodes, decoder):
+    files = [f for f in _named(decodes["events"], "decode.file") if f[4]["op"] == decodes["op"][decoder]]
+    # a key pass and a value pass over each run's file
+    assert len(files) == 2 * RUNS and {f[4]["format"] for f in files} == {decoder.split("-")[0]}
+    for f in files:
+        phases = _children(decodes["events"], f, "decode.")
+        assert {p[0] for p in phases} == ({"decode.format"} if "native" in decoder else {"decode.format", "decode.convert"})
+        for p in phases:  # the file's own thread, operation and span
+            assert p[3] == f[3] and p[4]["op"] == f[4]["op"] > 0 and p[4]["parent"] == "decode.file"
+            assert p[4]["columns"] >= f[4]["columns"]  # the file's columns: the read's, and seq and kind where read
+        calls = [p for p in phases if p[0] == "decode.format"]
+        assert f[4]["parts"] == len(calls)
+        assert [c[4]["call"] for c in calls] == (["native"] if "native" in decoder else
+                                                 ["open"] + [{"orc": "stripe"}.get(decoder, "row_groups")] * (len(calls) - 1))
+        assert sum(c[4]["rows"] for c in calls) == f[4]["rows"] == ROWS_A_RUN
+        converted = [p for p in phases if p[0] == "decode.convert"]
+        assert sum(p[4]["rows"] for p in converted) == (0 if "native" in decoder else f[4]["rows"])
+
+
+@pytest.mark.parametrize("decoder", sorted(DECODERS))
+def test_without_a_session_a_decode_gives_the_same_output(decodes, decoder):
+    assert decodes["untraced"][decoder] == decodes["traced"][decoder]
+    assert decodes["untraced"][decoder] == decodes["untraced"]["parquet"]
+
+
+ENCODERS = {"orc": ("orc", {}), "parquet": ("parquet", {}),
+            "parquet-native": ("parquet", {"format.parquet.encoder": "native"})}
+WRITE_SCHEMA = pt.RowType.of(("id", pt.BIGINT(False)), ("v", pt.DOUBLE()), ("s", pt.STRING()))
+WRITE_ROWS = 6_000
+
+
+def _writer(directory, encoder, rows_a_file):
+    from paimon_tpu.core.datafile import KeyValueFileWriterFactory
+    from paimon_tpu.fs import LocalFileIO
+
+    file_format, options = ENCODERS[encoder]
+    return KeyValueFileWriterFactory(LocalFileIO(), str(directory), WRITE_SCHEMA, ["id"], 0, file_format=file_format,
+                                     target_file_size=32 * rows_a_file, format_options=options)
+
+
+def _written(directory, encoder, metas):
+    from paimon_tpu.core.datafile import KeyValueFileReaderFactory
+    from paimon_tpu.fs import LocalFileIO
+
+    reader = KeyValueFileReaderFactory(LocalFileIO(), str(directory), WRITE_SCHEMA, {0: WRITE_SCHEMA},
+                                       ENCODERS[encoder][0])
+    return [(m.row_count, m.min_key, m.max_key, m.key_stats, m.value_stats, m.min_sequence_number,
+             m.max_sequence_number, m.delete_row_count, reader.read(m).data.to_pylist()) for m in metas]
+
+
+@pytest.fixture(scope="module")
+def encodes(tmp_path_factory):
+    """A batch written through each encoder as six files on the shared pool
+    (a `files.write`) and as one file on the caller's thread: once without a
+    session, once traced."""
+    from paimon_tpu.core.kv import KVBatch
+    from paimon_tpu.data.batch import ColumnBatch
+
+    tmp = tmp_path_factory.mktemp("encodes")
+    ids = np.arange(WRITE_ROWS, dtype=np.int64) * 2
+    kv = KVBatch.from_rows(ColumnBatch.from_pydict(WRITE_SCHEMA, {
+        "id": ids, "v": ids * 0.5, "s": np.array([None if i % 9 == 0 else f"s{i % 13}" for i in ids], dtype=object)}), 7)
+    out = {}
+    for mode in ("untraced", "traced"):
+        session = traced(tmp / "trace") if mode == "traced" else contextlib.nullcontext([])
+        with session as events:
+            for encoder in ENCODERS:
+                for files in (6, 1):
+                    directory = tmp / mode / encoder / str(files)
+                    with span("compact", new_op=True) as caller:
+                        metas = _writer(directory, encoder, -(-WRITE_ROWS // files)).write(kv, level=1)
+                    out[mode, encoder, files] = _written(directory, encoder, metas)
+                    out["op", encoder, files] = caller.op
+        out[mode] = events
+    return out
+
+
+@pytest.mark.parametrize("encoder", sorted(ENCODERS))
+def test_a_written_file_opens_the_four_encode_phases_inside_file_write(encodes, encoder):
+    events = encodes["traced"]
+    fans = _named(events, "files.write")
+    for files in (6, 1):
+        mine = [w for w in _named(events, "file.write") if w[4]["op"] == encodes["op", encoder, files]]
+        assert len(mine) == files and {w[4]["format"] for w in mine} == {ENCODERS[encoder][0]}
+        for w in mine:
+            phases = _children(events, w, "encode.")
+            counts = {n: sum(p[0] == n for p in phases) for n in ("encode.arrow", "encode.format", "encode.put", "encode.stats")}
+            # the file's disk batch and, where arrow encodes it, its arrow table; the buffer out, then the file's size
+            assert counts == {"encode.arrow": 1 if "native" in encoder else 2, "encode.format": 1, "encode.put": 2,
+                              "encode.stats": 1}
+            assert {p[0] for p in phases} == set(counts)
+            for p in phases:  # on the file's own thread, with its operation, named for the file
+                assert p[3] == w[3] and p[4]["op"] == w[4]["op"] > 0 and p[4]["parent"] == "file.write"
+                assert p[4]["rows"] == w[4]["rows"]
+            assert sum(p[4].get("bytes", 0) for p in phases if p[0] == "encode.put") == w[4]["bytes"]
+            if files == 6:  # a pool thread under the caller's files.write
+                assert w[4]["parent"] == "files.write"
+                assert any(f[1] <= w[1] and w[2] <= f[2] and f[3] != w[3] for f in fans)
+            else:
+                assert w[4]["parent"] == "compact"
+
+
+def test_without_a_session_an_encode_gives_the_same_files(encodes):
+    assert encodes["untraced"] == []
+    for encoder in ENCODERS:
+        for files in (6, 1):
+            assert encodes["untraced", encoder, files] == encodes["traced", encoder, files]
+            assert len(encodes["traced", encoder, files]) == files
+
+
+@pytest.fixture(scope="module")
+def compaction(tmp_path_factory):
+    """A full compaction of the read's table (four overlapping runs), traced."""
+    from paimon_tpu.table.compactor import DedicatedCompactor
+    from paimon_tpu.utils.cache import clear_all
+
+    tmp = tmp_path_factory.mktemp("compaction")
+    table = _table(tmp / "warehouse")
+    before = _columns(_read(table))
+    clear_all()
+    with traced(tmp / "trace") as events:
+        assert DedicatedCompactor(table).run_once(full=True) is True
+    return {"events": events, "before": before, "after": _columns(_read(table))}
+
+
+def test_a_rewrite_joins_its_decoded_parts_inside_compact_read(compaction):
+    events = compaction["events"]
+    (read,), (join,) = _named(events, "compact.read"), _named(events, "compact.join")
+    assert _inside(join, read) and join[4]["parent"] == "compact.read" and join[4]["op"] == read[4]["op"]
+    assert (join[4]["rows"], join[4]["bytes"]) == (read[4]["rows"], read[4]["bytes"]) == (
+        RUNS * ROWS_A_RUN, sum(e[4]["bytes"] for e in _named(events, "decode.file")))
+    assert join[1] >= max(e[2] for e in _named(events, "decode.file"))  # after the last decode: the wait is before it
+    for f in _named(events, "file.write"):  # the round's output files are encoded in the same phases
+        assert {p[0] for p in _children(events, f, "encode.")} == {"encode.arrow", "encode.format", "encode.put",
+                                                                  "encode.stats"}
+    assert compaction["after"] == compaction["before"]
+
+
 # ---- the registry the readers lean on ---------------------------------------
 
 def test_histogram_keeps_lifetime_total_and_sum_beside_its_window():
