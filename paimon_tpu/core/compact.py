@@ -16,6 +16,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Sequence
 
+import numpy as np
+
 from ..metrics import compaction_metrics, span
 from ..options import CoreOptions
 from ..utils import now_millis
@@ -205,11 +207,11 @@ class MergeTreeCompactRewriter:
         pipe = SplitPipeline(parallelism, depth, stage="compact")
         read_section = lambda section: self._read_section(section, output_level)
         pending = None  # previous section's (merge handle, old_top)
-        for kv, old_top, seq_ascending in pipe.map_ordered(sections, read_section):
-            handle = self.merge.merge_async(kv, seq_ascending=seq_ascending)
+        for read in pipe.map_ordered(sections, read_section):
+            job = self._dispatch(read)
             if pending is not None:
                 self._write_section(pending, output_level, drop_delete, out, changelog)
-            pending = (handle, old_top)
+            pending = job
         if pending is not None:
             self._write_section(pending, output_level, drop_delete, out, changelog)
         return out, changelog
@@ -219,6 +221,8 @@ class MergeTreeCompactRewriter:
         of rewrite_complete and rewrite_pipelined)."""
         handle, old_top = job
         merged = self.merge.merge_resolve(handle)
+        if handle[0] != "parts":  # the whole-batch merge: every array of its winners taken from the join
+            compaction_metrics().counter("rows_gathered").inc(merged.num_rows * (len(merged.data.schema.fields) + 2))
         if drop_delete:
             merged = merged.drop_deletes()
         if self.emit_full_changelog and drop_delete:
@@ -230,9 +234,13 @@ class MergeTreeCompactRewriter:
         out.extend(self.writer_factory.write(merged, output_level, file_source="compact"))
 
     def _read_section(self, section: list[SortedRun], output_level: int):
-        """Read one section's runs in merge order: (concatenated KVBatch,
-        old top-level batches for the changelog diff, seq_ascending) — the
-        shared read head of every rewrite mode."""
+        """Read one section's runs in merge order: (joined KVBatch, per-file
+        parts or None, old top-level batches for the changelog diff,
+        seq_ascending) — the shared read head of every rewrite mode. Where
+        the merge picks its winners from the key columns, seq and kind alone
+        (MergeExecutor.supports_keys_only_pipeline), only those are joined and
+        the decoded parts are kept for the gather of the winners (_dispatch);
+        otherwise every column is joined and there are no parts."""
         from ..parallel.pipeline import bounded_map
         from .read import order_runs_for_merge
 
@@ -244,13 +252,30 @@ class MergeTreeCompactRewriter:
             # old serial loop); this is leaf work per the pool contract
             batches = bounded_map(self._read, files)
             decoded = sum(b.byte_size() for b in batches)
-            with span("compact.join", bytes=decoded) as join:
-                kv = KVBatch.concat(batches)
+            parts, joined, joined_bytes = None, batches, decoded
+            if self.merge.supports_keys_only_pipeline():
+                keys = self.merge.key_names
+                parts, joined = batches, [KVBatch(b.data.select(keys), b.seq, b.kind) for b in batches]
+                joined_bytes = sum(b.byte_size() for b in joined)
+            with span("compact.join", bytes=joined_bytes) as join:
+                kv = KVBatch.concat(joined)
                 join.add(rows=kv.num_rows)
             sp.add(rows=kv.num_rows, bytes=decoded)
         compaction_metrics().counter("bytes_in").inc(decoded)
         old_top = [b for f, b in zip(files, batches) if f.level == output_level]
-        return kv, old_top, seq_ascending
+        return kv, parts, old_top, seq_ascending
+
+    def _dispatch(self, read) -> tuple:
+        """Dispatch one read section's merge: the (merge handle, old_top) job
+        that _write_section resolves. A section with parts merges on its
+        joined keys and takes its winners from the parts
+        (MergeExecutor.merge_parts_async), counting compaction{rows_gathered,
+        rows_gathered_from_parts}; any other merges the joined batch."""
+        kv, parts, old_top, seq_ascending = read
+        if parts is None:
+            return self.merge.merge_async(kv, seq_ascending=seq_ascending), old_top
+        offsets = np.concatenate([[0], np.cumsum([p.num_rows for p in parts])])
+        return self.merge.merge_parts_async(kv, parts, offsets, seq_ascending, compaction_metrics()), old_top
 
     def rewrite_dispatch(self, sections: list[list[SortedRun]], output_level: int):
         """Phase 1: read every section's runs and dispatch their merges.
@@ -275,16 +300,10 @@ class MergeTreeCompactRewriter:
             lanes = ctx.feeder_lanes
             pipe = SplitPipeline(parallelism=lanes, depth=lanes, stage="compact")
             return [
-                (self.merge.merge_async(kv, seq_ascending=sa), old_top)
-                for kv, old_top, sa in pipe.map_ordered(
-                    sections, lambda s: self._read_section(s, output_level)
-                )
+                self._dispatch(read)
+                for read in pipe.map_ordered(sections, lambda s: self._read_section(s, output_level))
             ]
-        jobs = []
-        for section in sections:
-            kv, old_top, seq_ascending = self._read_section(section, output_level)
-            jobs.append((self.merge.merge_async(kv, seq_ascending=seq_ascending), old_top))
-        return jobs
+        return [self._dispatch(self._read_section(section, output_level)) for section in sections]
 
     def rewrite_complete(
         self, jobs, output_level: int, drop_delete: bool

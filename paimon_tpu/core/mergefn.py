@@ -15,9 +15,9 @@ from typing import Sequence
 
 import numpy as np
 
-from ..data.batch import Column, ColumnBatch
+from ..data.batch import BatchSink, Column, ColumnBatch, PartsTake
 from ..data.keys import build_string_pool, encode_key_lanes, split_int64_lanes
-from ..metrics import read_metrics, span
+from ..metrics import MetricGroup, read_metrics, span
 from ..options import CoreOptions, MergeEngine
 from ..ops import (
     AggregateSpec,
@@ -30,9 +30,73 @@ from ..ops import (
 )
 from ..ops.aggregates import _gather_column
 from ..types import RowKind, RowType, TypeRoot
-from .kv import KVBatch
+from .kv import KVBatch, retracts
 
-__all__ = ["MergeExecutor"]
+__all__ = ["MergeExecutor", "gather_winners"]
+
+
+def gather_winners(
+    kv_keys: KVBatch,
+    parts: Sequence[KVBatch],
+    offsets: Sequence[int],
+    take: np.ndarray,
+    schema: RowType,
+    counters: MetricGroup,
+    map_fn,
+    place: BatchSink | None = None,
+) -> KVBatch:
+    """The winners of a dedup merge that dispatched on keys alone, a column
+    a task of `map_fn` (the shared pool's ordered map). `kv_keys` is the
+    parts' key columns, seq and kind, joined in part order, the parts
+    starting at row `offsets` (len(parts) + 1 of them); `take` indexes it. A
+    value column of `schema` is taken straight from its per-file parts
+    (Column.take_from_parts: the parts are never concatenated); the key
+    columns and seq, which were joined for the lanes, are taken whole. Same
+    batch as the concatenation's take would give. `counters` gets
+    rows_gathered and rows_gathered_from_parts (read{...} for the read path,
+    compaction{...} for a rewrite).
+
+    kind goes first, on this thread: where no winner is a -D or -U, a
+    numpy-valued column of the schema is written into the rows that `place`
+    reserves (the read path's result; a rewrite gives none): its values are
+    then a view of that result, which an append leaves where they are."""
+    key_names = set(kv_keys.data.schema.field_names)
+    rows_out = len(take)
+    with span("gather", rows_in=kv_keys.num_rows, rows_out=rows_out, columns=len(schema.fields), parts=len(offsets) - 1):
+        plan = None
+        if parts:
+            with span("gather.plan", rows=rows_out, parts=len(parts)):
+                plan = PartsTake(offsets, take, map_fn)
+        with span("gather.column", column="_kind", rows_out=rows_out, parts=1):
+            kind = kv_keys.kind.take(take)  # raises for a take beyond the keys
+        dest = place.reserve(rows_out) if place is not None and not retracts(kind).any() else {}
+        # a task a column: a key column or seq whole, or a value column's per-file parts; only a column
+        # of the schema has a destination, whatever its name
+        tasks = [
+            (n, kv_keys.data.column(n) if n in key_names else [p.data.column(n) for p in parts], dest.get(n))
+            for n in schema.field_names
+        ]
+        tasks.append(("_seq", Column(kv_keys.seq), None))
+
+        def gather_column(task):
+            name, col, out = task
+            whole = not isinstance(col, list)
+            with span("gather.column", column=name, rows_out=rows_out, parts=1 if whole else len(col)):
+                if not whole:
+                    return Column.take_from_parts(col, plan, out)
+                plain = col._values is not None and col.dict_cache is None  # values and validity, nothing else to carry
+                if out is None or not plain or col._values.dtype != out.dtype:
+                    return col.take(take), False
+                # kind's take has checked the indices: numpy writes straight into `out` only
+                # under a mode that cannot raise halfway
+                values = np.take(col._values, take, out=out, mode="wrap")
+                return Column(values, None if col.validity is None else col.validity.take(take)), False
+
+        gathered = map_fn(gather_column, tasks)
+        *cols, seq = (col for col, _ in gathered)
+        counters.counter("rows_gathered").inc(rows_out * (len(tasks) + 1))
+        counters.counter("rows_gathered_from_parts").inc(rows_out * sum(from_parts for _, from_parts in gathered))
+    return KVBatch(ColumnBatch(schema, cols), seq.values, kind)
 
 
 def _numpy_dedup_select(
@@ -234,40 +298,15 @@ class MergeExecutor:
                 if kv.num_rows == 0:
                     return ("sync", kv)
         if self.engine == MergeEngine.DEDUPLICATE:
-            engine = self.effective_sort_engine()
-            if ctx is not None and engine != SortEngine.NUMPY:
-                lanes, plan = self._key_lanes(kv), None  # a round packs its shards itself
-            else:
-                lanes, plan = self._sort_lanes(kv, enable_ovc=engine != SortEngine.NUMPY)
-            if self._strictly_increasing(lanes):
-                # already key-sorted with unique keys (bulk loads, replayed
-                # sorted runs): dedup is the identity — skip the device trip
-                # (sequence lanes are never built on this path; packing keeps
-                # order and equality, so packed lanes answer as raw ones do)
+            select = self._dedup_dispatch(kv, seq_ascending)
+            if select is None:
                 return ("sync", kv)
-            seq_lanes = self._seq_lanes(kv, seq_ascending)
-            if engine == SortEngine.NUMPY:
-                return ("sync", kv.take(_numpy_dedup_select(lanes, seq_lanes, self._compress, plan)))
-            if ctx is not None:
-                # submit RAW lanes — compression is decided ONCE per family
-                # batch from stats reduced over every shard
-                # (ops.lanes.plan_lanes_global), so all shards of one
-                # shard_map agree on packed widths (ISSUE 7 fix)
-                from ..ops.lanes import resolve_compress
-
-                return (
-                    "dedup",
-                    ctx,
-                    ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress)),
-                    kv,
-                )
-            backend = "pallas" if engine == SortEngine.PALLAS else "xla"
-            from ..ops.merge import deduplicate_resolve, deduplicate_select_async
-
-            take = deduplicate_resolve(
-                deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress, plan=plan)
-            )
-            return ("sync", self.gather(kv, take))
+            tag, h = select
+            if tag == "numpy":
+                return ("sync", kv.take(h))
+            if tag == "mesh":
+                return ("dedup", *h, kv)
+            return ("sync", self.gather(kv, self.dedup_resolve(select)))
         lanes, seq_lanes = self._lanes(kv, seq_ascending)
         engine = self.effective_sort_engine()
         if ctx is not None and engine != SortEngine.NUMPY:
@@ -299,14 +338,86 @@ class MergeExecutor:
             ),
         )
 
+    def _dedup_dispatch(self, kv: KVBatch, seq_ascending: bool):
+        """The dedup merge's device half on whatever `kv` holds of the key
+        columns, seq and kind (the lanes read nothing else): None where the
+        keys already strictly increase, else a dedup_resolve handle: the
+        host engine's winners, a job of the mesh context, or the stream
+        tiles of deduplicate_select_async."""
+        from ..options import SortEngine
+        from ..parallel.mesh_exec import current_mesh_context
+
+        ctx = current_mesh_context()
+        engine = self.effective_sort_engine()
+        if ctx is not None and engine != SortEngine.NUMPY:
+            lanes, plan = self._key_lanes(kv), None  # a round packs its shards itself
+        else:
+            lanes, plan = self._sort_lanes(kv, enable_ovc=engine != SortEngine.NUMPY)
+        if self._strictly_increasing(lanes):
+            # already key-sorted with unique keys (bulk loads, replayed
+            # sorted runs): dedup is the identity — skip the device trip
+            # (sequence lanes are never built on this path; packing keeps
+            # order and equality, so packed lanes answer as raw ones do)
+            return None
+        seq_lanes = self._seq_lanes(kv, seq_ascending)
+        if engine == SortEngine.NUMPY:
+            return ("numpy", _numpy_dedup_select(lanes, seq_lanes, self._compress, plan))
+        if ctx is not None:
+            # submit RAW lanes — compression is decided ONCE per family
+            # batch from stats reduced over every shard
+            # (ops.lanes.plan_lanes_global), so all shards of one
+            # shard_map agree on packed widths (ISSUE 7 fix)
+            from ..ops.lanes import resolve_compress
+
+            return ("mesh", (ctx, ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress))))
+        backend = "pallas" if engine == SortEngine.PALLAS else "xla"
+        from ..ops.merge import deduplicate_select_async
+
+        return (
+            "single",
+            deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress, plan=plan),
+        )
+
+    def merge_parts_async(self, kv_keys: KVBatch, parts: Sequence[KVBatch], offsets: Sequence[int],
+                          seq_ascending: bool, counters: MetricGroup):
+        """merge_async for a dedup merge whose value columns stay in their
+        per-file parts (where supports_keys_only_pipeline()): the dispatch
+        runs on `kv_keys`, the parts' key columns, seq and kind joined at
+        row `offsets`, exactly as merge_async runs it on a whole batch (the
+        same lanes, sorted-keys shortcut, stream tiles resolved here, or job
+        of the mesh context); merge_resolve gathers the winners from the
+        parts (gather_winners, counting into `counters`), or, where the keys
+        already strictly increase, joins the parts whole."""
+        select = self._dedup_dispatch(kv_keys, seq_ascending) if kv_keys.num_rows else None
+        if select is not None and select[0] == "single":
+            # resolved here, as merge_async resolves it: the take in hand, as the host engine's is
+            select = ("numpy", self.dedup_resolve(select))
+        return ("parts", select, kv_keys, parts, offsets, counters)
+
     def merge_resolve(self, handle) -> KVBatch:
         tag = handle[0]
         if tag == "sync":
             return handle[1]
+        if tag == "parts":
+            return self._resolve_parts(*handle[1:])
         _, ctx, job_id, kv = handle
         if tag == "dedup":
             return self.gather(kv, ctx.result(job_id))
         return self._merge_with_plan(kv, ctx.result(job_id))
+
+    def _resolve_parts(self, select, kv_keys: KVBatch, parts, offsets, counters: MetricGroup) -> KVBatch:
+        from ..parallel.pipeline import bounded_map
+
+        arrays = len(self.value_schema.fields) + 2
+        if select is None:
+            # every row wins: the parts joined, as the whole-batch path hands them on
+            with span("gather", rows_in=kv_keys.num_rows, rows_out=kv_keys.num_rows, columns=arrays - 2,
+                      parts=len(parts)):
+                out = KVBatch.concat(parts)
+            counters.counter("rows_gathered").inc(out.num_rows * arrays)
+            return out
+        return gather_winners(kv_keys, parts, offsets, self.dedup_resolve(select), self.value_schema, counters,
+                              bounded_map)
 
     @staticmethod
     def gather(kv: KVBatch, take: np.ndarray) -> KVBatch:

@@ -13,13 +13,13 @@ from typing import Sequence
 
 import numpy as np
 
-from ..data.batch import BatchSink, Column, ColumnBatch, PartsTake, concat_batches
+from ..data.batch import BatchSink, ColumnBatch, concat_batches
 from ..data.predicate import Predicate, PredicateBuilder, and_
 from ..metrics import read_metrics, span
 from .datafile import DataFileMeta, KeyValueFileReaderFactory
-from .kv import KVBatch, retracts
+from .kv import KVBatch
 from .levels import IntervalPartition
-from .mergefn import MergeExecutor
+from .mergefn import MergeExecutor, gather_winners
 
 __all__ = ["MergeFileSplitRead", "order_runs_for_merge"]
 
@@ -225,7 +225,7 @@ class MergeFileSplitRead:
         columns, dispatch the dedup kernel (async), decode the value columns
         while the device sorts; the continuation returned resolves the
         select and gathers the winners from the per-file value columns,
-        into `place` where it is given one (_gather_winners).
+        into `place` where it is given one (mergefn.gather_winners).
         The two decode passes share the predicate, so their row sets are
         identical (datafile.read contract)."""
         key_names = [n for n in self.reader_factory.read_schema.field_names if n in self.key_names]
@@ -265,59 +265,11 @@ class MergeFileSplitRead:
                     ordered_files,
                     parallelism=self.parallelism,
                 )
-        return lambda place=None: self._gather_winners(kv_keys, tails, run_offsets, self.merge.dedup_resolve(handle), place)
-
-    def _gather_winners(
-        self, kv_keys: KVBatch, tails: list[KVBatch], run_offsets: list[int], take: np.ndarray, place: BatchSink | None = None
-    ) -> KVBatch:
-        """The winners of the keys-only pipeline, a column a task on the
-        shared pool. A value column is taken straight from its per-file parts
-        (Column.take_from_parts: the value pass is never concatenated); the
-        key columns and seq, which the key pass joined for the lanes, are
-        taken whole. Same batch as the concatenation's take would give.
-
-        kind goes first, on this thread: where no winner is a -D or -U, the
-        read's finish drops no row, and a numpy-valued column of the schema
-        is written into the rows that `place` reserves for this split: its
-        values are then a view of the operation's result, which an append
-        leaves where they are."""
-        schema = self.reader_factory.read_schema
-        rows_out = len(take)
-        with span("gather", rows_in=kv_keys.num_rows, rows_out=rows_out, columns=len(schema.fields), parts=len(run_offsets) - 1):
-            plan = None
-            if tails:
-                with span("gather.plan", rows=rows_out, parts=len(tails)):
-                    plan = PartsTake(run_offsets, take, lambda fn, items: _parallel_map(fn, items, self.parallelism))
-            with span("gather.column", column="_kind", rows_out=rows_out, parts=1):
-                kind = kv_keys.kind.take(take)  # raises for a take beyond the key pass
-            dest = place.reserve(rows_out) if place is not None and not retracts(kind).any() else {}
-            # a task a column: a key column or seq whole, or a value column's per-file parts; only a column
-            # of the schema has a destination, whatever its name
-            tasks = [
-                (n, kv_keys.data.column(n) if n in self.key_names else [t.data.column(n) for t in tails], dest.get(n))
-                for n in schema.field_names
-            ]
-            tasks.append(("_seq", Column(kv_keys.seq), None))
-
-            def gather_column(task):
-                name, col, out = task
-                whole = not isinstance(col, list)
-                with span("gather.column", column=name, rows_out=rows_out, parts=1 if whole else len(col)):
-                    if not whole:
-                        return Column.take_from_parts(col, plan, out)
-                    plain = col._values is not None and col.dict_cache is None  # values and validity, nothing else to carry
-                    if out is None or not plain or col._values.dtype != out.dtype:
-                        return col.take(take), False
-                    # kind's take has checked the indices: numpy writes straight into `out` only
-                    # under a mode that cannot raise halfway
-                    values = np.take(col._values, take, out=out, mode="wrap")
-                    return Column(values, None if col.validity is None else col.validity.take(take)), False
-
-            gathered = _parallel_map(gather_column, tasks, self.parallelism)
-            *cols, seq = (col for col, _ in gathered)
-            read_metrics().counter("rows_gathered").inc(rows_out * (len(tasks) + 1))
-            read_metrics().counter("rows_gathered_from_parts").inc(rows_out * sum(from_parts for _, from_parts in gathered))
-        return KVBatch(ColumnBatch(schema, cols), seq.values, kind)
+        pool_map = lambda fn, items: _parallel_map(fn, items, self.parallelism)  # noqa: E731
+        return lambda place=None: gather_winners(
+            kv_keys, tails, run_offsets, self.merge.dedup_resolve(handle), self.reader_factory.read_schema, read_metrics(),
+            pool_map, place,
+        )
 
     def read_kv(
         self, files: list[DataFileMeta], drop_delete: bool = False, deletion_vectors: dict | None = None

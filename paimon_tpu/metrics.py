@@ -271,7 +271,8 @@ def read_metrics() -> MetricGroup:
     taken from the per-file parts by Column.take_from_parts; not arrow-backed
     ones, whose chunks pyarrow's take joins inside, nor a column that fell
     back to the concatenation, nor the key columns, seq and kind). Both are
-    counted by the gather of the keys-only pipeline (core/read.py).
+    counted by the gather of the keys-only pipeline (core/mergefn.py
+    gather_winners, called by core/read.py with this group).
     Resolved per call so registry.reset() in tests swaps the group out."""
     return registry.group("read")
 
@@ -376,9 +377,12 @@ def compaction_metrics() -> MetricGroup:
     Canonical members — counters: rounds (trigger_compaction calls, whether
     or not the strategy picked a unit), rows_in / bytes_in / rows_out /
     files_out / bytes_out (rows of the files a rewrite read and their
-    decoded bytes as its read head joined them, and rows, count and sizes
+    decoded bytes as its read head took them in, and rows, count and sizes
     of the files it wrote: an upgrade moves a file between levels and counts
-    in none of them), compactions, files_rewritten (execution
+    in none of them), rows_gathered / rows_gathered_from_parts (a rewritten
+    section's winners x its arrays, the columns, seq and kind, and those of
+    them that a dedup rewrite's gather took from the per-file parts without
+    a join: core/mergefn.py gather_winners), compactions, files_rewritten (execution
     side, incremented per committed rewrite), adaptive_runs (buckets the
     adaptive scheduler compacted), deferred_buckets (buckets with pending
     sorted runs the policy deliberately left for later — cold or below

@@ -3,7 +3,7 @@
 The primitive must give exactly Column.concat(parts).take(take): values,
 validity, dtype, backing and, for code-backed columns, pool and codes. The
 read path uses it for the value columns of the keys-only pipeline
-(MergeFileSplitRead._gather_winners), where every engine and the serial
+(mergefn.gather_winners), where every engine and the serial
 map must give the batch that read_kv's whole-batch merge gives.
 """
 

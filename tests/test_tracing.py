@@ -425,13 +425,27 @@ def test_a_rewrite_joins_its_decoded_parts_inside_compact_read(compaction):
     events = compaction["events"]
     (read,), (join,) = _named(events, "compact.read"), _named(events, "compact.join")
     assert _inside(join, read) and join[4]["parent"] == "compact.read" and join[4]["op"] == read[4]["op"]
-    assert (join[4]["rows"], join[4]["bytes"]) == (read[4]["rows"], read[4]["bytes"]) == (
-        RUNS * ROWS_A_RUN, sum(e[4]["bytes"] for e in _named(events, "decode.file")))
+    assert join[4]["rows"] == read[4]["rows"] == RUNS * ROWS_A_RUN
+    assert read[4]["bytes"] == sum(e[4]["bytes"] for e in _named(events, "decode.file"))
+    # a dedup rewrite joins what the merge takes: the key column, seq and kind, 8 + 8 + 1 bytes a row
+    assert join[4]["bytes"] == 17 * RUNS * ROWS_A_RUN
     assert join[1] >= max(e[2] for e in _named(events, "decode.file"))  # after the last decode: the wait is before it
     for f in _named(events, "file.write"):  # the round's output files are encoded in the same phases
         assert {p[0] for p in _children(events, f, "encode.")} == {"encode.arrow", "encode.format", "encode.put",
                                                                   "encode.stats"}
     assert compaction["after"] == compaction["before"]
+
+
+def test_a_rewrite_gathers_its_winners_from_the_parts_a_column_on_the_pool(compaction):
+    events = compaction["events"]
+    (compact,), (gather,), (plan,) = _named(events, "compact"), _named(events, "gather"), _named(events, "gather.plan")
+    assert _inside(gather, compact) and _inside(plan, gather) and gather[4]["parent"] == "compact"
+    assert gather[4]["parts"] == RUNS and gather[4]["columns"] == 3 and gather[4]["rows_in"] == RUNS * ROWS_A_RUN
+    columns = _named(events, "gather.column")
+    assert {c[4]["column"]: c[4]["parts"] for c in columns} == {"id": 1, "v": RUNS, "s": RUNS, "_seq": 1, "_kind": 1}
+    assert {c[3] for c in columns} != {gather[3]}, "no column left the rewrite's thread"
+    for c in columns:
+        assert c[4]["op"] == gather[4]["op"] and c[4]["parent"] == "gather" and gather[1] <= c[1] and c[2] <= gather[2]
 
 
 # ---- the registry the readers lean on ---------------------------------------
