@@ -186,8 +186,8 @@ def sorted_segments(
 def segment_last_where(seg_id, masks, pos=None):
     """In-kernel: per SEGMENT, the last sorted position where each mask row
     is True (-1 = none). masks (F, m) bool in SORTED coords; returns (F, m)
-    indexed by segment id. The shared core of every partial-update selection
-    (local fused, local planned, and the distributed range-shuffle engine)."""
+    indexed by segment id. The shared core of the planned partial-update
+    selection and of the distributed range-shuffle engine's."""
     m = seg_id.shape[0]
     if pos is None:
         pos = jnp.arange(m, dtype=jnp.int32)
@@ -1220,129 +1220,51 @@ def _partial_update_fn():
     return f
 
 
-def _ascending_block_starts(key_lanes: np.ndarray, max_blocks: int = 257) -> list[int] | None:
-    """Host-side: split the input rows into maximal lexicographically
-    non-decreasing blocks (block = run analog). Any input admits such a
-    partition, so compact selection encodings work without plumbing run
-    offsets: within a block, one winner per key means winners ascend with
-    key. Returns None once more than max_blocks-1 boundaries are found
-    (caller falls back to the index download)."""
-    n, k = key_lanes.shape
-    if n <= 1:
-        return [0]
-    a, b = key_lanes[:-1], key_lanes[1:]
-    gt = np.zeros(n - 1, dtype=np.bool_)  # strict lex decrease at i -> i+1
-    eq = np.ones(n - 1, dtype=np.bool_)
-    for i in range(k):
-        gt |= eq & (a[:, i] > b[:, i])
-        eq &= a[:, i] == b[:, i]
-    cuts = np.flatnonzero(gt)
-    if len(cuts) + 1 >= max_blocks:
-        return None
-    return [0] + (cuts + 1).tolist()
-
-
-def _partial_update_select(perm, pad_sorted, seg_id, field_valid, is_add, is_delete):
-    """In-kernel shared core of BOTH fused partial-update kernels (compact
-    and index-download): per-field last-valid-add-after-last-delete winner
-    per segment, plus segment existence. Keeping it single-sourced means the
-    two download encodings can never diverge semantically."""
-    m = perm.shape[0]
-    pos = jnp.arange(m, dtype=jnp.int32)
-    add_sorted = is_add[perm]
-    del_sorted = is_delete[perm]
-    del_cand = jnp.where(del_sorted, pos, -1)
-    last_del = jax.ops.segment_max(del_cand, seg_id, num_segments=m)
-    gate = pos[None, :] > last_del[seg_id][None, :]
-    fv_sorted = field_valid[:, perm]
-    last_per_field = segment_last_where(seg_id, fv_sorted & add_sorted[None, :] & gate, pos)
-    src = jnp.where(last_per_field >= 0, perm[jnp.clip(last_per_field, 0, m - 1)], -1)  # (F, m)
-    add_cand = jnp.where(add_sorted, pos, -1)
-    last_add = jax.ops.segment_max(add_cand, seg_id, num_segments=m)
-    exists = last_add > last_del  # (m,) indexed by segment id
-    return src, exists
-
-
-@functools.lru_cache(maxsize=None)
-def _fused_partial_update_compact_fn(num_key: int, num_seq: int, num_fields: int, engine: str = "xla"):
-    """The fused partial-update kernel with compact downloads: instead of
-    the (F, k) int32 source matrix (the dominant device-to-host bytes of the
-    partial-update read), each field ships a
-    bit-packed winner mask over input rows, presence bits per segment, and
-    bit-packed block-ids of present winners; existence and keep-last ship
-    as bits + block-ids too. ~10x fewer bytes; exact reconstruction in
-    unpack_field_selection_compact."""
-
-    @_jit("fused_partial_update_compact")
-    def f(key_lanes, seq_lanes, pad_flag, field_valid, is_add, is_delete, starts):
-        m = pad_flag.shape[0]
-        pad_sorted, perm, _, keep_last, seg_id = sorted_segments(
-            num_key, num_seq, key_lanes, seq_lanes, pad_flag, engine=engine
-        )
-        src, exists = _partial_update_select(perm, pad_sorted, seg_id, field_valid, is_add, is_delete)
-        # ---- compact encodings --------------------------------------------
-        rbits = _runid_bits(starts.shape[0])
-        mask_last, runs_last, count = pack_selection_compact(
-            keep_last & (pad_sorted == 0), perm, starts
-        )
-        exists_bits = jnp.packbits(exists)
-        present = src >= 0  # (F, m) by segment id
-        present_bits = jax.vmap(jnp.packbits)(present)
-        src_cl = jnp.clip(src, 0, m - 1)
-        win_mask = jnp.zeros((num_fields, m), jnp.bool_)
-        win_mask = win_mask.at[jnp.arange(num_fields)[:, None], src_cl].max(present)
-        win_bits = jax.vmap(jnp.packbits)(win_mask)
-        blk = jnp.clip(
-            jnp.searchsorted(starts, src_cl.reshape(-1), side="right").astype(jnp.int32) - 1,
-            0,
-            starts.shape[0] - 1,
-        ).reshape(num_fields, m)
-
-        def pack_front(pr, bi):
-            _, packed = jax.lax.sort(
-                [(~pr).astype(jnp.uint32), bi.astype(jnp.uint32)], num_keys=1, is_stable=True
-            )
-            return packed
-
-        blk_front = jax.vmap(pack_front)(present, blk)  # (F, m) present blocks first
-        blk_bits = _bitpack_rows(blk_front, rbits)  # (F, m*rbits//8)
-        return win_bits, present_bits, blk_bits, exists_bits, mask_last, runs_last, count
-
-    return f
-
-
-def unpack_field_selection_compact(
-    win_bits_f, present_bits_f, blk_bits_f, kk: int, n: int, rbits: int
-) -> tuple[np.ndarray, np.ndarray]:
-    """Host half for ONE field: -> (present mask (kk,), winner input indices
-    for present segments, in segment order)."""
-    present = np.unpackbits(np.asarray(present_bits_f[: (kk + 7) // 8]), count=kk).astype(bool)
-    c = int(present.sum())
-    if c == 0:
-        return present, np.empty(0, dtype=np.int32)
-    winners = np.flatnonzero(
-        np.unpackbits(np.asarray(win_bits_f[: (n + 7) // 8]), count=n)
-    ).astype(np.int32)
-    vals = _interleave_winners(winners, _unpack_runids(blk_bits_f, c, rbits))
-    return present, vals
-
-
 @functools.lru_cache(maxsize=None)
 def _fused_partial_update_fn(num_key: int, num_seq: int, num_fields: int, engine: str = "xla"):
     """Sort + segment + partial-update selection in ONE kernel: the plan never
-    leaves the device, and the only downloads are the per-field source indices
-    (F, k), the per-key existence bits and the winning-row indices — instead
-    of 4 full plan arrays + per-field round trips. This is the fusion the
-    dedup engine got in round 1 (_dedup_select_fn), applied to partial-update."""
+    leaves the device. It returns sorted positions, each key's in key order:
+    per field the position of the key's winning row (-1 where the field
+    stays null), whether the key exists, the key's last row, and the sort's
+    permutation, which maps positions to input rows on the host
+    (fused_partial_update).
+
+    A per-key quantity is a running maximum of sorted positions read at the
+    key's last row (`ends`, packed to the front): the key's last row where a
+    mask holds, unless it reaches back before the key's first row. The chip
+    pays per index of a gather or a scatter, whatever it moves, so the masks
+    travel as bits of one word a row through the sort's permutation, the
+    running maxima are read at `ends` as rows of F + 3, and the winners
+    come down as positions, not as input rows (a gather a field and key);
+    no scatter, so no index sort."""
+    num_words = -(-(num_fields + 2) // 32)
 
     @_jit("fused_partial_update")
     def f(key_lanes, seq_lanes, pad_flag, field_valid, is_add, is_delete):
-        pad_sorted, perm, _, keep_last, seg_id = sorted_segments(
+        pad_sorted, perm, seg_start, keep_last, _ = sorted_segments(
             num_key, num_seq, key_lanes, seq_lanes, pad_flag, engine=engine
         )
-        src, exists = _partial_update_select(perm, pad_sorted, seg_id, field_valid, is_add, is_delete)
-        packed, count = pack_selected(keep_last & (pad_sorted == 0), perm)
-        return src, exists, packed, count
+        pos = jnp.arange(perm.shape[0], dtype=jnp.int32)
+        ends, count = pack_selected(keep_last & (pad_sorted == 0), pos)
+        # bit b of the words: field b's validity for b < F, then the add flag, then the delete flag
+        flags = [field_valid[i] for i in range(num_fields)] + [is_add, is_delete]
+        words = jnp.stack([
+            functools.reduce(jnp.bitwise_or, [
+                flags[b].astype(jnp.uint32) << (b % 32) for b in range(32 * w, min(32 * w + 32, len(flags)))])
+            for w in range(num_words)])[:, perm]
+
+        def bit(b):
+            return ((words[b // 32] >> (b % 32)) & 1).astype(jnp.bool_)
+
+        add, delete = bit(num_fields), bit(num_fields + 1)
+        masks = [bit(i) & add for i in range(num_fields)] + [add, delete]
+        last = jax.lax.cummax(jnp.stack([jnp.where(mk, pos, -1) for mk in masks] + [jnp.where(seg_start, pos, -1)]),
+                              axis=1)[:, ends]
+        last = jnp.where(last[:-1] >= last[-1], last[:-1], -1)  # the last row reads the key's first row
+        last_field, last_add, last_del = last[:num_fields], last[num_fields], last[num_fields + 1]
+        # a field's value is its last non-null add; an add before the key's last delete is void
+        src = jnp.where(last_field > last_del, last_field, -1)
+        return src, last_add > last_del, ends, perm, count
 
     return f
 
@@ -1359,13 +1281,15 @@ def fused_partial_update(
     """Single-call partial-update merge: returns (src (F, k), exists (k,),
     last_take (k,)) in key order — the same contract as
     merge_plan + partial_update_takes + keep-last takes, one device trip.
-    When the input decomposes into <=256 ascending-key blocks (always true
-    for real sections), downloads use the compact bit-packed encoding.
+    The selection comes down as it is, int32 sorted positions and the
+    sort's permutation, which maps them to input rows on the host: an
+    encoding that ships fewer bytes, or the map made on the chip, costs the
+    chip more time than the link or the host saves.
     Key lanes run through the compression seam (truncate + pack); an
     all-constant key sorts on sequence lanes alone (k=0 kernel). The host
     work up to the call, lanes packed and every operand padded, is
     `merge.dispatch` (the validity masks' padding inside it `pu.valid`); the
-    downloads `merge.resolve`; each field's selection decoded on the host
+    downloads `merge.resolve`; the map of positions to input rows
     `pu.unpack`."""
     from ..types import RowKind
 
@@ -1378,52 +1302,26 @@ def fused_partial_update(
         else:
             is_delete = np.zeros_like(is_add)
         with span("pu.valid", rows=n, fields=F):
-            fv = np.zeros((max(F, 1), m), dtype=np.bool_)
-            if F:
-                fv[:F, :n] = field_valid
+            fv = np.zeros((F, m), dtype=np.bool_)
+            fv[:, :n] = field_valid
         if engine == "pallas":
             from .pallas_kernels import note_dispatch
 
             note_dispatch(m)
-        starts_real = _ascending_block_starts(key_lanes) if F and _link_encodings_pay_off() else None
         merge_metrics().counter("merges").inc()
         operands = (klp, slp, pad, fv, pad_to(is_add, m, False), pad_to(is_delete, m, False))
-        if starts_real is not None:
-            starts_p = _pad_starts(starts_real, m)
-            _count_kernel(operands + (starts_p,), n, m)
-            outs = _fused_partial_update_compact_fn(k, s, fv.shape[0], engine)(*operands, starts_p)
-        else:
-            _count_kernel(operands, n, m)
-            outs = _fused_partial_update_fn(k, s, fv.shape[0], engine)(*operands)
-    if starts_real is not None:
-        rbits = _runid_bits(len(starts_p))
-        win_bits, present_bits, blk_bits, exists_bits, mask_last, runs_last, count = outs
-        with span("merge.resolve"):
-            kk = int(count)
-            last_take, nbytes = unpack_selection_compact(mask_last, runs_last, count, n, len(starts_real), rbits)
-            exists_np, exists_nbytes = _fetch(exists_bits, (kk + 7) // 8)
-            exists = np.unpackbits(exists_np, count=kk).astype(bool)
-            # one download per tensor (not per field): 3 link round-trips total
-            per = 8 // rbits
-            winb, win_nbytes = _fetch(win_bits, (n + 7) // 8)
-            prb, pr_nbytes = _fetch(present_bits, (kk + 7) // 8)
-            blb, bl_nbytes = _fetch(blk_bits, max(1, (kk + per - 1) // per))
-            _count_download(count.nbytes + nbytes + exists_nbytes + win_nbytes + pr_nbytes + bl_nbytes, kk)
-        with span("pu.unpack", rows_out=kk, fields=F, compact=1):
-            src_out = np.full((F, kk), -1, dtype=np.int32)
-            for f in range(F):
-                present, vals = unpack_field_selection_compact(winb[f], prb[f], blb[f], kk, n, rbits)
-                src_out[f, present] = vals
-        return src_out, exists, last_take
-    src, exists, packed, count = outs
+        _count_kernel(operands, n, m)
+        src, exists, ends, perm, count = _fused_partial_update_fn(k, s, F, engine)(*operands)
     with span("merge.resolve"):
         kk = int(count)
-        (src_np, src_nbytes), (exists_np, exists_nbytes), (packed_np, packed_nbytes) = (
-            _fetch(src, kk), _fetch(exists, kk), _fetch(packed, kk))
-        _count_download(count.nbytes + src_nbytes + exists_nbytes + packed_nbytes, kk)
-    with span("pu.unpack", rows_out=kk, fields=F, compact=0):
-        src_np = src_np[:F]  # the index download: each field's selection comes down as it is
-    return src_np, exists_np, packed_np
+        (src_np, src_nbytes), (exists_np, exists_nbytes), (ends_np, ends_nbytes), (perm_np, perm_nbytes) = (
+            _fetch(src, kk), _fetch(exists, kk), _fetch(ends, kk), _fetch(perm, n))
+        _count_download(count.nbytes + src_nbytes + exists_nbytes + ends_nbytes + perm_nbytes, kk)
+    with span("pu.unpack", rows_out=kk, fields=F):
+        rows = np.empty(n + 1, dtype=np.int32)  # input row at each sorted position; -1, a null, reads the last
+        rows[:n] = perm_np
+        rows[n] = -1
+        return rows[src_np], exists_np, rows[ends_np]
 
 
 def partial_update_takes(

@@ -623,10 +623,9 @@ def test_compact_selection_many_runs_fallback(rng):
     assert got.tolist() == _dedup_oracle(lanes).tolist()
 
 
-def test_fused_partial_update_compact_tiers(rng):
-    """Compact per-field downloads across block counts spanning all rbits
-    tiers (2/4/8-bit block ids), odd sizes, all-null fields, and the >256
-    block fallback — all must match the unfused plan oracle exactly."""
+def test_fused_partial_update_blocks(rng):
+    """Inputs of 3 to 300 ascending-key blocks, odd sizes, nearly and fully
+    null fields: the fused kernel must match the unfused plan oracle exactly."""
     from paimon_tpu.ops import merge as M
 
     for n, blocks in ((4000, 3), (6003, 12), (9001, 40), (4000, 300)):
@@ -638,8 +637,6 @@ def test_fused_partial_update_compact_tiers(rng):
         F = 3
         fv = rng.random((F, n)) < [[0.7], [0.05], [0.0]]  # incl. nearly/fully null fields
         kinds = np.zeros(n, dtype=np.uint8)
-        if blocks > 256:  # the fallback case must actually BE the fallback
-            assert M._ascending_block_starts(keys) is None
         src, exists, last = M.fused_partial_update(keys, None, fv, kinds)
         plan = M.merge_plan(keys, None)
         src_o, exists_o = M.partial_update_takes(plan, fv, kinds)
@@ -649,6 +646,100 @@ def test_fused_partial_update_compact_tiers(rng):
         assert src.tolist() == np.asarray(src_o).tolist(), (n, blocks)
 
 
+def _four_sources(rng, keys: int, blocks: int = 10):
+    """A wide table's merge input as four sources write it: 14 fields, each
+    block an ascending run of one source, the sources writing their fields
+    for 100/75/50/25% of the keys in turn; the row kinds mixed, a tenth of
+    them deletes."""
+    shares = (1.0, 0.75, 0.5, 0.25)
+    fields_of = (range(0, 6), range(6, 10), range(10, 14), range(0, 3))
+    runs, valid = [], []
+    for b in range(blocks):
+        source = b % 4
+        ids = np.sort(rng.choice(keys, int(shares[source] * keys), replace=False)).astype(np.uint32)
+        fv = np.zeros((14, len(ids)), dtype=np.bool_)
+        fv[list(fields_of[source])] = rng.random((len(fields_of[source]), len(ids))) < 0.9
+        runs.append(ids)
+        valid.append(fv)
+    key_lanes = np.concatenate(runs).reshape(-1, 1)
+    n = len(key_lanes)
+    kinds = rng.choice([int(k) for k in (RowKind.INSERT, RowKind.UPDATE_AFTER, RowKind.UPDATE_BEFORE, RowKind.DELETE)],
+                       size=n, p=[0.7, 0.15, 0.05, 0.1]).astype(np.uint8)
+    return key_lanes, np.arange(n, dtype=np.uint32).reshape(-1, 1), np.concatenate(valid, axis=1), kinds
+
+
+@pytest.mark.parametrize("remove_record_on_delete", [False, True])
+def test_fused_partial_update_at_the_wide_tables_width(rng, remove_record_on_delete):
+    from paimon_tpu.ops import merge as M
+
+    keys, seq, fv, kinds = _four_sources(rng, keys=3_000)
+    src, exists, last = M.fused_partial_update(keys, seq, fv, kinds, remove_record_on_delete)
+    plan = M.merge_plan(keys, seq)
+    src_o, exists_o = M.partial_update_takes(plan, fv, kinds, remove_record_on_delete)
+    assert last.tolist() == plan.perm[plan.keep_last & plan.valid_sorted].tolist()
+    assert exists.tolist() == np.asarray(exists_o).astype(bool).tolist()
+    assert src.tolist() == np.asarray(src_o).tolist()
+    assert (src >= 0).any(axis=1).all() and (src < 0).any()  # every field written, and nulls left
+    assert (~exists).any() == remove_record_on_delete  # every key is added more than once: only a delete drops one
+
+
+@pytest.mark.parametrize("fields", [0, 30, 31, 45])
+def test_fused_partial_update_field_counts(rng, fields):
+    """No field, and the field counts about the 32 flags (F fields, an add
+    and a delete flag) that fit one word a row."""
+    from paimon_tpu.ops import merge as M
+
+    n = 3_000
+    keys = np.sort(rng.integers(0, 800, size=(n, 1), dtype=np.uint32), axis=0)
+    fv = rng.random((fields, n)) < np.linspace(0.0, 0.9, fields)[:, None]
+    kinds = np.where(rng.random(n) < 0.1, int(RowKind.DELETE), int(RowKind.INSERT)).astype(np.uint8)
+    src, exists, last = M.fused_partial_update(keys, None, fv, kinds, remove_record_on_delete=True)
+    plan = M.merge_plan(keys, None)
+    src_o, exists_o = M.partial_update_takes(plan, fv, kinds, remove_record_on_delete=True)
+    assert last.tolist() == plan.perm[plan.keep_last & plan.valid_sorted].tolist()
+    assert exists.tolist() == np.asarray(exists_o).astype(bool).tolist()
+    assert src.shape == (fields, len(last)) and src.tolist() == np.asarray(src_o).tolist()
+
+
+def test_fused_partial_update_downloads_its_indices(rng, monkeypatch):
+    """One merge adds exactly the index download to merge{d2h_bytes}: each
+    field's winning position, the existence flags and each key's last
+    position, cut on the device to the step that holds the keys; the sort's
+    permutation, cut to the step that holds the rows; and the count."""
+    from paimon_tpu.metrics import registry
+    from paimon_tpu.ops import merge as M
+
+    assert M._link_encodings_pay_off()  # the suite forces the link encodings: they must not matter here
+    monkeypatch.setattr(M, "_FETCH_WHOLE_ELEMS", 1024)  # so that the download is cut on the device
+    keys, seq, fv, kinds = _four_sources(rng, keys=1_000, blocks=4)
+    n, F = len(keys), fv.shape[0]
+    registry.reset()
+    src, exists, last = M.fused_partial_update(keys, seq, fv, kinds)
+    counted = registry.snapshot()["merge"]
+    m = M.pad_size(n)
+    step = m // M._FETCH_STEPS
+    cut, rows_cut = (-(-c // step) * step for c in (len(last), n))
+    assert len(last) == 1_000 and cut < rows_cut < m
+    # int32 positions, a byte a flag; the count is an int64 (the package runs JAX in x64)
+    assert counted["d2h_bytes"] == F * cut * 4 + cut + cut * 4 + rows_cut * 4 + 8
+    assert counted["winners"] == 1_000 and src.shape == (F, 1_000) and exists.shape == last.shape == (1_000,)
+
+
+def test_the_fused_partial_update_program_holds_no_scatter_and_no_loop():
+    """The merge's own sort and the pack of the winners are its only sorts:
+    no per-field sort, scatter (an index sort on the chip) or loop, which a
+    per-field encoding of the selection would bring back."""
+    from paimon_tpu.ops import merge as M
+
+    m = 256
+    lowered = M._fused_partial_update_fn(1, 1, 14).lower(
+        [np.zeros(m, np.uint32)], [np.zeros(m, np.uint32)], np.zeros(m, np.uint8),
+        np.zeros((14, m), np.bool_), np.zeros(m, np.bool_), np.zeros(m, np.bool_))
+    text = lowered.as_text()
+    assert "stablehlo.scatter" not in text and "stablehlo.while" not in text
+    assert text.count("stablehlo.sort") == 2
+
+
 @pytest.mark.skipif(
     os.environ.get("PAIMON_TEST_PLATFORM", "cpu") != "cpu",
     reason="gate-off asserts the configured-cpu dispatch state",
@@ -656,9 +747,9 @@ def test_fused_partial_update_compact_tiers(rng):
 def test_dispatch_gate_off_wide_parity(rng, monkeypatch):
     """With the FORCE_COMPACT override removed, the configured-cpu platform
     makes the dispatcher skip every link encoding (no link bytes to save)
-    — and the wide path must return exactly the compact path's rows. This
-    pins the production CPU-fallback dispatch, which the suite otherwise
-    never exercises (conftest forces the device policy on)."""
+    — and the wide path must return exactly the oracle's rows. This pins
+    the production CPU-fallback dispatch, which the suite otherwise never
+    exercises (conftest forces the device policy on)."""
     from paimon_tpu.ops import merge as M
 
     monkeypatch.delenv("PAIMON_TPU_FORCE_COMPACT", raising=False)
@@ -667,17 +758,6 @@ def test_dispatch_gate_off_wide_parity(rng, monkeypatch):
     handle = M._dedup_dispatch(lanes, offsets, backend="xla")
     assert not (isinstance(handle, tuple) and handle[0] == "compact")
     assert M.deduplicate_resolve(handle).tolist() == _dedup_oracle(lanes).tolist()
-    # fused partial-update: gate-off (index download) == gate-on (compact)
-    keys = np.sort(rng.integers(0, 8_000, size=(8_000, 1), dtype=np.uint32), axis=0)
-    fv = rng.random((2, 8_000)) < 0.6
-    kinds = np.zeros(8_000, dtype=np.uint8)
-    src_off, exists_off, last_off = M.fused_partial_update(keys, None, fv, kinds)
-    monkeypatch.setenv("PAIMON_TPU_FORCE_COMPACT", "1")
-    assert M._link_encodings_pay_off()
-    src_on, exists_on, last_on = M.fused_partial_update(keys, None, fv, kinds)
-    assert src_off.tolist() == src_on.tolist()
-    assert exists_off.tolist() == exists_on.tolist()
-    assert last_off.tolist() == last_on.tolist()
 
 
 def test_delta_upload_pallas_and_many_runs(rng):

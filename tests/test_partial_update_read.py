@@ -4,7 +4,7 @@ latest non-null value written for it, and the value predicate is decided on
 the merged row. The system (`ReadBuilder.with_filter` -> `plan()` ->
 `read_all()`) against a plain numpy reference, under the host engine
 (`sort-engine` numpy: the planned merge) and the device engine (`xla-segmented`: the
-fused kernel, both of its download encodings); and the spans and counters
+fused kernel and its index download); and the spans and counters
 the partial-update path opens and counts (docs/tracing.md).
 """
 
@@ -21,7 +21,6 @@ from paimon_tpu.catalog import FileSystemCatalog
 from paimon_tpu.data.batch import Column, ColumnBatch
 from paimon_tpu.data.predicate import PredicateBuilder, and_
 from paimon_tpu.metrics import registry
-from paimon_tpu.ops import merge as M
 
 KEYS = 24_000
 SOURCES = (  # (share of the keys, the columns the source writes), committed in this order
@@ -132,19 +131,12 @@ def _assert_matches(out, want):
         assert np.array_equal(got[valid], want[name][0][valid]), name
 
 
-ENGINES = {  # case -> (table options, whether the fused kernel ships the compact encoding)
-    "numpy": ({"sort-engine": "numpy"}, None),
-    "xla-compact": ({"sort-engine": "xla-segmented"}, True),
-    "xla-index": ({"sort-engine": "xla-segmented"}, False),
-}
+ENGINES = {"numpy": {"sort-engine": "numpy"}, "xla": {"sort-engine": "xla-segmented"}}
 
 
 @pytest.mark.parametrize("case", sorted(ENGINES))
-def test_a_filtered_read_of_four_sources_merges_field_by_field(case, tmp_path, monkeypatch):
-    options, compact = ENGINES[case]
-    if compact is not None:  # the suite forces the link encodings on; the index download is the other branch
-        monkeypatch.setattr(M, "_link_encodings_pay_off", lambda: compact)
-    table, members = _table(tmp_path, seed=11, **options)
+def test_a_filtered_read_of_four_sources_merges_field_by_field(case, tmp_path):
+    table, members = _table(tmp_path, seed=11, **ENGINES[case])
     registry.reset()
     out = _read(table)
     want = _expected(members)
@@ -153,7 +145,7 @@ def test_a_filtered_read_of_four_sources_merges_field_by_field(case, tmp_path, m
     counted = registry.snapshot()["partial_update"]
     # every key of the files read merges, the filter comes after: 24,000 keys from 60,000 rows, 14 fields
     assert counted == {"merges": 1, "rows_in": ROWS, "rows_out": KEYS, "fields": len(NAMES) - 1}
-    if compact is not None:  # one call of the fused kernel over the whole section
+    if case == "xla":  # one call of the fused kernel over the whole section
         merged = registry.snapshot()["merge"]
         assert (merged["merges"], merged["rows_in"], merged["tiles"], merged["pad_rows"]) == (1, ROWS, 1, 65_536 - ROWS)
 
@@ -219,7 +211,7 @@ def test_the_partial_update_spans_carry_their_stats(traced_read):
     assert sorted(s["rows"] for s in by_name["pu.valid"]) == [ROWS, ROWS]
     assert all(s["fields"] == fields for s in by_name["pu.valid"])
     (unpack,), (gather,), (join,) = by_name["pu.unpack"], by_name["pu.gather"], by_name["read.join"]
-    assert unpack == {**unpack, "rows_out": KEYS, "fields": fields, "compact": 1}  # the suite forces the encodings
+    assert unpack == {**unpack, "rows_out": KEYS, "fields": fields} and "compact" not in unpack  # one download
     assert gather == {**gather, "rows_in": ROWS, "rows_out": KEYS, "fields": fields}
     assert join == {**join, "rows": ROWS, "columns": len(NAMES)}
     assert "concat" not in {e[0] for e in traced_read if e[4].get("parent") == "split" and e[4].get("rows") == ROWS}

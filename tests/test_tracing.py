@@ -598,8 +598,6 @@ PROGRAMS = [
     ("dedup_select_delta_wide", lambda: M._dedup_select_delta_wide_fn(), (_U16, _STARTS, _BASES, _U8)),
     ("dedup_select_batched", lambda: M._dedup_select_batched_fn(1), ((np.zeros((2, _M), np.uint32),), np.zeros((2, _M), np.uint8))),
     ("partial_update", lambda: M._partial_update_fn(), (_I32, _I32, _FV, _BOOL, _BOOL)),
-    ("fused_partial_update_compact", lambda: M._fused_partial_update_compact_fn(1, 0, 1),
-     ([_U32], [], _U8, _FV, _BOOL, _BOOL, _STARTS)),
     ("fused_partial_update", lambda: M._fused_partial_update_fn(1, 0, 1), ([_U32], [], _U8, _FV, _BOOL, _BOOL)),
 ]
 
