@@ -17,7 +17,7 @@ import numpy as np
 
 from ..data.batch import BatchSink, Column, ColumnBatch, PartsTake
 from ..data.keys import build_string_pool, encode_key_lanes, split_int64_lanes
-from ..metrics import MetricGroup, partial_update_metrics, read_metrics, span
+from ..metrics import MetricGroup, aggregate_metrics, partial_update_metrics, read_metrics, span
 from ..options import CoreOptions, MergeEngine
 from ..ops import (
     AggregateSpec,
@@ -28,7 +28,7 @@ from ..ops import (
     merge_plan,
     partial_update_takes,
 )
-from ..ops.aggregates import _gather_column
+from ..ops.aggregates import _gather_column, field_on
 from ..types import RowKind, RowType, TypeRoot
 from .kv import KVBatch, retracts
 
@@ -105,6 +105,15 @@ def _count_partial_update(rows_in: int, rows_out: int, fields: int) -> None:
     g.counter("rows_in").inc(rows_in)
     g.counter("rows_out").inc(rows_out)
     g.counter("fields").inc(fields)
+
+
+def _count_aggregate(rows_in: int, rows_out: int, fields: int, fields_on_host: int) -> None:
+    g = aggregate_metrics()
+    g.counter("merges").inc()
+    g.counter("rows_in").inc(rows_in)
+    g.counter("rows_out").inc(rows_out)
+    g.counter("fields").inc(fields)
+    g.counter("fields_on_host").inc(fields_on_host)
 
 
 def _numpy_dedup_select(
@@ -572,20 +581,27 @@ class MergeExecutor:
 
     def _aggregate_fused(self, kv: KVBatch, lanes, seq_lanes, fields, specs, cols_in) -> KVBatch:
         """Single-call aggregation: every column's segment reduction runs in
-        the same kernel as the sort."""
+        the same kernel as the sort. `agg.reduce` holds the call (its lane
+        packing, the kernel and the wait) and the fields taken from its
+        outputs, each an `agg.field`."""
         from ..ops.aggregates import fused_aggregate
 
-        agg_cols, last_take = fused_aggregate(
-            lanes, seq_lanes, cols_in, specs, kv.kind, compress=self._compress, engine=self._engine_str()
-        )
-        cols: dict[str, Column] = {}
-        for k in self.key_names:
-            cols[k] = kv.data.column(k).take(last_take)
-        for f, c in zip(fields, agg_cols):
-            cols[f.name] = c
-        data = ColumnBatch(self.value_schema, cols)
-        kind = np.full(len(last_take), int(RowKind.INSERT), dtype=np.uint8)
-        return KVBatch(data, kv.seq.take(last_take), kind)
+        with span("agg.reduce", rows_in=kv.num_rows, fields=len(fields), route="fused") as sp:
+            agg_cols, last_take = fused_aggregate(
+                lanes, seq_lanes, cols_in, specs, kv.kind, compress=self._compress, engine=self._engine_str(),
+                names=[f.name for f in fields],
+            )
+            cols: dict[str, Column] = {}
+            for k in self.key_names:
+                cols[k] = kv.data.column(k).take(last_take)
+            for f, c in zip(fields, agg_cols):
+                cols[f.name] = c
+            data = ColumnBatch(self.value_schema, cols)
+            kind = np.full(len(last_take), int(RowKind.INSERT), dtype=np.uint8)
+            out = KVBatch(data, kv.seq.take(last_take), kind)
+            sp.add(rows_out=out.num_rows)
+        _count_aggregate(kv.num_rows, out.num_rows, len(fields), 0)
+        return out
 
     def _partial_update(self, kv: KVBatch, plan, last_take, out_seq) -> KVBatch:
         remove_on_delete = self.options.options.get(CoreOptions.PARTIAL_UPDATE_REMOVE_RECORD_ON_DELETE)
@@ -701,13 +717,21 @@ class MergeExecutor:
         return AggregateSpec(fn, ignore_retract, delim, distinct, nested_key)
 
     def _aggregate(self, kv: KVBatch, plan, last_take, out_seq) -> KVBatch:
-        cols: dict[str, Column] = {}
-        for k in self.key_names:
-            cols[k] = kv.data.column(k).take(last_take)
-        for f in self.value_schema.fields:
-            if f.name in self.key_names:
-                continue
-            cols[f.name] = aggregate_merge(plan, kv.data.column(f.name), self._agg_spec(f.name), kv.kind)
-        data = ColumnBatch(self.value_schema, cols)
-        kind = np.full(len(last_take), int(RowKind.INSERT), dtype=np.uint8)
+        """The planned route: a plan in hand, each field reduced on its own
+        (`aggregate_merge`), an `agg.field` a field inside `agg.reduce`."""
+        fields = [f for f in self.value_schema.fields if f.name not in self.key_names]
+        on_host = 0
+        with span("agg.reduce", rows_in=kv.num_rows, rows_out=len(last_take), fields=len(fields), route="planned"):
+            cols: dict[str, Column] = {}
+            for k in self.key_names:
+                cols[k] = kv.data.column(k).take(last_take)
+            for f in fields:
+                col, spec = kv.data.column(f.name), self._agg_spec(f.name)
+                on = field_on(spec, col)
+                on_host += on == "host"
+                with span("agg.field", field=f.name, fn=spec.function, on=on):
+                    cols[f.name] = aggregate_merge(plan, col, spec, kv.kind)
+            data = ColumnBatch(self.value_schema, cols)
+            kind = np.full(len(last_take), int(RowKind.INSERT), dtype=np.uint8)
+        _count_aggregate(kv.num_rows, len(last_take), len(fields), on_host)
         return KVBatch(data, out_seq, kind)

@@ -27,6 +27,7 @@ __all__ = [
     "span",
     "carried",
     "timed",
+    "aggregate_metrics",
     "compaction_metrics",
     "datafile_metrics",
     "decode_metrics",
@@ -317,6 +318,19 @@ def partial_update_metrics() -> MetricGroup:
     fields merged field by field, summed over the merges). Resolved per call
     so registry.reset() in tests swaps the group out."""
     return registry.group("partial_update")
+
+
+def aggregate_metrics() -> MetricGroup:
+    """The aggregate{...} group (the aggregation merge engine,
+    paimon_tpu.core.mergefn, fused and planned routes alike: reads, flushes
+    and compactions). Canonical members, counters: merges (aggregation
+    merges run), rows_in (input rows merged), rows_out (rows the merges
+    returned: one a key), fields (value fields reduced, summed over the
+    merges), fields_on_host (those of them reduced on the host: f64
+    sum/max/min where the backend has no exact f64, product, listagg,
+    collect, merge_map, nested_update). Resolved per call so
+    registry.reset() in tests swaps the group out."""
+    return registry.group("aggregate")
 
 
 def mesh_metrics() -> MetricGroup:

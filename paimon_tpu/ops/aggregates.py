@@ -26,8 +26,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.batch import Column
+from ..metrics import span
 from ..types import RowKind
-from .merge import MergePlan, pad_to
+from .merge import MergePlan, _jit, pad_to
 
 __all__ = [
     "AggregateSpec",
@@ -70,7 +71,7 @@ class AggregateSpec:
 
 @functools.lru_cache(maxsize=None)
 def _sum_fn():
-    @jax.jit
+    @_jit("agg_sum")
     def f(perm, seg_id, values, valid, sign):
         m = perm.shape[0]
         v = values[perm]
@@ -86,7 +87,7 @@ def _sum_fn():
 
 @functools.lru_cache(maxsize=None)
 def _minmax_fn(is_max: bool):
-    @jax.jit
+    @_jit("agg_minmax")
     def f(perm, seg_id, values, valid):
         m = perm.shape[0]
         v = values[perm]
@@ -107,7 +108,7 @@ def _minmax_fn(is_max: bool):
 
 @functools.lru_cache(maxsize=None)
 def _pick_fn(last: bool):
-    @jax.jit
+    @_jit("agg_pick")
     def f(perm, seg_id, candidate):
         # candidate: (m,) bool in INPUT coords — rows eligible to be picked
         # (validity and/or retract-exclusion already folded in by the caller)
@@ -261,6 +262,21 @@ def _f64_on_device_unsupported() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def field_on(spec: AggregateSpec, column: Column) -> str:
+    """Where aggregate_merge reduces a field: "pick" (a first/last or
+    primary-key pick on the device, then the row taken on the host), "host"
+    (listagg, collect, merge_map, nested_update, product, and f64 sum/max/min
+    where the backend has no exact f64) or "device" (a segment reduction)."""
+    fn = spec.function
+    if fn in _PICK_FNS or fn == "primary-key":
+        return "pick"
+    if fn in ("listagg", "collect", "merge_map", "nested_update", "product"):
+        return "host"
+    if fn in ("max", "min", "sum") and column.values.dtype == np.float64 and _f64_on_device_unsupported():
+        return "host"
+    return "device"
+
+
 def fused_routable(specs: list[AggregateSpec], columns: list[Column]) -> bool:
     """True when every column can run inside the single fused kernel:
     numeric reductions and first/last picks. product stays host-exact,
@@ -313,7 +329,7 @@ def _fused_aggregate_fn(num_key: int, num_seq: int, col_fns: tuple[str, ...], en
 
     from .merge import pack_selected, sorted_segments
 
-    @jax.jit
+    @_jit("fused_aggregate")
     def f(key_lanes, seq_lanes, pad_flag, values, valids, signs):
         m = pad_flag.shape[0]
         pad_sorted, perm, _, keep_last, seg_id = sorted_segments(
@@ -369,11 +385,13 @@ def fused_aggregate(
     row_kind: np.ndarray,
     compress: bool | None = None,
     engine: str = "xla",
+    names: list[str] | None = None,
 ) -> tuple[list[Column], np.ndarray]:
     """Single-call aggregation merge over every value column. Returns
     (aggregated columns in key order, last_take winning-row indices). Key
     lanes run through the compression seam (ops/lanes.py) — identical
-    segmentation, fewer sort operands."""
+    segmentation, fewer sort operands. Each column's result is taken from
+    the kernel's outputs under an `agg.field` span (`names` name them)."""
     from .merge import prepare_lanes_planned
 
     klp, slp, pad, n, k, s, m, _plan = prepare_lanes_planned(key_lanes, seq_lanes, compress=compress)
@@ -417,17 +435,19 @@ def fused_aggregate(
     )
     kk = int(count)
     result: list[Column] = []
-    for spec, col, fn, o, av in zip(specs, columns, col_fns, outs, anyv):
-        if fn.startswith("pick_"):
-            result.append(_gather_column(col, np.asarray(o[:kk])))
-        elif fn == "count":
-            result.append(Column(np.asarray(o[:kk])))  # count of nothing is 0
-        else:
-            vals = np.asarray(o[:kk]).astype(col.values.dtype, copy=False)
-            valid = np.asarray(av[:kk])
-            if fn in ("bool_and", "bool_or"):
-                vals = vals.astype(np.bool_)
-            result.append(Column(vals, valid if not valid.all() else None))
+    for i, (spec, col, fn, o, av) in enumerate(zip(specs, columns, col_fns, outs, anyv)):
+        with span("agg.field", field=names[i] if names else str(i), fn=spec.function,
+                  on="pick" if fn.startswith("pick_") else "device"):
+            if fn.startswith("pick_"):
+                result.append(_gather_column(col, np.asarray(o[:kk])))
+            elif fn == "count":
+                result.append(Column(np.asarray(o[:kk])))  # count of nothing is 0
+            else:
+                vals = np.asarray(o[:kk]).astype(col.values.dtype, copy=False)
+                valid = np.asarray(av[:kk])
+                if fn in ("bool_and", "bool_or"):
+                    vals = vals.astype(np.bool_)
+                result.append(Column(vals, valid if not valid.all() else None))
     return result, np.asarray(packed[:kk])
 
 
