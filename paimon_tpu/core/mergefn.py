@@ -28,7 +28,7 @@ from ..ops import (
     merge_plan,
     partial_update_takes,
 )
-from ..ops.aggregates import _gather_column, field_on
+from ..ops.aggregates import _gather_column, field_on, takes_scan
 from ..types import RowKind, RowType, TypeRoot
 from .kv import KVBatch, retracts
 
@@ -107,13 +107,14 @@ def _count_partial_update(rows_in: int, rows_out: int, fields: int) -> None:
     g.counter("fields").inc(fields)
 
 
-def _count_aggregate(rows_in: int, rows_out: int, fields: int, fields_on_host: int) -> None:
+def _count_aggregate(rows_in: int, rows_out: int, fields: int, fields_on_host: int, fields_by_scan: int) -> None:
     g = aggregate_metrics()
     g.counter("merges").inc()
     g.counter("rows_in").inc(rows_in)
     g.counter("rows_out").inc(rows_out)
     g.counter("fields").inc(fields)
     g.counter("fields_on_host").inc(fields_on_host)
+    g.counter("fields_by_scan").inc(fields_by_scan)
 
 
 def _numpy_dedup_select(
@@ -600,7 +601,7 @@ class MergeExecutor:
             kind = np.full(len(last_take), int(RowKind.INSERT), dtype=np.uint8)
             out = KVBatch(data, kv.seq.take(last_take), kind)
             sp.add(rows_out=out.num_rows)
-        _count_aggregate(kv.num_rows, out.num_rows, len(fields), 0)
+        _count_aggregate(kv.num_rows, out.num_rows, len(fields), 0, sum(map(takes_scan, specs, cols_in)))
         return out
 
     def _partial_update(self, kv: KVBatch, plan, last_take, out_seq) -> KVBatch:
@@ -635,10 +636,7 @@ class MergeExecutor:
         return out
 
     def _group_take(self, kv: KVBatch, seq_col: str, fields: Sequence[str]) -> dict[str, Column]:
-        from ..ops.aggregates import _pick_fn
-        from ..ops.merge import pad_to
-
-        import jax.numpy as jnp
+        from ..ops.aggregates import pick_rows
 
         key_lanes = self._key_lanes(kv)
         # order: (key, group seq, system seq); null group seq sorts first and
@@ -656,10 +654,7 @@ class MergeExecutor:
         seq_lanes = np.concatenate([g_lanes, np.stack([hi, lo], axis=1)], axis=1)
         gplan = merge_plan(key_lanes, seq_lanes, compress=self._compress, engine=self._engine_str())
         candidate = g_valid & np.isin(kv.kind, (int(RowKind.INSERT), int(RowKind.UPDATE_AFTER)))
-        src = _pick_fn(True)(
-            jnp.asarray(gplan.perm), jnp.asarray(gplan.seg_id), jnp.asarray(pad_to(candidate, gplan.m, False))
-        )
-        src = np.asarray(src)[: gplan.num_segments]
+        src = pick_rows(gplan, candidate)
         out = {}
         out[seq_col] = _gather_column(kv.data.column(seq_col), src)
         default_fn = self.options.options.get(CoreOptions.AGGREGATE_DEFAULT_FUNC)
@@ -720,7 +715,7 @@ class MergeExecutor:
         """The planned route: a plan in hand, each field reduced on its own
         (`aggregate_merge`), an `agg.field` a field inside `agg.reduce`."""
         fields = [f for f in self.value_schema.fields if f.name not in self.key_names]
-        on_host = 0
+        on_host = by_scan = 0
         with span("agg.reduce", rows_in=kv.num_rows, rows_out=len(last_take), fields=len(fields), route="planned"):
             cols: dict[str, Column] = {}
             for k in self.key_names:
@@ -729,9 +724,10 @@ class MergeExecutor:
                 col, spec = kv.data.column(f.name), self._agg_spec(f.name)
                 on = field_on(spec, col)
                 on_host += on == "host"
+                by_scan += takes_scan(spec, col)
                 with span("agg.field", field=f.name, fn=spec.function, on=on):
                     cols[f.name] = aggregate_merge(plan, col, spec, kv.kind)
             data = ColumnBatch(self.value_schema, cols)
             kind = np.full(len(last_take), int(RowKind.INSERT), dtype=np.uint8)
-        _count_aggregate(kv.num_rows, len(last_take), len(fields), on_host)
+        _count_aggregate(kv.num_rows, len(last_take), len(fields), on_host, by_scan)
         return KVBatch(data, out_seq, kind)

@@ -328,7 +328,9 @@ def aggregate_metrics() -> MetricGroup:
     returned: one a key), fields (value fields reduced, summed over the
     merges), fields_on_host (those of them reduced on the host: f64
     sum/max/min where the backend has no exact f64, product, listagg,
-    collect, merge_map, nested_update). Resolved per call so
+    collect, merge_map, nested_update), fields_by_scan (those of them
+    reduced by scans read at the segments' bounds, not a scatter: integer
+    sums, counts, first/last picks, primary-key). Resolved per call so
     registry.reset() in tests swaps the group out."""
     return registry.group("aggregate")
 

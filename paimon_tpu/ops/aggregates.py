@@ -6,10 +6,21 @@ FieldAggregator subclasses: sum, product, count, max, min, bool_and, bool_or,
 first_value, first_non_null_value, last_value, last_non_null_value, listagg,
 collect, merge_map, nested_update, primary-key, ignore-retract wrapper).
 
-Numeric/bool/min/max/count/sum run on device as jax segment reductions over
+Numeric/bool/min/max/count/sum run on device as segment reductions over
 the MergePlan's sorted order; first/last pick per-segment row indices (gather
 stays exact for any type, including strings); listagg/collect run host-side
 per segment (variable-length outputs cannot live on device anyway).
+
+How a segment is reduced on the device. The sorted order makes each segment
+a run of consecutive rows, so integer sums and counts, the validity bit and
+the picks read a scan over the sorted rows at each segment's bounds: an
+integer sum is the inclusive prefix sum at the segment's end less the one at
+the previous segment's end (exact in wrapping arithmetic: the bits of a
+scatter-add, modulo 2**width); the validity bit a prefix count; a last
+(first) pick a running max (reverse running min) of the candidates' sorted
+positions read at the end (start). Floating sums keep `segment_sum`, since a
+prefix difference in floating point cancels, and min/max keep
+`segment_max`/`segment_min` for their values: those scatter into `seg_id`.
 
 Retract rows (-U/-D): sum and count subtract; ignore-retract drops them for
 a field; everything else raises — the same contract as the reference
@@ -24,6 +35,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..data.batch import Column
 from ..metrics import span
@@ -69,18 +81,62 @@ class AggregateSpec:
     nested_key: tuple[str, ...] = ()  # nested_update: ARRAY<ROW> upsert key
 
 
+def _scans(dtype) -> bool:
+    """Whether a sum of `dtype` contributions takes the prefix difference:
+    an integer's wrapping arithmetic makes it exact (the bits a scatter-add
+    gives, modulo 2**width); a floating sum would cancel, so it keeps
+    `segment_sum`."""
+    return np.issubdtype(dtype, np.integer)
+
+
+def _segment_sum(contrib, ends, seg_id=None):
+    """In-kernel: per segment, the sum of `contrib` (sorted order), segment
+    i spanning the sorted rows (ends[i-1], ends[i]]. Integers: the inclusive
+    prefix sum read at each end less the one read at the end before (no
+    scatter, which the chip emulates for s64). Floats: `segment_sum` over
+    `seg_id`."""
+    if _scans(contrib.dtype):
+        at_end = jnp.cumsum(contrib, dtype=contrib.dtype)[ends]
+        return at_end - jnp.concatenate([jnp.zeros((1,), at_end.dtype), at_end[:-1]])
+    return jax.ops.segment_sum(contrib, seg_id, num_segments=ends.shape[0])
+
+
+def _any_valid(ok, ends):
+    """In-kernel: per segment, whether any of its rows is ok (a prefix count)."""
+    return _segment_sum(ok.astype(jnp.int32), ends) > 0
+
+
+def _pick(ok, ends, last: bool):
+    """In-kernel: per segment, the sorted position of its last (first) ok
+    row, -1 where none: a running max (a reverse running min) of the ok
+    rows' positions read at the segment's end (start)."""
+    m = ok.shape[0]
+    pos = jnp.arange(m, dtype=jnp.int32)
+    before = jnp.concatenate([jnp.full((1,), -1, ends.dtype), ends[:-1]])
+    if last:
+        best = lax.cummax(jnp.where(ok, pos, -1), axis=0)[ends]
+        return jnp.where(best > before, best, -1)
+    best = lax.cummin(jnp.where(ok, pos, m), axis=0, reverse=True)[before + 1]
+    return jnp.where(best <= ends, best, -1)
+
+
+def _segment_extreme(v, ok, seg_id, num_segments: int, is_max: bool):
+    """In-kernel: per segment, the max (min) of `v` over its ok rows, the
+    dtype's least (greatest) value where none."""
+    info = jnp.finfo(v.dtype) if jnp.issubdtype(v.dtype, jnp.floating) else jnp.iinfo(v.dtype)
+    masked = jnp.where(ok, v, info.min if is_max else info.max)
+    reduce = jax.ops.segment_max if is_max else jax.ops.segment_min
+    return reduce(masked, seg_id, num_segments=num_segments)
+
+
 @functools.lru_cache(maxsize=None)
 def _sum_fn():
     @_jit("agg_sum")
-    def f(perm, seg_id, values, valid, sign):
-        m = perm.shape[0]
-        v = values[perm]
+    def f(perm, ends, seg_id, values, valid, sign):
+        # seg_id: None for integer values, which never scatter
         ok = valid[perm]
-        s = sign[perm]
-        contrib = jnp.where(ok, v * s, jnp.zeros((), values.dtype))
-        total = jax.ops.segment_sum(contrib, seg_id, num_segments=m)
-        any_valid = jax.ops.segment_max(ok.astype(jnp.int32), seg_id, num_segments=m) > 0
-        return total, any_valid
+        contrib = jnp.where(ok, values[perm] * sign[perm], jnp.zeros((), values.dtype))
+        return _segment_sum(contrib, ends, seg_id), _any_valid(ok, ends)
 
     return f
 
@@ -88,20 +144,9 @@ def _sum_fn():
 @functools.lru_cache(maxsize=None)
 def _minmax_fn(is_max: bool):
     @_jit("agg_minmax")
-    def f(perm, seg_id, values, valid):
-        m = perm.shape[0]
-        v = values[perm]
+    def f(perm, ends, seg_id, values, valid):
         ok = valid[perm]
-        if is_max:
-            fill = jnp.finfo(values.dtype).min if jnp.issubdtype(values.dtype, jnp.floating) else jnp.iinfo(values.dtype).min
-            masked = jnp.where(ok, v, fill)
-            agg = jax.ops.segment_max(masked, seg_id, num_segments=m)
-        else:
-            fill = jnp.finfo(values.dtype).max if jnp.issubdtype(values.dtype, jnp.floating) else jnp.iinfo(values.dtype).max
-            masked = jnp.where(ok, v, fill)
-            agg = jax.ops.segment_min(masked, seg_id, num_segments=m)
-        any_valid = jax.ops.segment_max(ok.astype(jnp.int32), seg_id, num_segments=m) > 0
-        return agg, any_valid
+        return _segment_extreme(values[perm], ok, seg_id, ends.shape[0], is_max), _any_valid(ok, ends)
 
     return f
 
@@ -109,23 +154,20 @@ def _minmax_fn(is_max: bool):
 @functools.lru_cache(maxsize=None)
 def _pick_fn(last: bool):
     @_jit("agg_pick")
-    def f(perm, seg_id, candidate):
+    def f(perm, ends, candidate):
         # candidate: (m,) bool in INPUT coords — rows eligible to be picked
         # (validity and/or retract-exclusion already folded in by the caller)
-        m = perm.shape[0]
-        pos = jnp.arange(m, dtype=jnp.int32)
-        ok = candidate[perm]
-        if last:
-            cand = jnp.where(ok, pos, -1)
-            best = jax.ops.segment_max(cand, seg_id, num_segments=m)
-        else:
-            cand = jnp.where(ok, pos, m)
-            best = jax.ops.segment_min(cand, seg_id, num_segments=m)
-            best = jnp.where(best == m, -1, best)
-        src = jnp.where(best >= 0, perm[jnp.clip(best, 0, m - 1)], -1)
-        return src
+        best = _pick(candidate[perm], ends, last)
+        return jnp.where(best >= 0, perm[jnp.clip(best, 0, perm.shape[0] - 1)], -1)
 
     return f
+
+
+def pick_rows(plan: MergePlan, candidate: np.ndarray, last: bool = True) -> np.ndarray:
+    """Per segment of `plan`, the input row of its last (first) candidate
+    row, -1 where it has none."""
+    src = _pick_fn(last)(plan.perm, plan.segment_ends, pad_to(candidate, plan.m, False))
+    return np.asarray(src)[: plan.num_segments]
 
 
 def _product_host(plan: MergePlan, values: np.ndarray, eff_valid: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -176,34 +218,25 @@ def aggregate_merge(
     if fn == "primary-key":
         # always the latest arrival, null or not, retract rows included
         # (reference FieldPrimaryKeyAgg: agg/retract both return inputField)
-        src_idx = _pick_fn(True)(
-            jnp.asarray(plan.perm),
-            jnp.asarray(plan.seg_id),
-            jnp.asarray(pad_to(np.ones(len(values), np.bool_), m, False)),
-        )
-        return _gather_column(column, np.asarray(src_idx)[:k])
+        return _gather_column(column, pick_rows(plan, np.ones(len(values), np.bool_)))
 
     sign, include = _signs(row_kind, spec, values.dtype if values.dtype != np.dtype(object) else np.int64)
     eff_valid = valid & include
 
-    perm = jnp.asarray(plan.perm)
-    seg_id = jnp.asarray(plan.seg_id)
-
-    if fn in ("first_value", "first_non_null_value", "last_value", "last_non_null_value"):
+    if fn in _PICK_FNS:
         # *_value picks may land on a null row; *_non_null_value requires
         # validity. Both must respect the retract include-mask.
         candidate = eff_valid if "non_null" in fn else include
-        src = _pick_fn(fn.startswith("last"))(perm, seg_id, jnp.asarray(pad_to(candidate, m, False)))
-        src = np.asarray(src)[:k]
-        return _gather_column(column, src)
+        return _gather_column(column, pick_rows(plan, candidate, fn.startswith("last")))
 
     if values.dtype == np.dtype(object):
         raise ValueError(f"aggregate {fn!r} unsupported for string/bytes columns")
 
+    perm, ends = plan.perm, plan.segment_ends
     if fn in ("bool_and", "bool_or"):
         v8 = values.astype(np.int8)
         agg, any_valid = _minmax_fn(fn == "bool_or")(
-            perm, seg_id, jnp.asarray(pad_to(v8, m, 0)), jnp.asarray(pad_to(eff_valid, m, False))
+            perm, ends, plan.seg_id, pad_to(v8, m, 0), pad_to(eff_valid, m, False)
         )
         out = np.asarray(agg)[:k].astype(np.bool_)
         av = np.asarray(any_valid)[:k]
@@ -218,27 +251,23 @@ def aggregate_merge(
         return Column(out.astype(values.dtype, copy=False), av if not av.all() else None)
     if fn in ("max", "min"):
         agg, any_valid = _minmax_fn(fn == "max")(
-            perm, seg_id, jnp.asarray(pad_to(values, m, 0)), jnp.asarray(pad_to(eff_valid, m, False))
+            perm, ends, plan.seg_id, pad_to(values, m, 0), pad_to(eff_valid, m, False)
         )
     elif fn == "sum":
         agg, any_valid = _sum_fn()(
             perm,
-            seg_id,
-            jnp.asarray(pad_to(values, m, 0)),
-            jnp.asarray(pad_to(eff_valid, m, False)),
-            jnp.asarray(pad_to(sign, m, 1)),
+            ends,
+            None if _scans(values.dtype) else plan.seg_id,
+            pad_to(values, m, 0),
+            pad_to(eff_valid, m, False),
+            pad_to(sign, m, 1),
         )
     elif fn == "count":
         ones = np.ones(len(values), dtype=np.int64)
-        agg, any_valid = _sum_fn()(
-            perm,
-            seg_id,
-            jnp.asarray(pad_to(ones, m, 0)),
-            jnp.asarray(pad_to(eff_valid, m, False)),
-            jnp.asarray(pad_to(sign.astype(np.int64), m, 1)),
+        agg, _ = _sum_fn()(
+            perm, ends, None, pad_to(ones, m, 0), pad_to(eff_valid, m, False), pad_to(sign.astype(np.int64), m, 1)
         )
-        out = np.asarray(agg)[:k]
-        return Column(out)  # count of nothing is 0, not null
+        return Column(np.asarray(agg)[:k])  # count of nothing is 0, not null
     elif fn == "product":
         out, av = _product_host(plan, values, eff_valid)
         return Column(out.astype(values.dtype, copy=False), av if not av.all() else None)
@@ -275,6 +304,14 @@ def field_on(spec: AggregateSpec, column: Column) -> str:
     if fn in ("max", "min", "sum") and column.values.dtype == np.float64 and _f64_on_device_unsupported():
         return "host"
     return "device"
+
+
+def takes_scan(spec: AggregateSpec, column: Column) -> bool:
+    """Whether a field's reduction reads scans at the segments' bounds, on
+    either route: the picks and primary-key, count, and integer sums (not
+    floating sums, min, max, bool_and/bool_or or the host's functions)."""
+    fn = spec.function
+    return fn in _PICK_FNS or fn in ("primary-key", "count") or (fn == "sum" and _scans(column.values.dtype))
 
 
 def fused_routable(specs: list[AggregateSpec], columns: list[Column]) -> bool:
@@ -335,43 +372,24 @@ def _fused_aggregate_fn(num_key: int, num_seq: int, col_fns: tuple[str, ...], en
         pad_sorted, perm, _, keep_last, seg_id = sorted_segments(
             num_key, num_seq, key_lanes, seq_lanes, pad_flag, engine=engine
         )
-        pos = jnp.arange(m, dtype=jnp.int32)
+        # the segments' last sorted rows ride the sort that packs the winners
+        packed, ends, count = pack_selected(keep_last & (pad_sorted == 0), perm, jnp.arange(m, dtype=jnp.int32))
         outs = []
         anyv = []
         for i, fn in enumerate(col_fns):
             ok = valids[i][perm]
             if fn.startswith("pick_"):
-                last = fn == "pick_last"
-                if last:
-                    cand = jnp.where(ok, pos, -1)
-                    best = jax.ops.segment_max(cand, seg_id, num_segments=m)
-                else:
-                    cand = jnp.where(ok, pos, m)
-                    best = jax.ops.segment_min(cand, seg_id, num_segments=m)
-                    best = jnp.where(best == m, -1, best)
+                best = _pick(ok, ends, fn == "pick_last")
                 outs.append(jnp.where(best >= 0, perm[jnp.clip(best, 0, m - 1)], -1))
                 anyv.append(best >= 0)
                 continue
             v = values[i][perm]
             if fn in ("sum", "count"):
                 s = signs[i][perm].astype(v.dtype)
-                contrib = jnp.where(ok, v * s, jnp.zeros((), v.dtype))
-                agg = jax.ops.segment_sum(contrib, seg_id, num_segments=m)
+                outs.append(_segment_sum(jnp.where(ok, v * s, jnp.zeros((), v.dtype)), ends, seg_id))
             else:
-                is_max = fn in ("max", "bool_or")
-                if jnp.issubdtype(v.dtype, jnp.floating):
-                    fill = jnp.finfo(v.dtype).min if is_max else jnp.finfo(v.dtype).max
-                else:
-                    fill = jnp.iinfo(v.dtype).min if is_max else jnp.iinfo(v.dtype).max
-                masked = jnp.where(ok, v, fill)
-                agg = (
-                    jax.ops.segment_max(masked, seg_id, num_segments=m)
-                    if is_max
-                    else jax.ops.segment_min(masked, seg_id, num_segments=m)
-                )
-            outs.append(agg)
-            anyv.append(jax.ops.segment_max(ok.astype(jnp.int32), seg_id, num_segments=m) > 0)
-        packed, count = pack_selected(keep_last & (pad_sorted == 0), perm)
+                outs.append(_segment_extreme(v, ok, seg_id, m, fn in ("max", "bool_or")))
+            anyv.append(_any_valid(ok, ends))
         return tuple(outs), tuple(anyv), packed, count
 
     return f
@@ -490,30 +508,26 @@ def _segment_reduce_fn(num_lanes: int, col_fns: tuple[str, ...], engine: str = "
         pad_sorted, perm, seg_start, _keep_last, seg_id = sorted_segments(
             num_lanes, 0, key_lanes, [], pad_flag, engine=engine
         )
+        valid_rows = pad_sorted == 0
+        packed, starts, count = pack_selected(seg_start & valid_rows, perm, jnp.arange(m, dtype=jnp.int32))
+        # the groups tile the valid rows: each ends where the next starts,
+        # the last at the last valid row
+        ends = jnp.where(
+            jnp.arange(m) < count - 1,
+            jnp.concatenate([starts[1:], starts[:1]]) - 1,
+            valid_rows.sum().astype(jnp.int32) - 1,
+        )
         outs = []
         anyv = []
         for i, fn in enumerate(col_fns):
             v = values[i][perm]
             ok = valids[i][perm]
             if fn in ("sum", "count"):
-                contrib = jnp.where(ok, v, jnp.zeros((), v.dtype))
-                agg = jax.ops.segment_sum(contrib, seg_id, num_segments=m)
+                outs.append(_segment_sum(jnp.where(ok, v, jnp.zeros((), v.dtype)), ends, seg_id))
             else:
-                is_max = fn == "max"
-                if jnp.issubdtype(v.dtype, jnp.floating):
-                    fill = jnp.finfo(v.dtype).min if is_max else jnp.finfo(v.dtype).max
-                else:
-                    fill = jnp.iinfo(v.dtype).min if is_max else jnp.iinfo(v.dtype).max
-                masked = jnp.where(ok, v, fill)
-                agg = (
-                    jax.ops.segment_max(masked, seg_id, num_segments=m)
-                    if is_max
-                    else jax.ops.segment_min(masked, seg_id, num_segments=m)
-                )
-            outs.append(agg)
-            anyv.append(jax.ops.segment_max(ok.astype(jnp.int32), seg_id, num_segments=m) > 0)
+                outs.append(_segment_extreme(v, ok, seg_id, m, fn == "max"))
+            anyv.append(_any_valid(ok, ends))
         first_pos = jax.ops.segment_min(pos[perm], seg_id, num_segments=m)
-        packed, count = pack_selected(seg_start & (pad_sorted == 0), perm)
         return tuple(outs), tuple(anyv), first_pos, packed, count
 
     return f

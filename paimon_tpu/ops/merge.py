@@ -195,13 +195,15 @@ def segment_last_where(seg_id, masks, pos=None):
     return jax.vmap(lambda c: jax.ops.segment_max(c, seg_id, num_segments=m))(cand)
 
 
-def pack_selected(sel, perm):
+def pack_selected(sel, perm, *carried):
     """In-kernel: pack the selected perms to the front (key order) and count
-    them — the minimal device->host transfer for selection kernels."""
+    them — the minimal device->host transfer for selection kernels. Arrays
+    in `carried` ride the same sort and come back packed the same way,
+    before the count."""
     with jax.named_scope("merge.pack"):
         not_sel = (~sel).astype(jnp.uint32)
-        _, packed = jax.lax.sort([not_sel, perm], num_keys=1, is_stable=True)
-        return packed, sel.sum()
+        _, *packed = jax.lax.sort([not_sel, perm, *carried], num_keys=1, is_stable=True)
+        return (*packed, sel.sum())
 
 
 def _runid_bits(num_runs: int) -> int:
@@ -446,6 +448,17 @@ class MergePlan:
     def num_segments(self) -> int:
         """Segments holding valid rows (pad segments sort after them)."""
         return int(self.seg_id[self.n - 1]) + 1 if self.n else 0
+
+    @functools.cached_property
+    def segment_ends(self) -> np.ndarray:
+        """(pad_size(num_segments),) int32: each segment's last sorted row,
+        in segment order, the tail past num_segments filled with the last
+        one. Segments tile the valid rows, so segment i starts one row after
+        segment i-1 ends. Padded by the segment count as rows are padded by
+        the row count: merges whose key counts share a bucket share the
+        programs that read it."""
+        ends = np.flatnonzero(self.keep_last[: self.n]).astype(np.int32)
+        return pad_to(ends, pad_size(len(ends)), ends[-1] if len(ends) else 0)
 
 
 def drop_constant_lanes(lanes: np.ndarray) -> np.ndarray:

@@ -179,7 +179,34 @@ def test_a_rollup_of_four_sources_sums_maximises_and_keeps_the_last_non_null(rou
     counted = registry.snapshot()["aggregate"]
     on_host = len(MAXES) if route == "planned" else 0
     assert counted == {"merges": 1, "rows_in": ROWS, "rows_out": KEYS, "fields": len(NAMES) - 1,
-                       "fields_on_host": on_host}
+                       "fields_on_host": on_host, "fields_by_scan": len(SUMS) + len(PICKS)}
+
+
+@pytest.mark.parametrize("route", ["fused", "planned"])
+def test_the_fields_reduced_by_scan_are_counted(route, tmp_path):
+    """aggregate{fields_by_scan}: an integer sum, a count and a pick read
+    scans at the segments' bounds; a FLOAT sum and a DOUBLE max do not."""
+    schema = pt.RowType.of(("id", pt.BIGINT(False)), ("total", pt.BIGINT()), ("n", pt.INT()), ("f", pt.FLOAT()),
+                           ("d", pt.DOUBLE()), ("s", pt.STRING()))
+    options = {"bucket": "1", "write-only": "true", "merge-engine": "aggregation",
+               "fields.total.aggregate-function": "sum", "fields.n.aggregate-function": "count",
+               "fields.f.aggregate-function": "sum", "fields.d.aggregate-function": "max"}
+    with _route(route):
+        table = FileSystemCatalog(str(tmp_path), commit_user="scan").create_table(
+            "db.scan", schema, primary_keys=["id"], options=options)
+        for run in range(2):
+            wb = table.new_batch_write_builder()
+            w = wb.new_write()
+            w.write({"id": [1, 2, 3], "total": [5, None, -7], "n": [1, 2, None], "f": [0.5, 1.5, None],
+                     "d": [1.0, None, 3.0], "s": ["a", f"b{run}", None]})
+            wb.new_commit().commit(w.prepare_commit())
+        registry.reset()
+        out = _read(table)
+    # each flush stores a count, 0 where its rows were null, and the read counts the stored rows
+    assert out.to_pylist() == [(1, 10, 2, 1.0, 1.0, "a"), (2, None, 2, 3.0, None, "b1"), (3, -14, 2, None, 3.0, None)]
+    counted = registry.snapshot()["aggregate"]
+    assert counted["fields"] == 5 and counted["fields_by_scan"] == 3
+    assert counted["fields_on_host"] == (1 if route == "planned" else 0)
 
 
 def test_every_flush_of_an_aggregation_table_counts(tmp_path):
@@ -188,7 +215,7 @@ def test_every_flush_of_an_aggregation_table_counts(tmp_path):
     counted = registry.snapshot()["aggregate"]
     # one flush a source: its keys once each
     assert counted == {"merges": 4, "rows_in": ROWS, "rows_out": ROWS, "fields": 4 * (len(NAMES) - 1),
-                       "fields_on_host": 0}
+                       "fields_on_host": 0, "fields_by_scan": 4 * (len(SUMS) + len(PICKS))}
 
 
 @contextlib.contextmanager
@@ -241,13 +268,14 @@ def test_the_aggregation_opens_its_spans_with_their_stats(route, tmp_path):
 def test_the_aggregation_programs_have_names_of_their_own(program):
     m = 256
     perm, seg = np.arange(m, dtype=np.int32), np.arange(m, dtype=np.int32) // 2
+    ends = np.arange(1, m, 2, dtype=np.int32)
     values, valid = np.arange(m, dtype=np.int64), np.ones(m, dtype=np.bool_)
     if program == "agg_sum":
-        lowered = aggregates._sum_fn().lower(perm, seg, values, valid, np.ones(m, dtype=np.int64))
+        lowered = aggregates._sum_fn().lower(perm, ends, None, values, valid, np.ones(m, dtype=np.int64))
     elif program == "agg_minmax":
-        lowered = aggregates._minmax_fn(True).lower(perm, seg, values, valid)
+        lowered = aggregates._minmax_fn(True).lower(perm, ends, seg, values, valid)
     elif program == "agg_pick":
-        lowered = aggregates._pick_fn(True).lower(perm, seg, valid)
+        lowered = aggregates._pick_fn(True).lower(perm, ends, valid)
     else:
         lanes = np.zeros((1, m), dtype=np.uint32)
         lowered = aggregates._fused_aggregate_fn(1, 0, ("sum",), "xla").lower(
