@@ -87,7 +87,8 @@ class Column:
     String/bytes columns may additionally be backed by a pyarrow array
     (`arrow`): structural ops (take/slice/filter/concat) then run in arrow's
     C++ and the object ndarray materializes lazily only when `.values` is
-    actually touched (predicates, key pools, python access).
+    actually touched (predicates, key pools, python access; counted in
+    dict{arrow_expanded}).
 
     `dict_cache` is an optional (sorted pool, uint32 ranks) pair attached by
     the key-lane encoder (data/keys.py): the ranks ARE exact dictionary
@@ -148,13 +149,13 @@ class Column:
     @property
     def values(self) -> np.ndarray:
         if self._values is None:
+            from ..metrics import dict_metrics
+
             if self.arrow is None:
                 # code-backed: expand pool[codes] on first python-level
                 # access. Object pools fill nulls with None (matching the
                 # expanded decode); fixed-width pools fill with the zero
                 # sentinel, exactly like decode_chunk's null fill
-                from ..metrics import dict_metrics
-
                 pool, codes = self.dict_cache
                 if len(pool):
                     v = pool.take(np.minimum(codes, len(pool) - 1))
@@ -173,8 +174,19 @@ class Column:
             v = arr.to_numpy(zero_copy_only=False)
             if v.dtype != np.dtype(object):
                 v = v.astype(object)
+            dict_metrics().counter("arrow_expanded").inc(self._len)
             self._values = v
         return self._values
+
+    @property
+    def dtype(self) -> np.dtype:
+        """The dtype of `.values`, read without building them: an
+        arrow-backed column's is object, a code-backed one's its pool's."""
+        if self._values is not None:
+            return self._values.dtype
+        if self.arrow is not None:
+            return np.dtype(object)
+        return self.dict_cache[0].dtype
 
     def value_at(self, i: int):
         """One python value without materializing the whole column (file

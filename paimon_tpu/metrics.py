@@ -199,8 +199,12 @@ def dict_metrics() -> MetricGroup:
     a reader as dictionary codes instead of expanded strings),
     fallback_expanded (rows that fell back to the expanded-string path: a
     non-dictionary chunk, a pool past merge.dict-domain.pool-limit, or a
-    consumer that needed real values); histogram: unify_ms (host wall
-    millis unifying pools — object work at |pool| scale, never |rows|).
+    consumer that needed real values), rows_taken_in_arrow (rows a merge's
+    pick took from an arrow-backed column with pyarrow's take, no Python
+    string built: ops.aggregates._gather_column), arrow_expanded (rows of
+    arrow-backed columns that Column.values turned into Python objects);
+    histogram: unify_ms (host wall millis unifying pools — object work at
+    |pool| scale, never |rows|).
     Resolved per call so registry.reset() in tests swaps the group out."""
     return registry.group("dict")
 

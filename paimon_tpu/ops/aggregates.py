@@ -35,10 +35,12 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pyarrow as pa
+import pyarrow.compute as pc
 from jax import lax
 
 from ..data.batch import Column
-from ..metrics import span
+from ..metrics import dict_metrics, span
 from ..types import RowKind
 from .merge import MergePlan, _jit, pad_to
 
@@ -199,6 +201,12 @@ def _signs(row_kind: np.ndarray, spec: AggregateSpec, dtype) -> tuple[np.ndarray
     return np.ones(len(row_kind), dtype=dtype), np.ones(len(row_kind), dtype=np.bool_)
 
 
+def _sign_dtype(column: Column):
+    """The dtype of `_signs`' arrays for a column, read without building its
+    values: the column's own, int64 for strings and bytes."""
+    return np.int64 if column.dtype == np.dtype(object) else column.dtype
+
+
 def aggregate_merge(
     plan: MergePlan,
     column: Column,
@@ -208,27 +216,28 @@ def aggregate_merge(
     """Aggregate one value column over the plan's segments. Returns a Column
     of length plan.num_segments (key order)."""
     m, k = plan.m, plan.num_segments
-    values = column.values
     valid = column.valid_mask()
     fn = spec.function
 
     if fn in ("listagg", "collect", "merge_map", "nested_update"):
-        return _host_aggregate(plan, values, valid, spec, row_kind)
+        return _host_aggregate(plan, column.values, valid, spec, row_kind)
 
     if fn == "primary-key":
         # always the latest arrival, null or not, retract rows included
         # (reference FieldPrimaryKeyAgg: agg/retract both return inputField)
-        return _gather_column(column, pick_rows(plan, np.ones(len(values), np.bool_)))
+        return _gather_column(column, pick_rows(plan, np.ones(len(column), np.bool_)))
 
-    sign, include = _signs(row_kind, spec, values.dtype if values.dtype != np.dtype(object) else np.int64)
+    sign, include = _signs(row_kind, spec, _sign_dtype(column))
     eff_valid = valid & include
 
     if fn in _PICK_FNS:
         # *_value picks may land on a null row; *_non_null_value requires
-        # validity. Both must respect the retract include-mask.
+        # validity. Both must respect the retract include-mask. The picks
+        # never read the values: a string column stays in arrow or codes
         candidate = eff_valid if "non_null" in fn else include
         return _gather_column(column, pick_rows(plan, candidate, fn.startswith("last")))
 
+    values = column.values
     if values.dtype == np.dtype(object):
         raise ValueError(f"aggregate {fn!r} unsupported for string/bytes columns")
 
@@ -301,7 +310,7 @@ def field_on(spec: AggregateSpec, column: Column) -> str:
         return "pick"
     if fn in ("listagg", "collect", "merge_map", "nested_update", "product"):
         return "host"
-    if fn in ("max", "min", "sum") and column.values.dtype == np.float64 and _f64_on_device_unsupported():
+    if fn in ("max", "min", "sum") and column.dtype == np.float64 and _f64_on_device_unsupported():
         return "host"
     return "device"
 
@@ -311,7 +320,7 @@ def takes_scan(spec: AggregateSpec, column: Column) -> bool:
     either route: the picks and primary-key, count, and integer sums (not
     floating sums, min, max, bool_and/bool_or or the host's functions)."""
     fn = spec.function
-    return fn in _PICK_FNS or fn in ("primary-key", "count") or (fn == "sum" and _scans(column.values.dtype))
+    return fn in _PICK_FNS or fn in ("primary-key", "count") or (fn == "sum" and _scans(column.dtype))
 
 
 def fused_routable(specs: list[AggregateSpec], columns: list[Column]) -> bool:
@@ -325,9 +334,9 @@ def fused_routable(specs: list[AggregateSpec], columns: list[Column]) -> bool:
             continue
         if spec.function not in _DEVICE_FNS:
             return False
-        if col.values.dtype == np.dtype(object):
+        if col.dtype == np.dtype(object):
             return False
-        if f64_off_device and col.values.dtype == np.float64 and spec.function != "count":
+        if f64_off_device and col.dtype == np.float64 and spec.function != "count":
             return False
     return True
 
@@ -419,9 +428,7 @@ def fused_aggregate(
     signs = []
     for spec, col in zip(specs, columns):
         fn = spec.function
-        sign, include = _signs(
-            row_kind, spec, col.values.dtype if col.values.dtype != np.dtype(object) else np.int64
-        )
+        sign, include = _signs(row_kind, spec, _sign_dtype(col))
         valid = col.valid_mask()
         if fn in _PICK_FNS:
             candidate = (valid & include) if "non_null" in fn else include
@@ -461,7 +468,7 @@ def fused_aggregate(
             elif fn == "count":
                 result.append(Column(np.asarray(o[:kk])))  # count of nothing is 0
             else:
-                vals = np.asarray(o[:kk]).astype(col.values.dtype, copy=False)
+                vals = np.asarray(o[:kk]).astype(col.dtype, copy=False)
                 valid = np.asarray(av[:kk])
                 if fn in ("bool_and", "bool_or"):
                     vals = vals.astype(np.bool_)
@@ -470,6 +477,8 @@ def fused_aggregate(
 
 
 def _gather_column(column: Column, src: np.ndarray) -> Column:
+    """The rows `src` names of `column`, null where `src` is -1, in the
+    domain the column is backed by: codes, arrow or numpy."""
     ok = src >= 0
     safe = np.clip(src, 0, max(len(column) - 1, 0))
     validity = ok & column.valid_mask().take(safe)
@@ -478,6 +487,18 @@ def _gather_column(column: Column, src: np.ndarray) -> Column:
         # and aggregation winners never materialize the strings
         pool, codes = column.dict_cache
         return Column.from_codes(pool, codes.take(safe), validity)
+    if column.arrow is not None:
+        # arrow's take, the -1 slots null indices: no string object is built
+        # (dict{rows_taken_in_arrow}), whether or not `.values` was filled
+        taken = pc.take(column.arrow, pa.array(src, mask=None if ok.all() else ~ok))
+        if isinstance(taken, pa.ChunkedArray):
+            taken = taken.chunk(0) if taken.num_chunks == 1 else taken.combine_chunks()
+        if taken.null_count != len(src) - int(validity.sum()):
+            # a validity that masks rows the arrow holds (as a LEFT join's
+            # unmatched side has it): the nulls follow the validity
+            taken = pc.if_else(pa.array(validity), taken, pa.scalar(None, taken.type))
+        dict_metrics().counter("rows_taken_in_arrow").inc(len(src))
+        return Column(validity=validity, arrow=taken)
     vals = column.values.take(safe)
     if column.values.dtype != np.dtype(object):
         vals = np.where(validity, vals, np.zeros((), column.values.dtype))

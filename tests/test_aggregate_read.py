@@ -183,6 +183,23 @@ def test_a_rollup_of_four_sources_sums_maximises_and_keeps_the_last_non_null(rou
 
 
 @pytest.mark.parametrize("route", ["fused", "planned"])
+def test_the_picks_take_their_strings_in_arrow(route, tmp_path):
+    """The ORC reader hands the STRING columns over arrow-backed; the four
+    `last_non_null_value` picks take their winners in arrow
+    (dict{rows_taken_in_arrow}), and no column of the read is turned into
+    Python strings (dict{arrow_expanded})."""
+    with _route(route):
+        table, sources = _table(tmp_path, seed=17)
+        registry.reset()
+        out = _read(table)
+    counted = registry.snapshot()["dict"]
+    assert counted["rows_taken_in_arrow"] == len(PICKS) * KEYS
+    assert counted.get("arrow_expanded", 0) == 0
+    assert all(out.column(n)._values is None and out.column(n).arrow is not None for n in PICKS)
+    _assert_matches(out, _expected(sources))
+
+
+@pytest.mark.parametrize("route", ["fused", "planned"])
 def test_the_fields_reduced_by_scan_are_counted(route, tmp_path):
     """aggregate{fields_by_scan}: an integer sum, a count and a pick read
     scans at the segments' bounds; a FLOAT sum and a DOUBLE max do not."""

@@ -150,6 +150,22 @@ def test_a_filtered_read_of_four_sources_merges_field_by_field(case, tmp_path):
         assert (merged["merges"], merged["rows_in"], merged["tiles"], merged["pad_rows"]) == (1, ROWS, 1, 65_536 - ROWS)
 
 
+@pytest.mark.parametrize("case", sorted(ENGINES))
+def test_the_merge_takes_its_strings_in_arrow(case, tmp_path):
+    """The four STRING fields arrive arrow-backed from the ORC reader and the
+    merge takes each one's winners in arrow (dict{rows_taken_in_arrow}, a row
+    a merged key): no column is turned into Python strings before the filter
+    (dict{arrow_expanded})."""
+    table, members = _table(tmp_path, seed=17, **ENGINES[case])
+    registry.reset()
+    out = _read(table)
+    counted = registry.snapshot()["dict"]
+    assert counted["rows_taken_in_arrow"] == 4 * KEYS
+    assert counted.get("arrow_expanded", 0) == 0
+    assert all(out.column(n)._values is None and out.column(n).arrow is not None for n in NAMES[11:])
+    _assert_matches(out, _expected(members))
+
+
 def test_a_source_that_leaves_every_string_null_writes_and_reads_back_as_nulls(tmp_path):
     table, members = _table(tmp_path, seed=5)
     rb = table.new_read_builder()
