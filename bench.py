@@ -303,57 +303,6 @@ def bench_dicts(table) -> list:
     ]
 
 
-def bench_pallas(table) -> list:
-    """Pallas sort-engine spot-check: the standard merge-read table read
-    through table.copy with sort-engine pallas (lax.sort + the pallas
-    boundary-sweep kernel; interpreted on the CPU, Mosaic-compiled on a
-    TPU) vs xla-segmented. Outputs asserted identical row-for-row, plus the
-    pallas{} counter breakdown."""
-    from paimon_tpu.metrics import pallas_metrics
-
-    g = pallas_metrics()
-
-    def counters():
-        return {k: g.counter(k).count for k in ("kernels_launched", "tiles")}
-
-    results = {}
-    deltas = None
-    for engine in ("xla-segmented", "pallas"):
-        t = table.copy({"sort-engine": engine})
-        rb = t.new_read_builder()
-        best = float("inf")
-        c0 = counters()
-        out = None
-        for it in range(3):
-            t0 = time.perf_counter()
-            out = rb.new_read().read_all(rb.new_scan().plan())
-            dt = time.perf_counter() - t0
-            assert out.num_rows == N_ROWS, out.num_rows
-            if it > 0:
-                best = min(best, dt)
-        if engine == "pallas":
-            deltas = {k: v - c0[k] for k, v in counters().items()}
-        results[engine] = (N_ROWS / best, out)
-    assert results["pallas"][1].to_pylist() == results["xla-segmented"][1].to_pylist()
-    pal, xla = results["pallas"][0], results["xla-segmented"][0]
-    return [
-        {
-            "metric": "merge-read sort-engine pallas vs xla-segmented (same table)",
-            "rows_per_sec_xla_segmented": round(xla, 1),
-            "rows_per_sec_pallas": round(pal, 1),
-            "speedup": round(pal / xla, 3),
-            "identical_output": True,
-            "unit": "rows/s",
-        },
-        {
-            "metric": "pallas kernel breakdown",
-            "kernels_launched": deltas["kernels_launched"],
-            "tiles": deltas["tiles"],
-            "unit": "counters",
-        },
-    ]
-
-
 def bench_join() -> list:
     """Device-join spot-check (benchmarks/join_bench.py is the dedicated
     1M x 100k fact x dimension sweep with the >=5x headline and the skew
@@ -633,7 +582,6 @@ def main():
         join_rows = bench_join()
         point_get_rows = bench_point_get()
         subscribe_rows = bench_subscribe()
-        pallas_rows = bench_pallas(table)
         adaptive_row = bench_adaptive()
         pipeline_rows = bench_pipeline()
         encode_rows = bench_encode()
@@ -669,7 +617,6 @@ def main():
             *join_rows,
             *point_get_rows,
             *subscribe_rows,
-            *pallas_rows,
             adaptive_row,
             *pipeline_rows,
             *encode_rows,

@@ -11,11 +11,11 @@ answer to "how fast is the TPU merge itself vs the reference's heap loop"
 scan baseline; the in-memory merge portion of the reference loop is what
 this kernel replaces).
 
-Grid: rows x lane-arity x engine(backend). Prints one JSON line per cell:
+Grid: rows x lane-arity x engine. Prints one JSON line per cell:
 {"metric": "kernel.<engine>.k<K>s<S>", "value": rows/s, ...}.
 
 Usage: python benchmarks/kernel_resident.py [--rows 1048576,4194304]
-       [--engines dedup,dedup_pallas,partial_update]
+       [--engines dedup,partial_update]
 """
 
 from __future__ import annotations
@@ -127,14 +127,13 @@ def time_kernel(inner, args, n_rows: int, k_lo: int = 4, k_hi: int = 32, reps: i
     return n_rows / t_kernel
 
 
-def bench_dedup(n: int, k: int, s: int, backend: str):
+def bench_dedup(n: int, k: int, s: int):
     from paimon_tpu.ops import merge as M
 
     klp, slp, pad, kk, ss, m = make_lanes(n, k, s)
-    fn = M._dedup_select_fn(kk, ss, backend)
+    fn = M._dedup_select_fn(kk, ss)
     rps = time_kernel(fn, (klp, slp, pad), n)
-    tag = "dedup" if backend == "xla" else f"dedup_{backend}"
-    emit(f"kernel.{tag}.k{kk}s{ss}", rps, rows=n, padded=m)
+    emit(f"kernel.dedup.k{kk}s{ss}", rps, rows=n, padded=m)
 
 
 def bench_partial_update(n: int, k: int, s: int, fields: int = 4):
@@ -158,7 +157,7 @@ def bench_partial_update(n: int, k: int, s: int, fields: int = 4):
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--rows", default="1048576,4194304")
-    ap.add_argument("--engines", default="dedup,dedup_pallas,partial_update")
+    ap.add_argument("--engines", default="dedup,partial_update")
     ap.add_argument("--arities", default="1:0,2:1")
     args = ap.parse_args()
     rows = [int(x) for x in args.rows.split(",")]
@@ -167,9 +166,7 @@ def main():
     for n in rows:
         for k, s in arities:
             if "dedup" in engines:
-                bench_dedup(n, k, s, "xla")
-            if "dedup_pallas" in engines and PLATFORM != "cpu":
-                bench_dedup(n, k, s, "pallas")
+                bench_dedup(n, k, s)
             if "partial_update" in engines:
                 bench_partial_update(n, k, s)
 

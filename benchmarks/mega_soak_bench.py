@@ -9,7 +9,7 @@ writer / getter / subscriber / distributed-SQL OS processes, snapshot-expiry
 configurations:
 
   full        the whole DEFAULT_MATRIX (flagship cluster+branch/tag cell,
-              dict-dynamic consumer-expiry cell, wide-pallas cell,
+              dict-dynamic consumer-expiry cell, wide cell,
               native-legacy engine-contrast cell), >= 10 min total at the
               default chaos shaping (1 op in 200 faulting, latency on every
               read/write), scripted kill -9 deaths at every registered
@@ -18,8 +18,8 @@ configurations:
               points with ONE consistent:true verdict — 0 lost/duplicated/
               mismatched rows, 0 untyped sheds, 0 pinned-read errors,
               post-sweep disk set == reachable closure, and every metric
-              group (io/soak/get/sub/cluster/sql/gateway/compaction/dict/
-              pallas) nonzero somewhere in the run.
+              group (io/soak/get/sub/cluster/sql/gateway/compaction/dict)
+              nonzero somewhere in the run.
   seed        the contrast run WITHOUT the resilience stack (fs.retry.
               max-attempts=1, commit.max-retries=0) on one cell at a hotter
               fault rate: the same chaos store now surfaces raw IO faults

@@ -169,41 +169,27 @@ class MergeExecutor:
 
     def effective_sort_engine(self):
         """The merge backend actually used. sort-engine set on the table
-        wins unconditionally (a table that explicitly chose numpy/pallas
-        keeps it); then the PAIMON_TPU_SORT_ENGINE env var (the CI forcing
-        knob, pattern of PAIMON_TPU_MERGE_ENGINE) pins every table that
-        did not choose; otherwise the default ADAPTS to the resolved
-        platform: the host lexsort path on a CPU-only backend (a single
-        stable `np.lexsort` beats XLA:CPU's variadic stable sort ~3x at the
-        1M-row scale), the device kernel everywhere else. The check never
-        initializes a backend (ops.merge.resolved_platform_is_cpu).
-        PAIMON_TPU_FORCE_DEVICE_ENGINE=1 pins the device kernel so the test
-        suite exercises the dispatch path on its virtual-CPU mesh."""
+        wins unconditionally (a table that explicitly chose numpy keeps
+        it); then PAIMON_TPU_FORCE_DEVICE_ENGINE=1 pins the device kernel so
+        the test suite exercises the dispatch path on its virtual-CPU mesh;
+        otherwise the default ADAPTS to the resolved platform: the host
+        lexsort path on a CPU-only backend (a single stable `np.lexsort`
+        beats XLA:CPU's variadic stable sort ~3x at the 1M-row scale), the
+        device kernel everywhere else. The check never initializes a
+        backend (ops.merge.resolved_platform_is_cpu)."""
         import os
 
         from ..options import CoreOptions, SortEngine
 
         if self.options.options.contains(CoreOptions.SORT_ENGINE):
-            return SortEngine(self.options.sort_engine)
-        env = os.environ.get("PAIMON_TPU_SORT_ENGINE", "").strip().lower()
-        if env:
-            return SortEngine(env)
+            return self.options.sort_engine
         if os.environ.get("PAIMON_TPU_FORCE_DEVICE_ENGINE", "") == "1":
-            return SortEngine(self.options.sort_engine)
+            return SortEngine.XLA_SEGMENTED
         from ..ops.merge import resolved_platform_is_cpu
 
         if resolved_platform_is_cpu():
             return SortEngine.NUMPY
-        return SortEngine(self.options.sort_engine)
-
-    def _engine_str(self) -> str:
-        """The ops-layer engine tag for the sorted_segments seam: 'pallas'
-        routes every merge kernel's sort+boundary preamble through the fused
-        pallas kernels; everything else is the stock XLA path. (The numpy
-        engine never reaches a device kernel — callers branch before.)"""
-        from ..options import SortEngine
-
-        return "pallas" if self.effective_sort_engine() == SortEngine.PALLAS else "xla"
+        return SortEngine.XLA_SEGMENTED
 
     def _key_lanes(self, kv: KVBatch) -> np.ndarray:
         from ..data.keys import encode_key_lanes_with_pools
@@ -280,7 +266,7 @@ class MergeExecutor:
 
     def _plan(self, kv: KVBatch, seq_ascending: bool = False):
         lanes, seq_lanes = self._lanes(kv, seq_ascending)
-        return merge_plan(lanes, seq_lanes, compress=self._compress, engine=self._engine_str())
+        return merge_plan(lanes, seq_lanes, compress=self._compress)
 
     def merge(self, kv: KVBatch, seq_ascending: bool = False) -> KVBatch:
         """One output row per key, key-sorted. Dedup keeps the winning row's
@@ -349,12 +335,7 @@ class MergeExecutor:
                 cols = [kv.data.column(f.name) for f in fields]
                 if fused_routable(specs, cols):
                     return ("sync", self._aggregate_fused(kv, lanes, seq_lanes, fields, specs, cols))
-        return (
-            "sync",
-            self._merge_with_plan(
-                kv, merge_plan(lanes, seq_lanes, compress=self._compress, engine=self._engine_str())
-            ),
-        )
+        return ("sync", self._merge_with_plan(kv, merge_plan(lanes, seq_lanes, compress=self._compress)))
 
     def _dedup_dispatch(self, kv: KVBatch, seq_ascending: bool):
         """The dedup merge's device half on whatever `kv` holds of the key
@@ -388,13 +369,9 @@ class MergeExecutor:
             from ..ops.lanes import resolve_compress
 
             return ("mesh", (ctx, ctx.submit_dedup(lanes, seq_lanes, compress=resolve_compress(self._compress))))
-        backend = "pallas" if engine == SortEngine.PALLAS else "xla"
         from ..ops.merge import deduplicate_select_async
 
-        return (
-            "single",
-            deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress, plan=plan),
-        )
+        return ("single", deduplicate_select_async(lanes, seq_lanes, compress=self._compress, plan=plan))
 
     def merge_parts_async(self, kv_keys: KVBatch, parts: Sequence[KVBatch], offsets: Sequence[int],
                           seq_ascending: bool, counters: MetricGroup):
@@ -479,21 +456,15 @@ class MergeExecutor:
         from ..ops.merge import deduplicate_select_async, deduplicate_tiled_dispatch
 
         lanes, plan = self._sort_lanes(kv_keys, enable_ovc=True)
-        backend = "pallas" if engine == SortEngine.PALLAS else "xla"
         if seq_lanes is None and run_offsets is not None:
             tile_rows = self.options.options.get(CoreOptions.MERGE_READ_BATCH_ROWS)
             # the tiled dispatcher owns the compression seam (one plan per
             # merge, shared by every tile) and the all-constant fast path
             return (
                 "tiled",
-                deduplicate_tiled_dispatch(
-                    lanes, run_offsets, tile_rows, backend=backend, compress=self._compress, plan=plan
-                ),
+                deduplicate_tiled_dispatch(lanes, run_offsets, tile_rows, compress=self._compress, plan=plan),
             )
-        return (
-            "single",
-            deduplicate_select_async(lanes, seq_lanes, backend=backend, compress=self._compress, plan=plan),
-        )
+        return ("single", deduplicate_select_async(lanes, seq_lanes, compress=self._compress, plan=plan))
 
     @staticmethod
     def dedup_resolve(handle) -> np.ndarray:
@@ -564,7 +535,6 @@ class MergeExecutor:
             kv.kind,
             remove_record_on_delete=remove_on_delete,
             compress=self._compress,
-            engine=self._engine_str(),
         )
         with span("pu.gather", rows_in=kv.num_rows, rows_out=len(last_take), fields=len(fields)):
             cols: dict[str, Column] = {}
@@ -589,7 +559,7 @@ class MergeExecutor:
 
         with span("agg.reduce", rows_in=kv.num_rows, fields=len(fields), route="fused") as sp:
             agg_cols, last_take = fused_aggregate(
-                lanes, seq_lanes, cols_in, specs, kv.kind, compress=self._compress, engine=self._engine_str(),
+                lanes, seq_lanes, cols_in, specs, kv.kind, compress=self._compress,
                 names=[f.name for f in fields],
             )
             cols: dict[str, Column] = {}
@@ -652,7 +622,7 @@ class MergeExecutor:
         g_lanes = self._lanes_nullsafe(gcol, root, gpool, seq_col)
         hi, lo = split_int64_lanes(kv.seq)
         seq_lanes = np.concatenate([g_lanes, np.stack([hi, lo], axis=1)], axis=1)
-        gplan = merge_plan(key_lanes, seq_lanes, compress=self._compress, engine=self._engine_str())
+        gplan = merge_plan(key_lanes, seq_lanes, compress=self._compress)
         candidate = g_valid & np.isin(kv.kind, (int(RowKind.INSERT), int(RowKind.UPDATE_AFTER)))
         src = pick_rows(gplan, candidate)
         out = {}

@@ -41,7 +41,6 @@ __all__ = [
     "lanes_metrics",
     "merge_metrics",
     "mesh_metrics",
-    "pallas_metrics",
     "partial_update_metrics",
     "pipeline_metrics",
     "read_metrics",
@@ -373,17 +372,6 @@ def soak_metrics() -> MetricGroup:
     admission). Resolved per call so registry.reset() in tests swaps the
     group out."""
     return registry.group("soak")
-
-
-def pallas_metrics() -> MetricGroup:
-    """The pallas{...} group (the boundary-sweep kernel, paimon_tpu.ops.
-    pallas_kernels, routed by sort-engine=pallas). Canonical members —
-    counters: kernels_launched (merge dispatches routed through the pallas
-    engine), tiles (pallas grid steps: one per _BLOCK rows of the
-    post-lax.sort boundary sweep); histogram: kernel_ms (wall millis of
-    synchronously-resolved dispatches: merge_plan). Resolved per call so
-    registry.reset() in tests swaps the group out."""
-    return registry.group("pallas")
 
 
 def write_metrics() -> MetricGroup:

@@ -366,7 +366,7 @@ def _host_reduce(plan: MergePlan, values: np.ndarray, eff_valid: np.ndarray, fn:
 
 
 @functools.lru_cache(maxsize=None)
-def _fused_aggregate_fn(num_key: int, num_seq: int, col_fns: tuple[str, ...], engine: str = "xla"):
+def _fused_aggregate_fn(num_key: int, num_seq: int, col_fns: tuple[str, ...]):
     """Sort + every column's segment reduction in ONE kernel (the aggregation
     analog of the fused dedup kernel): uploads lanes + value columns once,
     downloads only the (C, k) results — no plan arrays, no per-column
@@ -378,9 +378,7 @@ def _fused_aggregate_fn(num_key: int, num_seq: int, col_fns: tuple[str, ...], en
     @_jit("fused_aggregate")
     def f(key_lanes, seq_lanes, pad_flag, values, valids, signs):
         m = pad_flag.shape[0]
-        pad_sorted, perm, _, keep_last, seg_id = sorted_segments(
-            num_key, num_seq, key_lanes, seq_lanes, pad_flag, engine=engine
-        )
+        pad_sorted, perm, _, keep_last, seg_id = sorted_segments(num_key, num_seq, key_lanes, seq_lanes, pad_flag)
         # the segments' last sorted rows ride the sort that packs the winners
         packed, ends, count = pack_selected(keep_last & (pad_sorted == 0), perm, jnp.arange(m, dtype=jnp.int32))
         outs = []
@@ -411,7 +409,6 @@ def fused_aggregate(
     specs: list[AggregateSpec],
     row_kind: np.ndarray,
     compress: bool | None = None,
-    engine: str = "xla",
     names: list[str] | None = None,
 ) -> tuple[list[Column], np.ndarray]:
     """Single-call aggregation merge over every value column. Returns
@@ -451,11 +448,7 @@ def fused_aggregate(
             values.append(pad_to(col.values, m, 0))
             valids.append(pad_to(valid & include, m, False))
             signs.append(pad_to(sign.astype(np.int8), m, 1))
-    if engine == "pallas":
-        from .pallas_kernels import note_dispatch
-
-        note_dispatch(m)
-    outs, anyv, packed, count = _fused_aggregate_fn(k, s, tuple(col_fns), engine)(
+    outs, anyv, packed, count = _fused_aggregate_fn(k, s, tuple(col_fns))(
         klp, slp, pad, tuple(values), tuple(valids), tuple(signs)
     )
     kk = int(count)
@@ -510,7 +503,7 @@ def _gather_column(column: Column, src: np.ndarray) -> Column:
 # The SQL group-by primitive: group keys arrive as uint32 lanes (dictionary
 # codes or narrowed fixed-width values), value columns reduce per segment in
 # ONE fused sort+reduce kernel through the same sorted_segments seam the
-# merge path uses — pallas/xla/lane-compression all inherit it. Unlike
+# merge path uses — lane compression included. Unlike
 # aggregate_merge there is no sequence dimension and no retract handling:
 # every row contributes, and the caller additionally gets each group's
 # minimum input position so first-appearance output order (and distributed
@@ -520,15 +513,13 @@ _SEGMENT_REDUCE_FNS = ("sum", "count", "min", "max")
 
 
 @functools.lru_cache(maxsize=None)
-def _segment_reduce_fn(num_lanes: int, col_fns: tuple[str, ...], engine: str = "xla"):
+def _segment_reduce_fn(num_lanes: int, col_fns: tuple[str, ...]):
     from .merge import pack_selected, sorted_segments
 
     @jax.jit
     def f(key_lanes, pad_flag, pos, values, valids):
         m = pad_flag.shape[0]
-        pad_sorted, perm, seg_start, _keep_last, seg_id = sorted_segments(
-            num_lanes, 0, key_lanes, [], pad_flag, engine=engine
-        )
+        pad_sorted, perm, seg_start, _keep_last, seg_id = sorted_segments(num_lanes, 0, key_lanes, [], pad_flag)
         valid_rows = pad_sorted == 0
         packed, starts, count = pack_selected(seg_start & valid_rows, perm, jnp.arange(m, dtype=jnp.int32))
         # the groups tile the valid rows: each ends where the next starts,
@@ -571,7 +562,8 @@ def segment_reduce(
     valid for column i, and ``first_pos[g]`` the minimum `pos` over the
     group (first-appearance ordering / distributed combine key).
 
-    Engines: "numpy" routes to the exact host twin; f64 columns leave the
+    engine: "numpy" routes to the exact host twin, "xla" to the device
+    kernel; there too f64 columns leave the
     device on TPU backends (no native f64, same rule as aggregate_merge);
     fully constant key lanes (k == 0 after compression) take the twin too —
     a single group is not worth a device round trip."""
@@ -596,12 +588,8 @@ def segment_reduce(
     from ..metrics import sql_metrics
 
     sql_metrics().counter("rows_reduced_device").inc(n)
-    if engine == "pallas":
-        from .pallas_kernels import note_dispatch
-
-        note_dispatch(m)
     big = np.iinfo(np.int64).max
-    outs, anyv, first_pos, packed, count = _segment_reduce_fn(k, tuple(fns), engine)(
+    outs, anyv, first_pos, packed, count = _segment_reduce_fn(k, tuple(fns))(
         klp,
         pad,
         jnp.asarray(pad_to(pos.astype(np.int64, copy=False), m, big)),

@@ -16,7 +16,7 @@ that landed for merges:
   * the sort-merge kernel rides the `ops/merge.sorted_segments` seam — the
     build and probe rows concatenate with a side lane as the leading
     sequence lane, one stable sort groups equal keys into segments with
-    build rows first, and `sort-engine=pallas` is inherited for free;
+    build rows first;
   * the code domain rides `ops/dicts.py` — when both sides of a key column
     are dictionary-backed, their pools unify once (O(|pool|) object work)
     and the join matches remapped uint32 codes with ZERO string
@@ -134,33 +134,27 @@ def _opt(options, key: str, default):
 
 
 def resolve_join_engine(options=None, rows: int = 0) -> str:
-    """'numpy' | 'xla' | 'pallas'. Resolution mirrors the merge kernels
+    """'numpy' | 'xla'. Resolution mirrors the merge kernels
     (core/mergefn.effective_sort_engine): the PAIMON_TPU_JOIN_ENGINE env
     (test forcing knob) beats the `join.engine` option beats auto. Auto
     keeps small joins on the host lexsort (dispatch overhead dominates) and
     CPU-only platforms host-side unless PAIMON_TPU_FORCE_DEVICE_ENGINE
-    pins the device path; the device flavor follows the table's sort-engine
-    choice so `sort-engine=pallas` carries into the join sort."""
+    pins the device path."""
     env = os.environ.get("PAIMON_TPU_JOIN_ENGINE", "").strip().lower()
     choice = env or _opt(options, "join.engine", "auto")
     if choice in ("xla", "xla-segmented"):
         return "xla"
-    if choice in ("numpy", "pallas"):
+    if choice == "numpy":
         return choice
-    # auto
+    if choice != "auto":
+        raise ValueError(f"join.engine: {choice!r} is not one of 'auto', 'numpy', 'xla'")
     if rows < _opt(options, "join.device-rows", 4096):
         return "numpy"
     from .merge import resolved_platform_is_cpu
 
     if resolved_platform_is_cpu() and os.environ.get("PAIMON_TPU_FORCE_DEVICE_ENGINE", "") != "1":
         return "numpy"
-    return _device_flavor(options)
-
-
-def _device_flavor(options) -> str:
-    sort_env = os.environ.get("PAIMON_TPU_SORT_ENGINE", "").strip().lower()
-    choice = _opt(options, "sort-engine", "") or sort_env
-    return "pallas" if choice == "pallas" else "xla"
+    return "xla"
 
 
 # ---------------------------------------------------------------------------
@@ -372,7 +366,7 @@ def _sortmerge_pairs(ll: np.ndarray, rl: np.ndarray, engine: str):
     else:
         from .merge import _merge_plan_padded
 
-        plan = _merge_plan_padded(joint, side[:, None], None, engine if engine == "pallas" else "xla")
+        plan = _merge_plan_padded(joint, side[:, None])
         perm = plan.perm[:n].astype(np.int64)
         seg = plan.seg_id[:n].astype(np.int64)
     is_left = perm >= n_r

@@ -124,9 +124,7 @@ def _fetch(arr, length: int, row: int | None = None) -> tuple[np.ndarray, int]:
     return host[..., :length], host.nbytes
 
 
-def sorted_segments(
-    num_key_lanes: int, num_seq_lanes: int, key_lanes, seq_lanes, pad_flag, extra_keys=(), engine: str = "xla"
-):
+def sorted_segments(num_key_lanes: int, num_seq_lanes: int, key_lanes, seq_lanes, pad_flag, extra_keys=()):
     """The shared in-kernel preamble (traced inside each jitted kernel): one
     stable lexicographic sort on (pad, key lanes, seq lanes, iota), then
     segment detection over (pad, key lanes) only — sequence lanes do NOT
@@ -142,13 +140,7 @@ def sorted_segments(
     tested FIRST in boundary detection. An extra key must satisfy the OVC
     contract — where it differs it agrees with full-key order, where it ties
     the key lanes decide — so both the permutation and the segmentation stay
-    bit-identical to the plain path.
-
-    engine="pallas" is the sort-engine=pallas seam every merge kernel
-    inherits: the sort stays `lax.sort` and the boundary mask comes from the
-    pallas sweep kernel (ops/pallas_kernels.keep_last_mask), bit-identical
-    to the plain path. A kernel the compiler refuses raises — the engine
-    never quietly runs another."""
+    bit-identical to the plain path."""
     m = pad_flag.shape[0]
     extra = list(extra_keys)
     boundary = [pad_flag] + extra + [key_lanes[i] for i in range(num_key_lanes)]
@@ -158,21 +150,6 @@ def sorted_segments(
     with jax.named_scope("merge.sort"):
         out = jax.lax.sort(operands, num_keys=len(operands) - 1, is_stable=True)
     perm = out[-1]
-    if engine == "pallas":
-        # lax.sort + the pallas boundary sweep (narrowed lanes may be
-        # u8/u16 — widening on device costs nothing)
-        from .pallas_kernels import keep_last_mask, pallas_interpret
-
-        with jax.named_scope("merge.segment"):
-            stacked = jnp.stack(
-                [lane.astype(jnp.uint32) for lane in out[: len(boundary)]], axis=0
-            )
-            keep_last = keep_last_mask(stacked, interpret=pallas_interpret(), mask_pad=False).astype(
-                jnp.bool_
-            )
-            seg_start = jnp.concatenate([jnp.ones((1,), jnp.bool_), keep_last[:-1]])
-            seg_id = jnp.cumsum(seg_start.astype(jnp.int32)) - 1
-        return out[0], perm, seg_start, keep_last, seg_id
     with jax.named_scope("merge.segment"):
         neq = jnp.zeros(m - 1, dtype=jnp.bool_)
         for lane in out[: len(boundary)]:
@@ -395,11 +372,10 @@ def prepare_lanes_planned(
 
 
 @functools.lru_cache(maxsize=None)
-def _plan_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0, engine: str = "xla"):
+def _plan_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0):
     """Builds the jitted sort+segment kernel for a lane arity. ovc_vbits > 0
     adds the device-computed offset-value code as the leading key (and the
-    base values as a traced (G,) operand). engine routes the preamble
-    through the sort-engine seam (pallas = lax.sort + pallas boundary sweep)."""
+    base values as a traced (G,) operand)."""
     if ovc_vbits:
         from .lanes import ovc_codes_jax
 
@@ -410,7 +386,7 @@ def _plan_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0, engine:
             )
             _, perm, seg_start, keep_last, seg_id = sorted_segments(
                 num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag,
-                extra_keys=(code,), engine=engine,
+                extra_keys=(code,),
             )
             return perm, seg_start, keep_last, seg_id
 
@@ -421,7 +397,7 @@ def _plan_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0, engine:
         # key/seq lanes: (K, m)/(S, m) arrays OR lists of (m,) mixed-dtype
         # uint arrays (narrowed upload); pad_flag: (m,) uint
         _, perm, seg_start, keep_last, seg_id = sorted_segments(
-            num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag, engine=engine
+            num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag
         )
         return perm, seg_start, keep_last, seg_id
 
@@ -478,7 +454,6 @@ def merge_plan(
     key_lanes: np.ndarray,
     seq_lanes: np.ndarray | None = None,
     compress: bool | None = None,
-    engine: str = "xla",
 ) -> MergePlan:
     """key_lanes: (n, K) uint32. seq_lanes: (n, S) uint32 ordering within a
     key group (user-defined sequence lanes first, then sequence-number lanes —
@@ -507,7 +482,7 @@ def merge_plan(
         # sort dispatched at all (the old path kept a dummy constant lane
         # "for shape sanity" and sorted it anyway)
         return _scalar_plan(key_lanes.shape[0])
-    return _merge_plan_padded(kl_kept, seq_keep, plan, engine)
+    return _merge_plan_padded(kl_kept, seq_keep, plan)
 
 
 def _scalar_plan(n: int) -> MergePlan:
@@ -528,9 +503,7 @@ def _scalar_plan(n: int) -> MergePlan:
     return MergePlan(perm=perm, seg_start=seg_start, keep_last=keep_last, seg_id=seg_id, n=n, m=m)
 
 
-def _merge_plan_padded(
-    key_lanes: np.ndarray, seq_lanes: np.ndarray | None, plan=None, engine: str = "xla"
-) -> MergePlan:
+def _merge_plan_padded(key_lanes: np.ndarray, seq_lanes: np.ndarray | None, plan=None) -> MergePlan:
     n, k = key_lanes.shape
     if seq_lanes is None:
         seq_lanes = np.zeros((n, 0), dtype=np.uint32)
@@ -543,32 +516,20 @@ def _merge_plan_padded(
     pad = np.zeros(m, dtype=np.uint32)
     pad[n:] = 1
     use_ovc = plan is not None and plan.use_ovc
-    timer = None
-    if engine == "pallas":
-        from ..metrics import pallas_metrics, timed
-        from .pallas_kernels import note_dispatch
-
-        note_dispatch(m)
-        # this path resolves synchronously just below (np.asarray), so the
-        # wall time around dispatch+download is the kernel latency
-        timer = timed(pallas_metrics().histogram("kernel_ms"))
-        timer.__enter__()
     merge_metrics().counter("merges").inc()
     with span("merge.dispatch", rows=n):
         if use_ovc:
             # this path uploads unshifted u32 lanes, so the packed-space base
             # passes through unshifted too
             operands = (kl, sl, pad, np.asarray(plan.base, dtype=np.uint32))
-            fn = _plan_fn(k, s, plan.ovc_vbits, engine)
+            fn = _plan_fn(k, s, plan.ovc_vbits)
         else:
-            operands, fn = (kl, sl, pad), _plan_fn(k, s, 0, engine)
+            operands, fn = (kl, sl, pad), _plan_fn(k, s)
         _count_kernel(operands, n, m)
         outs = fn(*operands)
     with span("merge.resolve"):
         perm, seg_start, keep_last, seg_id = (np.asarray(o) for o in outs)
         _count_download(_nbytes((perm, seg_start, keep_last, seg_id)), 0)
-    if timer is not None:
-        timer.__exit__(None, None, None)
     return MergePlan(perm=perm, seg_start=seg_start, keep_last=keep_last, seg_id=seg_id, n=n, m=m)
 
 
@@ -580,15 +541,12 @@ def deduplicate_take(plan: MergePlan) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=None)
-def _dedup_select_fn(num_key_lanes: int, num_seq_lanes: int, backend: str = "xla", ovc_vbits: int = 0):
+def _dedup_select_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0):
     """Sort + keep-last + device-side compaction: returns ONLY the selected
     input indices (packed to the front) and their count — the minimal
-    device->host transfer for the dominant dedup path. backend="pallas"
-    computes the boundary mask with the pallas sweep kernel through the
-    sorted_segments seam;
-    ovc_vbits > 0 computes the offset-value code lane on device and leads
-    the sort + boundary detection with it (ops/lanes.py) — composing with
-    either engine."""
+    device->host transfer for the dominant dedup path. ovc_vbits > 0
+    computes the offset-value code lane on device and leads the sort +
+    boundary detection with it (ops/lanes.py)."""
     if ovc_vbits:
         from .lanes import ovc_codes_jax
 
@@ -599,7 +557,7 @@ def _dedup_select_fn(num_key_lanes: int, num_seq_lanes: int, backend: str = "xla
             )
             pad_sorted, perm, _, keep_last, _ = sorted_segments(
                 num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag,
-                extra_keys=(code,), engine=backend,
+                extra_keys=(code,),
             )
             return pack_selected(keep_last & (pad_sorted == 0), perm)
 
@@ -608,7 +566,7 @@ def _dedup_select_fn(num_key_lanes: int, num_seq_lanes: int, backend: str = "xla
     @_jit("dedup_select")
     def f(key_lanes, seq_lanes, pad_flag):
         pad_sorted, perm, _, keep_last, _ = sorted_segments(
-            num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag, engine=backend
+            num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag
         )
         sel = keep_last & (pad_sorted == 0)  # exclude pad rows
         return pack_selected(sel, perm)
@@ -619,7 +577,6 @@ def _dedup_select_fn(num_key_lanes: int, num_seq_lanes: int, backend: str = "xla
 def deduplicate_select_async(
     key_lanes: np.ndarray,
     seq_lanes: np.ndarray | None = None,
-    backend: str = "xla",
     compress: bool | None = None,
     plan=None,
 ):
@@ -632,10 +589,10 @@ def deduplicate_select_async(
     dispatch."""
     with span("merge.dispatch", rows=len(key_lanes)):
         if len(key_lanes) > _STREAM_TILE_ROWS:
-            handle = _stream_dispatch(key_lanes, seq_lanes, backend, compress, plan)
+            handle = _stream_dispatch(key_lanes, seq_lanes, compress, plan)
             if handle is not None:
                 return handle
-        return _select_async(key_lanes, seq_lanes, backend, compress, merges=1, plan=plan)
+        return _select_async(key_lanes, seq_lanes, compress, merges=1, plan=plan)
 
 
 # A merge of more rows than this runs as key-range tiles of this one padded
@@ -648,7 +605,7 @@ def deduplicate_select_async(
 _STREAM_TILE_ROWS = 1 << 17
 
 
-def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None, plan=None):
+def _stream_dispatch(key_lanes, seq_lanes, compress: bool | None, plan=None):
     """Key-range tiles of an input in ANY row order, all of the padded shape
     (_STREAM_TILE_ROWS,) and of u32 lanes, so every tile of every merge with
     the same lane arity is the same program. Tiles cut the key space on the
@@ -678,7 +635,7 @@ def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None, 
     order = np.argsort(tile_of, kind="stable").astype(np.int32)  # radix: O(n)
     use_ovc = plan is not None and plan.use_ovc
     k, s, m = lanes.shape[1], 0 if seqs is None else seqs.shape[1], _STREAM_TILE_ROWS
-    fn = _dedup_select_fn(k, s, backend, plan.ovc_vbits if use_ovc else 0)
+    fn = _dedup_select_fn(k, s, plan.ovc_vbits if use_ovc else 0)
     base = (np.asarray(plan.base, dtype=np.uint32),) if use_ovc else ()
     merge_metrics().counter("merges").inc()
     handles, at = [], 0
@@ -691,17 +648,13 @@ def _stream_dispatch(key_lanes, seq_lanes, backend: str, compress: bool | None, 
         slp = [pad_to(seqs[rows, i], m) for i in range(s)]
         pad = np.zeros(m, dtype=np.uint8)
         pad[size:] = 1
-        if backend == "pallas":
-            from .pallas_kernels import note_dispatch
-
-            note_dispatch(m)
         operands = (klp, slp, pad) + base
         _count_kernel(operands, size, m)
         handles.append((fn(*operands), rows))  # async: the next tile assembles while this sorts
     return ("stream", handles)
 
 
-def _select_async(key_lanes, seq_lanes, backend: str, compress: bool | None, merges: int = 0, plan=None):
+def _select_async(key_lanes, seq_lanes, compress: bool | None, merges: int = 0, plan=None):
     """deduplicate_select_async without its span; `merges` is 1 where the
     call is a whole merge and 0 where it is one tile of a merge that its
     caller counts."""
@@ -713,15 +666,11 @@ def _select_async(key_lanes, seq_lanes, backend: str, compress: bool | None, mer
 
         return ("scalar", scalar_dedup_winner(seq_lanes, n))
     use_ovc = plan is not None and plan.use_ovc
-    if backend == "pallas":
-        from .pallas_kernels import note_dispatch
-
-        note_dispatch(m)
     if use_ovc:
         operands = (klp, slp, pad, np.asarray(plan.base, dtype=np.uint32))
-        fn = _dedup_select_fn(k, s, backend, plan.ovc_vbits)
+        fn = _dedup_select_fn(k, s, plan.ovc_vbits)
     else:
-        operands, fn = (klp, slp, pad), _dedup_select_fn(k, s, backend)
+        operands, fn = (klp, slp, pad), _dedup_select_fn(k, s)
     merge_metrics().counter("merges").inc(merges)
     _count_kernel(operands, n, m)
     return fn(*operands)
@@ -766,12 +715,11 @@ def _pad_starts(starts_real: Sequence[int], m: int) -> np.ndarray:
 
 
 @functools.lru_cache(maxsize=None)
-def _dedup_select_compact_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0, engine: str = "xla"):
+def _dedup_select_compact_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: int = 0):
     """Sort + keep-last + compact-encoded selection: the downlink-minimal
     dedup kernel (bit-packed keep-mask + run-id interleave instead of int32
     indices). ovc_vbits > 0 leads sort + boundary detection with the
-    device-computed offset-value code lane; engine routes the preamble
-    through the sort-engine seam."""
+    device-computed offset-value code lane."""
     if ovc_vbits:
         from .lanes import ovc_codes_jax
 
@@ -782,7 +730,7 @@ def _dedup_select_compact_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: 
             )
             pad_sorted, perm, _, keep_last, _ = sorted_segments(
                 num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag,
-                extra_keys=(code,), engine=engine,
+                extra_keys=(code,),
             )
             return pack_selection_compact(keep_last & (pad_sorted == 0), perm, starts)
 
@@ -791,7 +739,7 @@ def _dedup_select_compact_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: 
     @_jit("dedup_select_compact")
     def f(key_lanes, seq_lanes, pad_flag, starts):
         pad_sorted, perm, _, keep_last, _ = sorted_segments(
-            num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag, engine=engine
+            num_key_lanes, num_seq_lanes, key_lanes, seq_lanes, pad_flag
         )
         sel = keep_last & (pad_sorted == 0)
         return pack_selection_compact(sel, perm, starts)
@@ -799,9 +747,7 @@ def _dedup_select_compact_fn(num_key_lanes: int, num_seq_lanes: int, ovc_vbits: 
     return f
 
 
-def deduplicate_select_compact_async(
-    key_lanes: np.ndarray, run_offsets: Sequence[int], compress: bool | None = None, backend: str = "xla"
-):
+def deduplicate_select_compact_async(key_lanes: np.ndarray, run_offsets: Sequence[int], compress: bool | None = None):
     """Compact-download dispatch for run-structured inputs (each run
     key-sorted ascending). Returns an opaque handle for
     deduplicate_resolve(), or None above 256 runs (run-ids are u8 on
@@ -818,15 +764,11 @@ def deduplicate_select_compact_async(
         return ("scalar", scalar_dedup_winner(None, n))
     starts_p = _pad_starts(starts_real, m)
     use_ovc = plan is not None and plan.use_ovc
-    if backend == "pallas":
-        from .pallas_kernels import note_dispatch
-
-        note_dispatch(m)
     if use_ovc:
         operands = (klp, slp, pad, starts_p, np.asarray(plan.base, dtype=np.uint32))
-        fn = _dedup_select_compact_fn(k, s, plan.ovc_vbits, backend)
+        fn = _dedup_select_compact_fn(k, s, plan.ovc_vbits)
     else:
-        operands, fn = (klp, slp, pad, starts_p), _dedup_select_compact_fn(k, s, 0, backend)
+        operands, fn = (klp, slp, pad, starts_p), _dedup_select_compact_fn(k, s)
     _count_kernel(operands, n, m)
     return ("compact", fn(*operands), n, len(starts_real), _runid_bits(len(starts_p)))
 
@@ -881,7 +823,7 @@ def _delta_reconstruct_lane(deltas, starts, bases, pad_flag):
 
 
 @functools.lru_cache(maxsize=None)
-def _dedup_select_delta_fn(backend: str = "xla"):
+def _dedup_select_delta_fn():
     """The dedup kernel for delta-packed single-lane keys: reconstruct the
     u32 lane on device (cumsum + per-run rebase), then the standard
     sort + keep-last epilogue with the compact-encoded download."""
@@ -889,7 +831,7 @@ def _dedup_select_delta_fn(backend: str = "xla"):
     @_jit("dedup_select_delta")
     def f(deltas, starts, bases, pad_flag):
         lane = _delta_reconstruct_lane(deltas, starts, bases, pad_flag)
-        pad_sorted, perm, _, keep_last, _ = sorted_segments(1, 0, [lane], [], pad_flag, engine=backend)
+        pad_sorted, perm, _, keep_last, _ = sorted_segments(1, 0, [lane], [], pad_flag)
         sel = keep_last & (pad_sorted == 0)
         return pack_selection_compact(sel, perm, starts)
 
@@ -897,7 +839,7 @@ def _dedup_select_delta_fn(backend: str = "xla"):
 
 
 @functools.lru_cache(maxsize=None)
-def _dedup_select_delta_wide_fn(backend: str = "xla"):
+def _dedup_select_delta_wide_fn():
     """Delta-packed UPLOAD with the legacy index DOWNLOAD (pack_selected):
     keeps the halved uplink bytes when the compact download encoding is
     unavailable — run counts past its u8 run-id limit (>256)."""
@@ -905,55 +847,48 @@ def _dedup_select_delta_wide_fn(backend: str = "xla"):
     @_jit("dedup_select_delta_wide")
     def f(deltas, starts, bases, pad_flag):
         lane = _delta_reconstruct_lane(deltas, starts, bases, pad_flag)
-        pad_sorted, perm, _, keep_last, _ = sorted_segments(1, 0, [lane], [], pad_flag, engine=backend)
+        pad_sorted, perm, _, keep_last, _ = sorted_segments(1, 0, [lane], [], pad_flag)
         sel = keep_last & (pad_sorted == 0)
         return pack_selected(sel, perm)
 
     return f
 
 
-def deduplicate_select_delta_async(key_lanes: np.ndarray, run_offsets: Sequence[int], backend: str = "xla"):
+def deduplicate_select_delta_async(key_lanes: np.ndarray, run_offsets: Sequence[int]):
     """Delta-packed dispatch for single-lane run-sorted keys; None when the
     lane does not qualify (multi-lane, non-ascending, sparse deltas, or a
     range the u16 narrowing already covers). Above 256 runs the upload
     stays delta-packed but the download falls back to packed indices
-    (_dedup_select_delta_wide_fn). Both downloads route the sort+boundary
-    preamble through the sort-engine seam."""
+    (_dedup_select_delta_wide_fn)."""
     if key_lanes.shape[1] != 1:
         return None
     packed = pack_delta_runs(key_lanes[:, 0], run_offsets)
     if packed is None:
         return None
     deltas, starts, bases, pad, n, m, num_runs = packed
-    if backend == "pallas":
-        from .pallas_kernels import note_dispatch
-
-        note_dispatch(m)
     _count_kernel((deltas, starts, bases, pad), n, m)
     if num_runs > 256:
-        return _dedup_select_delta_wide_fn(backend)(deltas, starts, bases, pad)
-    outs = _dedup_select_delta_fn(backend)(deltas, starts, bases, pad)
+        return _dedup_select_delta_wide_fn()(deltas, starts, bases, pad)
+    outs = _dedup_select_delta_fn()(deltas, starts, bases, pad)
     return ("compact", outs, n, num_runs, _runid_bits(len(starts)))
 
 
-def _dedup_dispatch(key_lanes: np.ndarray, run_offsets: Sequence[int], backend: str):
+def _dedup_dispatch(key_lanes: np.ndarray, run_offsets: Sequence[int]):
     """One dispatch-policy site: delta-packed upload when it qualifies,
     compact (bit-packed) download when the run count allows, wide
     index-download otherwise. On the CPU backend every encoding is skipped
     (_link_encodings_pay_off): there are no link bytes to save. Callers
     (the tiled dispatcher) have already run the lane-compression seam, so
     every path here suppresses it (compress=False) — plans are made once
-    per merge, not once per tile. The sort-engine seam (backend) composes
-    with every encoding: the link format is independent of which kernel
-    computes the sort + boundary."""
+    per merge, not once per tile."""
     if not _link_encodings_pay_off():
-        return _select_async(key_lanes, None, backend, False)
-    handle = deduplicate_select_delta_async(key_lanes, run_offsets, backend=backend)
+        return _select_async(key_lanes, None, False)
+    handle = deduplicate_select_delta_async(key_lanes, run_offsets)
     if handle is not None:
         return handle
-    handle = deduplicate_select_compact_async(key_lanes, run_offsets, compress=False, backend=backend)
+    handle = deduplicate_select_compact_async(key_lanes, run_offsets, compress=False)
     if handle is None:  # >256 runs: index-download fallback
-        handle = _select_async(key_lanes, None, backend, False)
+        handle = _select_async(key_lanes, None, False)
     return handle
 
 
@@ -994,7 +929,6 @@ def deduplicate_select_tiled(
     key_lanes: np.ndarray,
     run_offsets: Sequence[int],
     tile_rows: int = 256 * 1024,
-    backend: str = "xla",
     compress: bool | None = None,
 ) -> np.ndarray:
     """Key-range tiled dedup for runs concatenated in ascending-seq order
@@ -1009,7 +943,7 @@ def deduplicate_select_tiled(
     spills via MergeSorter :110-116; we tile by key range instead).
     Returns selected input-row indices in global key order."""
     return deduplicate_resolve_tiled(
-        deduplicate_tiled_dispatch(key_lanes, run_offsets, tile_rows, backend, compress=compress)
+        deduplicate_tiled_dispatch(key_lanes, run_offsets, tile_rows, compress=compress)
     )
 
 
@@ -1085,7 +1019,6 @@ def deduplicate_tiled_dispatch(
     key_lanes: np.ndarray,
     run_offsets: Sequence[int],
     tile_rows: int = 256 * 1024,
-    backend: str = "xla",
     compress: bool | None = None,
     plan=None,
 ):
@@ -1102,10 +1035,10 @@ def deduplicate_tiled_dispatch(
     than the device budget stream through as equal-shaped chunks (the
     reference spills to disk instead: MergeSorter.java:110-116)."""
     with span("merge.dispatch", rows=len(key_lanes)):
-        return _tiled_dispatch(key_lanes, run_offsets, tile_rows, backend, compress, plan)
+        return _tiled_dispatch(key_lanes, run_offsets, tile_rows, compress, plan)
 
 
-def _tiled_dispatch(key_lanes, run_offsets, tile_rows: int, backend: str, compress: bool | None, plan=None):
+def _tiled_dispatch(key_lanes, run_offsets, tile_rows: int, compress: bool | None, plan=None):
     n = key_lanes.shape[0]
     offsets = list(run_offsets)
     if n == 0:
@@ -1122,17 +1055,15 @@ def _tiled_dispatch(key_lanes, run_offsets, tile_rows: int, backend: str, compre
         return [(("scalar", scalar_dedup_winner(None, n)), np.arange(n, dtype=np.int32))]
     merge_metrics().counter("merges").inc()
     if n <= tile_rows or len(offsets) < 3:
-        return [(_dedup_dispatch(key_lanes, offsets, backend), np.arange(n, dtype=np.int32))]
+        return [(_dedup_dispatch(key_lanes, offsets), np.arange(n, dtype=np.int32))]
     lane0_runs = [key_lanes[offsets[r] : offsets[r + 1], 0] for r in range(len(offsets) - 1)]
     num_tiles = max(2, (n + tile_rows - 1) // tile_rows)
     boundaries = _tile_boundaries(lane0_runs, num_tiles)
     tiles = _gather_tiles(key_lanes, offsets, lane0_runs, boundaries)
-    if len(tiles) == 1 or backend == "pallas":
-        # pallas epilogue is benchmarked per-tile; a single tile needs no batch
-        handles = []
-        for tile_lanes, tile_global in tiles:
-            handles.append((_dedup_dispatch(tile_lanes, [0, len(tile_lanes)], backend), tile_global))
-        return handles
+    if len(tiles) == 1:
+        # a single tile needs no batch
+        tile_lanes, tile_global = tiles[0]
+        return [(_dedup_dispatch(tile_lanes, [0, len(tile_lanes)]), tile_global)]
 
     k = key_lanes.shape[1]
     m = pad_size(max(t[0].shape[0] for t in tiles))
@@ -1234,7 +1165,7 @@ def _partial_update_fn():
 
 
 @functools.lru_cache(maxsize=None)
-def _fused_partial_update_fn(num_key: int, num_seq: int, num_fields: int, engine: str = "xla"):
+def _fused_partial_update_fn(num_key: int, num_seq: int, num_fields: int):
     """Sort + segment + partial-update selection in ONE kernel: the plan never
     leaves the device. It returns sorted positions, each key's in key order:
     per field the position of the key's winning row (-1 where the field
@@ -1255,7 +1186,7 @@ def _fused_partial_update_fn(num_key: int, num_seq: int, num_fields: int, engine
     @_jit("fused_partial_update")
     def f(key_lanes, seq_lanes, pad_flag, field_valid, is_add, is_delete):
         pad_sorted, perm, seg_start, keep_last, _ = sorted_segments(
-            num_key, num_seq, key_lanes, seq_lanes, pad_flag, engine=engine
+            num_key, num_seq, key_lanes, seq_lanes, pad_flag
         )
         pos = jnp.arange(perm.shape[0], dtype=jnp.int32)
         ends, count = pack_selected(keep_last & (pad_sorted == 0), pos)
@@ -1289,7 +1220,6 @@ def fused_partial_update(
     row_kind: np.ndarray,  # (n,) uint8
     remove_record_on_delete: bool = False,
     compress: bool | None = None,
-    engine: str = "xla",
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Single-call partial-update merge: returns (src (F, k), exists (k,),
     last_take (k,)) in key order — the same contract as
@@ -1317,14 +1247,10 @@ def fused_partial_update(
         with span("pu.valid", rows=n, fields=F):
             fv = np.zeros((F, m), dtype=np.bool_)
             fv[:, :n] = field_valid
-        if engine == "pallas":
-            from .pallas_kernels import note_dispatch
-
-            note_dispatch(m)
         merge_metrics().counter("merges").inc()
         operands = (klp, slp, pad, fv, pad_to(is_add, m, False), pad_to(is_delete, m, False))
         _count_kernel(operands, n, m)
-        src, exists, ends, perm, count = _fused_partial_update_fn(k, s, F, engine)(*operands)
+        src, exists, ends, perm, count = _fused_partial_update_fn(k, s, F)(*operands)
     with span("merge.resolve"):
         kk = int(count)
         (src_np, src_nbytes), (exists_np, exists_nbytes), (ends_np, ends_nbytes), (perm_np, perm_nbytes) = (

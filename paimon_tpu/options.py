@@ -113,7 +113,11 @@ class ConfigOption(Generic[T]):
         def parse(v):
             if isinstance(v, enum_cls):
                 return v
-            return enum_cls(str(v).lower().replace("_", "-"))
+            try:
+                return enum_cls(str(v).lower().replace("_", "-"))
+            except ValueError:
+                allowed = ", ".join(repr(e.value) for e in enum_cls)
+                raise ValueError(f"{key}: {v!r} is not one of {allowed}") from None
 
         return ConfigOption(key, default, parse, description, fallback)
 
@@ -194,7 +198,6 @@ class ChangelogProducer(str, enum.Enum):
 
 class SortEngine(str, enum.Enum):
     XLA_SEGMENTED = "xla-segmented"  # device sort+segment-reduce (default)
-    PALLAS = "pallas"  # lax.sort + pallas boundary/keep-last sweep kernel
     NUMPY = "numpy"  # host oracle
 
 
@@ -330,7 +333,14 @@ class CoreOptions:
             "partial-update.ignore-delete",
         ),
     )
-    SORT_ENGINE = ConfigOption.enum("sort-engine", SortEngine, SortEngine.XLA_SEGMENTED, "Merge kernel backend.")
+    SORT_ENGINE = ConfigOption.enum(
+        "sort-engine",
+        SortEngine,
+        SortEngine.XLA_SEGMENTED,
+        "Merge kernel backend: 'xla-segmented' (the device sort and segment "
+        "kernels) or 'numpy' (the host lexsort oracle). Left unset, a "
+        "CPU-only platform merges on the host.",
+    )
     MERGE_LANE_COMPRESSION = ConfigOption.bool_(
         "merge.lane-compression",
         True,
@@ -368,17 +378,16 @@ class CoreOptions:
         "auto",
         "Equi-join kernel: 'hash' probes a sorted single-operand key by "
         "binary search, 'sort-merge' routes multi-operand keys through the "
-        "merge kernel's sorted_segments seam (inheriting sort-engine=pallas), "
+        "merge kernel's sorted_segments seam, "
         "'auto' picks hash exactly when the global lane plan packed the key "
         "into one fused uint32 operand.",
     )
     JOIN_ENGINE = ConfigOption.string(
         "join.engine",
         "auto",
-        "Join execution backend: 'numpy' (host lexsort/searchsorted), 'xla' "
-        "or 'pallas' (device kernels). 'auto' mirrors the merge rule — host "
-        "below join.device-rows or on a CPU-only platform, device otherwise, "
-        "with the device flavor following sort-engine. "
+        "Join execution backend: 'numpy' (host lexsort/searchsorted) or "
+        "'xla' (device kernels). 'auto' mirrors the merge rule — host below "
+        "join.device-rows or on a CPU-only platform, device otherwise. "
         "PAIMON_TPU_JOIN_ENGINE overrides.",
     )
     JOIN_DEVICE_ROWS = ConfigOption.int_(

@@ -33,8 +33,8 @@ queues below cover all nine, four kinds); every death is respawned and
 journal-recovered per the PR 9/15 protocol. The scenario matrix axes:
 schema shape (bigint k/v, dict-string PK, wide mixed), bucket mode (fixed
 + dynamic), branches/tags, consumer expiry, CDC wire format, and engine
-toggles (pallas sort, mesh merge, dict-domain merge, native manifest
-codec, lane compression off).
+toggles (mesh merge, dict-domain merge, native manifest codec, lane
+compression off).
 
 End of each cell, on the HEALED store: one fold_landed_rounds call over
 every plane's journals (user_prefix is a tuple — direct, cluster, and
@@ -45,8 +45,8 @@ fold == pinned scan at each checkpoint, a quiesced SQL bit-identity
 battery (gateway SQL twice + local query once, byte-equal), tag/branch
 time travel vs the fold-up-to-tag, and consumer-expiry liveness. The run
 verdict is the AND over cells, plus per-plane counters and a metric-group
-census (io/soak/get/sub/cluster/sql/gateway/compaction/dict/pallas must
-all be nonzero somewhere in the matrix).
+census (io/soak/get/sub/cluster/sql/gateway/compaction/dict must all be
+nonzero somewhere in the matrix).
 
 Run directly:  python -m paimon_tpu.service.mega_soak [base_dir] [flags]
 Child roles:   python -m paimon_tpu.service.mega_soak gateway-writer|getter|sql-client ...
@@ -118,7 +118,7 @@ DEFAULT_MEGA_KILLS = (
 # metric groups the matrix must tick (the acceptance census)
 METRIC_GROUPS = (
     "io", "soak", "get", "sub", "cluster", "sql",
-    "gateway", "compaction", "dict", "pallas",
+    "gateway", "compaction", "dict",
 )
 
 
@@ -179,16 +179,16 @@ DEFAULT_MATRIX = (
         consumer_expiry=True,
         table_options=(("merge.dict-domain", "true"),),
     ),
-    # wide mixed schema (float + dict-string + int columns), pallas sort
-    # engine, native manifest codec, maxwell wire format
+    # wide mixed schema (float + dict-string + int columns), avro
+    # manifests, maxwell wire format
     MegaScenario(
-        name="wide-pallas",
+        name="wide",
         schema="wide",
         bucket=2,
         cdc_format="maxwell-json",
         direct_writers=0,
         gateway_writers=2,
-        table_options=(("sort-engine", "pallas"), ("manifest.format", "avro")),
+        table_options=(("manifest.format", "avro"),),
     ),
     # engine-toggle contrast: numpy sort, lane compression off, plain json
     # wire format, cluster plane on a second kv table

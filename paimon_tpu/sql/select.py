@@ -240,27 +240,16 @@ def parse_select(statement: str) -> SelectPlan:
 
 
 def _engine_for(table) -> str:
-    """Engine for the SQL segment-reduce: an explicit sort-engine choice
-    (table option or PAIMON_TPU_SORT_ENGINE) is honored; with no explicit
-    choice the jitted XLA kernel runs everywhere — unlike the 1M-row merge
-    sort, the group-by reduce's operands are a handful of uint32 lanes, so
-    the CPU-adaptive lexsort default of effective_sort_engine would only
-    forfeit the device path the distributed plane is built around."""
-    import os
+    """Engine for the SQL segment-reduce: "numpy" where the table set
+    sort-engine=numpy, the jitted XLA kernel everywhere else — unlike the
+    1M-row merge sort, the group-by reduce's operands are a handful of
+    uint32 lanes, so the CPU-adaptive lexsort default of
+    effective_sort_engine would only forfeit the device path the
+    distributed plane is built around."""
+    from ..options import CoreOptions, SortEngine
 
-    try:
-        from ..options import CoreOptions
-
-        opts = table.store.options
-        if opts.options.contains(CoreOptions.SORT_ENGINE):
-            name = str(opts.sort_engine).lower()
-        else:
-            name = os.environ.get("PAIMON_TPU_SORT_ENGINE", "").strip().lower() or "xla"
-    except Exception:
-        name = "xla"
-    if "pallas" in name:
-        return "pallas"
-    if "numpy" in name:
+    opts = table.store.options
+    if opts.options.contains(CoreOptions.SORT_ENGINE) and opts.sort_engine == SortEngine.NUMPY:
         return "numpy"
     return "xla"
 

@@ -48,11 +48,7 @@ def sort_compact(
     # CPU-only backend: clustering is a plain stable sort of the curve
     # codes — the host lexsort wins (same adaptive rule as merge reads,
     # mergefn.effective_sort_engine); resolved once for the whole call
-    effective_engine = store.merge_executor().effective_sort_engine()
-    use_host_sort = effective_engine == SortEngine.NUMPY
-    # sort-engine=pallas: the clustering sort inherits the fused kernel
-    # through the same sorted_segments seam as every merge
-    kernel_engine = "pallas" if effective_engine == SortEngine.PALLAS else "xla"
+    use_host_sort = store.merge_executor().effective_sort_engine() == SortEngine.NUMPY
     jobs = [
         (partition, bucket, files)
         for partition, buckets in plan.grouped().items()
@@ -133,7 +129,7 @@ def sort_compact(
             perm = lexsort_rows(sort_lanes)
         elif perm is None:
             # device sort; stability keeps arrival order on ties
-            p = merge_plan(lanes, compress=compress, engine=kernel_engine)
+            p = merge_plan(lanes, compress=compress)
             perm = p.perm[p.valid_sorted]
         sorted_kv = kv.take(perm)
         wf = store.writer_factory(partition, bucket)

@@ -131,14 +131,6 @@
 #                              native — every natively-written file must
 #                              read back bit-identically through BOTH the
 #                              native decoder and pyarrow.
-#   scripts/verify.sh pallas   pallas sort-engine parity stage: the
-#                              tests/test_pallas_merge.py randomized suite
-#                              plus the merge-kernel + whole-store oracles
-#                              run TWICE — PAIMON_TPU_SORT_ENGINE forced
-#                              pallas (interpret mode on CPU), then
-#                              xla-segmented — so the pallas sweep kernel
-#                              and the stock XLA path both prove
-#                              bit-identical merge output end to end.
 #   scripts/verify.sh gateway  multi-tenant gateway stage: the gateway
 #                              suite (per-tenant admission, typed-shed
 #                              canonicalization, hedged reads + loser
@@ -216,14 +208,12 @@ if [ "${1:-}" = "pipeline" ]; then
 fi
 
 if [ "${1:-}" = "faults" ]; then
-  # mesh engine + code-domain merge + pallas sort engine forced ON: the
-  # fault matrix (transient retries, crash points, torn writes) must stay
-  # green through the mesh-sharded executor, its feeder workers, the
-  # dictionary-code merge currency, and the pallas sweep kernel on every
-  # single-device merge (ISSUE 7 / ISSUE 10 / ISSUE 11)
+  # mesh engine + code-domain merge forced ON: the fault matrix
+  # (transient retries, crash points, torn writes) must stay green through
+  # the mesh-sharded executor, its feeder workers and the dictionary-code
+  # merge currency
   exec env JAX_PLATFORMS=cpu PAIMON_TPU_FAULT_SEEDS="0 1 2 3 4" PAIMON_TPU_PARQUET_ENCODER=native \
     PAIMON_TPU_LANE_COMPRESSION=1 PAIMON_TPU_MERGE_ENGINE=mesh PAIMON_TPU_DICT_DOMAIN=1 \
-    PAIMON_TPU_SORT_ENGINE=pallas \
     timeout -k 10 600 python -m pytest tests/test_resilience.py tests/test_commit_faults.py \
     tests/test_encode.py::test_native_encoder_under_transient_faults -q \
     -p no:cacheprovider -p no:xdist -p no:randomly
@@ -385,20 +375,6 @@ if [ "${1:-}" = "encode" ]; then
   exec env JAX_PLATFORMS=cpu PAIMON_TPU_PARQUET_ENCODER=native \
     timeout -k 10 600 python -m pytest tests/test_encode.py -q \
     -p no:cacheprovider -p no:xdist -p no:randomly
-fi
-
-if [ "${1:-}" = "pallas" ]; then
-  # parity suites with the sort engine forced pallas (sweep kernel, CPU
-  # via interpret=True), then xla-segmented: both sides of the sort-engine
-  # switch must produce bit-identical merge output (tables that explicitly
-  # chose an engine keep it — the env only pins the undecided)
-  for eng in pallas xla-segmented; do
-    env JAX_PLATFORMS=cpu PAIMON_TPU_SORT_ENGINE=$eng \
-      timeout -k 10 600 python -m pytest tests/test_pallas_merge.py tests/test_pallas.py \
-      tests/test_merge_kernel.py tests/test_randomized_oracle.py -q \
-      -p no:cacheprovider -p no:xdist -p no:randomly || exit $?
-  done
-  exit 0
 fi
 
 rm -f /tmp/_t1.log

@@ -295,7 +295,7 @@ def test_the_aggregation_programs_have_names_of_their_own(program):
         lowered = aggregates._pick_fn(True).lower(perm, ends, valid)
     else:
         lanes = np.zeros((1, m), dtype=np.uint32)
-        lowered = aggregates._fused_aggregate_fn(1, 0, ("sum",), "xla").lower(
+        lowered = aggregates._fused_aggregate_fn(1, 0, ("sum",)).lower(
             lanes, np.zeros((0, m), dtype=np.uint32), np.zeros(m, dtype=np.uint32), (values,), (valid,),
             (np.ones(m, dtype=np.int8),))
     assert f"module @jit_{program} " in lowered.as_text()  # the trace's XLA Modules line and its device events

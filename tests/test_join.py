@@ -4,7 +4,7 @@ vectorized lookups).
 The contract: every join result is BIT-IDENTICAL to an independent host
 oracle (a dict-based nested probe, cross-checked against pandas.merge at
 the SQL level) — across seeds, key skew, null rates, dict/non-dict key
-columns, lane-compression on/off, engines (numpy / xla / pallas) and
+columns, lane-compression on/off, engines (numpy / xla) and
 partitioned skew-split execution — while dict-backed keys actually match
 in the code domain (join{code_domain_joins} > 0, zero string
 materialization on the matched path) and one hot key never serializes a
@@ -74,7 +74,7 @@ SKEWS = {
 }
 
 
-@pytest.mark.parametrize("engine", ["numpy", "xla", "pallas"])
+@pytest.mark.parametrize("engine", ["numpy", "xla"])
 @pytest.mark.parametrize("how", ["inner", "left"])
 @pytest.mark.parametrize("skew", sorted(SKEWS))
 def test_single_int_key_parity(engine, how, skew):
@@ -92,7 +92,7 @@ def test_single_int_key_parity(engine, how, skew):
     assert_join_matches_oracle(left, right, ["k"], ["id"], how, engine=engine)
 
 
-@pytest.mark.parametrize("engine", ["numpy", "xla", "pallas"])
+@pytest.mark.parametrize("engine", ["numpy", "xla"])
 @pytest.mark.parametrize("null_rate", [0.0, 0.25])
 def test_composite_string_int_key_parity(engine, null_rate):
     rng = np.random.default_rng(hash((engine, null_rate)) % (1 << 16))
@@ -692,7 +692,7 @@ def test_randomized_join_oracle(seed, monkeypatch):
     left = ColumnBatch(RowType.of(("s", STRING()),), {"s": lc})
     right = ColumnBatch(RowType.of(("s", STRING()),), {"s": rc})
     how = "left" if seed % 2 else "inner"
-    engine = ["numpy", "xla", "pallas"][seed % 3]
+    engine = "numpy" if seed % 3 == 0 else "xla"
     parts = str(int(rng.integers(1, 5)))
     res = join_batches(
         left, right, ["s"], ["s"], how=how,

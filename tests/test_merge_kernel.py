@@ -556,7 +556,7 @@ def test_compact_selection_exact_order(rng):
     ]
     for case in cases:
         lanes, offsets = _runs_fixture(rng, case["n"], case["runs"], case["key_hi"], case["k"])
-        handle = M._dedup_dispatch(lanes, offsets, backend="xla")
+        handle = M._dedup_dispatch(lanes, offsets)
         got = M.deduplicate_resolve(handle)
         expect = _dedup_oracle(lanes)
         assert got.tolist() == expect.tolist(), case
@@ -567,16 +567,16 @@ def test_compact_selection_edge_shapes(rng):
 
     # empty middle run (filtered-out file)
     lanes = np.array([[5], [9], [1], [9]], dtype=np.uint32)
-    handle = M._dedup_dispatch(lanes, [0, 2, 2, 4], backend="xla")
+    handle = M._dedup_dispatch(lanes, [0, 2, 2, 4])
     assert M.deduplicate_resolve(handle).tolist() == _dedup_oracle(lanes).tolist()
     # all keys equal: one winner, the last input row
     lanes2 = np.full((1000, 1), 7, dtype=np.uint32)
-    handle2 = M._dedup_dispatch(lanes2, [0, 500, 1000], backend="xla")
+    handle2 = M._dedup_dispatch(lanes2, [0, 500, 1000])
     assert M.deduplicate_resolve(handle2).tolist() == [999]
     # duplicate keys WITHIN one run (pre-merged files can't produce this,
     # but the kernel contract allows it): last index still wins
     lanes3 = np.array([[1], [1], [2], [1]], dtype=np.uint32)
-    handle3 = M._dedup_dispatch(lanes3, [0, 3, 4], backend="xla")
+    handle3 = M._dedup_dispatch(lanes3, [0, 3, 4])
     assert M.deduplicate_resolve(handle3).tolist() == _dedup_oracle(lanes3).tolist()
 
 
@@ -617,7 +617,7 @@ def test_compact_selection_many_runs_fallback(rng):
 
     n, runs = 6000, 300
     lanes, offsets = _runs_fixture(rng, n, runs, 1 << 30, 1)
-    handle = M._dedup_dispatch(lanes, offsets, backend="xla")
+    handle = M._dedup_dispatch(lanes, offsets)
     assert not (isinstance(handle, tuple) and handle[0] == "compact")
     got = M.deduplicate_resolve(handle)
     assert got.tolist() == _dedup_oracle(lanes).tolist()
@@ -755,16 +755,16 @@ def test_dispatch_gate_off_wide_parity(rng, monkeypatch):
     monkeypatch.delenv("PAIMON_TPU_FORCE_COMPACT", raising=False)
     assert not M._link_encodings_pay_off()  # conftest pins jax_platforms=cpu
     lanes, offsets = _runs_fixture(rng, 20_000, 4, 1 << 20, 1)
-    handle = M._dedup_dispatch(lanes, offsets, backend="xla")
+    handle = M._dedup_dispatch(lanes, offsets)
     assert not (isinstance(handle, tuple) and handle[0] == "compact")
     assert M.deduplicate_resolve(handle).tolist() == _dedup_oracle(lanes).tolist()
 
 
 def test_delta_upload_pallas_and_many_runs(rng):
     """The delta-packed UPLOAD survives past the compact download's limits
-    (ADVICE r3): >256 runs and the pallas backend both route through
-    _dedup_select_delta_wide_fn (delta upload + index download) instead of
-    dropping the upload optimization entirely."""
+    (ADVICE r3): >256 runs route through _dedup_select_delta_wide_fn (delta
+    upload + index download) instead of dropping the upload optimization
+    entirely."""
     from paimon_tpu.ops import merge as M
 
     n, runs = 13_000, 325
@@ -781,10 +781,3 @@ def test_delta_upload_pallas_and_many_runs(rng):
     h = M.deduplicate_select_delta_async(lanes, offsets)
     assert h is not None and not (isinstance(h, tuple) and h[0] == "compact")
     assert np.sort(M.deduplicate_resolve(h)).tolist() == np.sort(_dedup_oracle(lanes)).tolist()
-    # pallas epilogue (interpret mode on cpu) over a small delta-qualifying set
-    lanes2, offsets2 = lanes[:4096], [0, 2048, 4096]
-    l2 = np.sort(lanes2[:2048, 0]); l3 = np.sort(lanes2[2048:, 0])
-    lanes2 = np.concatenate([l2, l3]).reshape(-1, 1)
-    hp = M.deduplicate_select_delta_async(lanes2, offsets2, backend="pallas")
-    assert hp is not None
-    assert np.sort(M.deduplicate_resolve(hp)).tolist() == np.sort(_dedup_oracle(lanes2)).tolist()

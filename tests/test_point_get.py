@@ -93,15 +93,12 @@ def test_get_batch_parity_randomized(cat, seed, bloom, schema_kind):
 
 
 def test_get_batch_parity_engines(cat):
-    """sort-engine=pallas and merge.engine=mesh tables serve identical
-    batched gets (the write/merge engines change file contents' layout,
-    never the served rows)."""
+    """merge.engine=mesh tables serve identical batched gets (the
+    write/merge engines change file contents' layout, never the served
+    rows)."""
     from paimon_tpu.table.query import LocalTableQuery
 
-    for name, extra in (
-        ("pal", {"sort-engine": "pallas"}),
-        ("mesh", {"merge.engine": "mesh"}),
-    ):
+    for name, extra in (("mesh", {"merge.engine": "mesh"}),):
         t = cat.create_table(f"db.eng_{name}", SCHEMA, primary_keys=["id"],
                              options={"bucket": "2", **extra})
         write(t, {"id": list(range(60)), "name": [f"n{i}" for i in range(60)],

@@ -591,7 +591,7 @@ PROGRAMS = [
     ("merge_plan", lambda: M._plan_fn(1, 0), ([_U32], [], _U32)),
     ("merge_plan_ovc", lambda: M._plan_fn(1, 0, 8), ([_U32], [], _U32, _BASE)),
     ("dedup_select", lambda: M._dedup_select_fn(1, 0), ([_U32], [], _U8)),
-    ("dedup_select_ovc", lambda: M._dedup_select_fn(1, 0, "xla", 8), ([_U32], [], _U8, _BASE)),
+    ("dedup_select_ovc", lambda: M._dedup_select_fn(1, 0, 8), ([_U32], [], _U8, _BASE)),
     ("dedup_select_compact", lambda: M._dedup_select_compact_fn(1, 0), ([_U32], [], _U8, _STARTS)),
     ("dedup_select_compact_ovc", lambda: M._dedup_select_compact_fn(1, 0, 8), ([_U32], [], _U8, _STARTS, _BASE)),
     ("dedup_select_delta", lambda: M._dedup_select_delta_fn(), (_U16, _STARTS, _BASES, _U8)),
